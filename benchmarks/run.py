@@ -38,6 +38,8 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro import obs
+    from repro.runtime import setup_compile_cache
+    setup_compile_cache()
     if args.trace:
         obs.enable()
 

@@ -29,7 +29,7 @@ Sits between the hand-written program builders (``core/multpim.py``,
   memoization so each spec compiles once per process and the executors
   receive pre-packed, identity-stable tables;
 * :mod:`.diskcache` / :mod:`.serialize` — verified entries spill to
-  ``~/.cache/repro`` (``REPRO_CACHE_DIR`` overrides; ``python -m
+  ``<checkout>/.repro-cache`` (``REPRO_CACHE_DIR`` overrides; ``python -m
   repro.compiler.diskcache clear`` wipes), so cold processes skip
   build+optimize+verify entirely.
 

@@ -1,7 +1,7 @@
 """On-disk persistence for the program cache (cold-start compile skip).
 
 Verified compiled entries are spilled as ``<kind>_nNN_<hash>.npz`` files
-under ``~/.cache/repro`` (override with ``REPRO_CACHE_DIR``; set it to
+under ``<checkout>/.repro-cache`` (override with ``REPRO_CACHE_DIR``; set it to
 ``0``/``off``/``none`` to disable persistence entirely). The file name
 hash is :meth:`OpSpec.content_hash` — a digest of the full spec *and*
 :data:`~repro.compiler.spec.PIPELINE_VERSION` — so any pass-pipeline or
@@ -42,7 +42,11 @@ def cache_dir(create: bool = False) -> Optional[Path]:
     raw = os.environ.get(_ENV)
     if raw is not None and raw.strip().lower() in _DISABLED:
         return None
-    d = Path(raw).expanduser() if raw else Path.home() / ".cache" / "repro"
+    if raw:
+        d = Path(raw).expanduser()
+    else:
+        from repro.runtime import CHECKOUT
+        d = CHECKOUT / ".repro-cache"
     if create:
         d.mkdir(parents=True, exist_ok=True)
     return d

@@ -165,7 +165,7 @@ def pack_program(prog: Program, pad_cols_to: Optional[int] = None) -> PackedProg
 
 
 def run_jax(prog: Program, inputs: Dict[str, np.ndarray], *,
-            use_pallas: bool = False, interpret: bool = True,
+            use_pallas: bool = False, interpret: Optional[bool] = None,
             packed: Optional[PackedProgram] = None
             ) -> Dict[str, np.ndarray]:
     """Execute with JAX. Semantically identical to :func:`run_numpy`.
@@ -178,7 +178,8 @@ def run_jax(prog: Program, inputs: Dict[str, np.ndarray], *,
     ``Executable`` via :meth:`repro.engine.Engine.compile` and call its
     ``run`` — that path adds input marshalling and cache-stable tables.
     """
-    from repro.engine.backends import resolve_backend
+    from repro.engine.backends import (JaxBackend, PallasBackend,
+                                       resolve_backend)
 
     if packed is None:
         packed = pack_program(prog)
@@ -189,6 +190,6 @@ def run_jax(prog: Program, inputs: Dict[str, np.ndarray], *,
         state[:, cols] = np.asarray(inputs[name], dtype=np.uint8)
 
     backend = resolve_backend(
-        f"pallas:interpret={str(interpret).lower()}" if use_pallas else "jax")
+        PallasBackend(interpret=interpret) if use_pallas else JaxBackend())
     final = np.asarray(backend.run_state(packed, state))
     return {name: final[:, cols].copy() for name, cols in prog.output_map.items()}

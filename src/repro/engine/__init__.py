@@ -29,8 +29,9 @@ benchmarks track);
 / ``eng.linear`` are the high-level ops the examples, benchmarks and
 the PIM-mode serve path all share. Backends are pluggable
 (:func:`register_backend`) and selectable per compile or per run:
-``"numpy"``, ``"jax"``, ``"pallas"`` /
-``"pallas:interpret=false,row_block=512"`` (real TPU).
+``"numpy"``, ``"jax:pack=true"``, ``"pallas:pack=true"`` (the Pallas
+interpreter on the CPU, Mosaic on a TPU). With no backend named, the
+platform picks one: numpy on the CPU, ``jax:pack=true`` on a TPU.
 
 Legacy entry points (``repro.core.matvec.matvec``,
 ``repro.kernels.ops.crossbar_run_cached``,

@@ -14,9 +14,15 @@ Stock registry entries:
 * ``"jax"``    — jitted ``lax.scan`` over the tables
   (:func:`repro.kernels.ref.crossbar_run_ref`);
 * ``"pallas"`` — the Mosaic TPU kernel
-  (:func:`repro.kernels.crossbar_step.crossbar_run_pallas`);
-  ``interpret=True`` on CPU, ``interpret=False`` on real TPU, with a
-  ``row_block`` row-tiling policy (rows are the SIMD batch axis).
+  (:func:`repro.kernels.crossbar_step.crossbar_run_pallas`), with a
+  ``row_block`` row-tiling policy (rows are the SIMD batch axis). It
+  runs under the Pallas interpreter exactly when JAX's default backend
+  is the CPU (:func:`repro.runtime.resolve_interpret`); an
+  explicit ``interpret=true`` is honoured on any platform.
+
+With no backend given, :func:`resolve_backend` derives one from the
+platform (:func:`platform_default_spec`): the numpy reference on the
+CPU, the packed jax scan on an accelerator.
 
 Every stock backend additionally carries a **bit-plane packing policy**
 (``pack=True``, spec-selectable as e.g. ``"jax:pack=true"``): crossbar
@@ -28,9 +34,10 @@ per cell. Packing is internal to ``run_state`` — the ``(rows, C)``
 {0,1} contract is unchanged and bit-parity with the unpacked
 interpreters is asserted by the test suite — so ``Executable``,
 ``BatchedExecutable`` and ``GroupedExecutable`` all benefit without API
-changes. The JAX/Pallas packed paths also macro-fuse consecutive cycles
-(``macro=``, :mod:`repro.compiler.macrocycle`) so the scan/grid executes
-``O(T/factor)`` dispatches instead of one per cycle.
+changes. The JAX packed scan also macro-fuses consecutive cycles
+(``macro=``, :mod:`repro.compiler.macrocycle`) so it executes
+``O(T/factor)`` scan steps instead of one per cycle; the packed Pallas
+kernel runs the program as one flat op stream and needs no fusion.
 
 Every stock backend also carries a **fault policy** (``faults=<key>``,
 e.g. ``"jax:pack=true,faults=flip@1e-5@7"``): the key resolves through
@@ -44,9 +51,9 @@ jax/pallas demand ``pack=true``, and the numpy backend transparently
 promotes to its 64-bit packed interpreter.
 
 ``resolve_backend`` accepts a Backend instance, a registered name, or a
-``"name:key=val,key=val"`` spec string — e.g. ``"pallas:interpret=false,
-row_block=512"`` or ``"jax:pack=true,macro=8"`` — so CLI flags map
-directly onto backend policy.
+``"name:key=val,key=val"`` spec string — e.g. ``"pallas:pack=true"``
+or ``"jax:pack=true,macro=8"`` — so CLI flags map directly onto backend
+policy.
 """
 from __future__ import annotations
 
@@ -64,6 +71,7 @@ from repro.core.isa import Gate
 __all__ = ["Backend", "NumpyBackend", "JaxBackend", "PallasBackend",
            "ResidentIndex", "supports_resident", "register_backend",
            "resolve_backend", "backend_names", "backend_fault_model",
+           "platform_default_spec",
            "autotune_row_block",
            "DEFAULT_ROW_BLOCK", "MAX_ROW_BLOCK", "DEFAULT_MACRO"]
 
@@ -415,19 +423,12 @@ class _PallasChain(_EagerPackedChain):
     """Packed Pallas resident chain: each program pass is one Pallas
     kernel launch over the eager-chain state representation."""
 
-    def __init__(self, backend: "PallasBackend", mac, stage, recomb,
-                 idx: ResidentIndex, rows: int, residue=None):
-        super().__init__(backend, mac, stage, recomb, idx, rows,
-                         residue=residue)
-        self._wb = max(8, (backend.row_block or DEFAULT_ROW_BLOCK) // 32)
-
     def _run(self, packed: PackedProgram, st):
         from repro.kernels.crossbar_step import crossbar_run_pallas_packed
         with obs.span("backend.kernel", backend=self.backend.name,
                       rows=self.rows, cycles=packed.n_cycles):
             return crossbar_run_pallas_packed(
-                st, packed, macro=_macro_factor(self.backend.macro),
-                word_block=self._wb, interpret=self.backend.interpret)
+                st, packed, interpret=self.backend.interpret)
 
 
 class _FaultyJaxChain(_EagerPackedChain):
@@ -677,7 +678,9 @@ def autotune_row_block(rows: int, max_block: int = MAX_ROW_BLOCK) -> int:
 
 @dataclass(frozen=True)
 class PallasBackend:
-    """Mosaic TPU kernel; ``interpret=True`` emulates on CPU.
+    """Mosaic TPU kernel. ``interpret=None`` (the default) derives the
+    mode from the platform — the Pallas interpreter on the CPU, Mosaic
+    on a TPU; an explicit ``True``/``False`` is honoured.
 
     ``row_block`` is the row-tiling policy: crossbar rows (the SIMD batch
     axis) are processed in VMEM-resident tiles of this many rows.
@@ -689,10 +692,10 @@ class PallasBackend:
 
     ``pack=True`` runs the bit-plane packed kernel
     (:func:`repro.kernels.crossbar_step.crossbar_run_pallas_packed`):
-    rows are packed 32-per-``uint32`` word, so the row tile becomes a
-    *word* tile of ``row_block / 32`` words (floor 8, the int32 sublane
-    tile) and gates evaluate bitwise on the VPU. ``macro`` is the
-    macro-cycle fusion depth, as on :class:`JaxBackend`.
+    rows are packed 32-per-``uint32`` word, held column-major in tiles
+    of ``SUBLANES * 128`` words, and gates evaluate bitwise on the VPU
+    from an SMEM op stream (``row_block`` applies to the unpacked
+    kernel only).
 
     ``faults=<key>`` activates a device-error model (requires
     ``pack=True``); faulty passes run the shared cycle-at-a-time
@@ -701,10 +704,9 @@ class PallasBackend:
     performance path.
     """
 
-    interpret: bool = True
+    interpret: Optional[bool] = None
     row_block: Optional[int] = None
     pack: bool = False
-    macro: Optional[int] = None
     faults: Optional[str] = None
     name: str = "pallas"
 
@@ -722,7 +724,6 @@ class PallasBackend:
             rows = state.shape[0]
             with obs.span("backend.pack", backend=self.name, rows=rows):
                 words = pack_rows(np.asarray(state, dtype=np.uint8), 32)
-            word_block = max(8, (self.row_block or DEFAULT_ROW_BLOCK) // 32)
             with obs.span("backend.kernel", backend=self.name, rows=rows,
                           cycles=packed.n_cycles,
                           faulty=model is not None):
@@ -734,8 +735,7 @@ class PallasBackend:
                 else:
                     final = crossbar_run_pallas_packed(
                         jnp.asarray(words), packed,
-                        macro=_macro_factor(self.macro),
-                        word_block=word_block, interpret=self.interpret)
+                        interpret=self.interpret)
             with obs.span("backend.unpack", backend=self.name, rows=rows):
                 return unpack_rows(np.asarray(final), rows)
         with obs.span("backend.kernel", backend=self.name,
@@ -802,11 +802,21 @@ def _parse_value(v: str):
         return v
 
 
+def platform_default_spec() -> str:
+    """The backend spec a caller that names none gets: the numpy
+    reference on the CPU (what the tests hold every device path to),
+    the packed jax scan on an accelerator."""
+    from repro.runtime import on_cpu
+    return "numpy" if on_cpu() else "jax:pack=true"
+
+
 def resolve_backend(spec: Union[None, str, Backend],
                     default: Optional[Backend] = None) -> Backend:
     """Backend instance from a name/spec-string/instance (see module doc)."""
     if spec is None:
-        return default if default is not None else NumpyBackend()
+        if default is not None:
+            return default
+        spec = platform_default_spec()
     if not isinstance(spec, str):
         return spec
     name, _, opts = spec.partition(":")
@@ -825,4 +835,4 @@ def resolve_backend(spec: Union[None, str, Backend],
             f"backend spec '{spec}': {e} — options the '{name}' backend "
             f"accepts are its constructor fields "
             f"(e.g. numpy: pack, faults; jax: pack, macro, faults; "
-            f"pallas: interpret, row_block, pack, macro, faults)") from e
+            f"pallas: interpret, row_block, pack, faults)") from e

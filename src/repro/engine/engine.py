@@ -111,13 +111,15 @@ class Engine:
     """Compile-and-execute front end over the PIM stack.
 
     ``backend`` is the default execution backend (name, spec string or
-    instance — see :func:`repro.engine.backends.resolve_backend`);
+    instance — see :func:`repro.engine.backends.resolve_backend`;
+    ``None`` derives it from the platform: numpy on the CPU,
+    ``jax:pack=true`` on an accelerator);
     ``cache`` defaults to the process-wide program cache so every Engine
     (and the legacy shim paths) share compiled artifacts; ``crossbar``
     parameterizes the cost model.
     """
 
-    def __init__(self, backend: Union[str, Backend] = "numpy", *,
+    def __init__(self, backend: Union[None, str, Backend] = None, *,
                  cache: Optional["ProgramCache"] = None,
                  crossbar: CrossbarSpec = CrossbarSpec(),
                  pass_config: Optional["PassConfig"] = None,
@@ -569,9 +571,12 @@ class Engine:
         and stays sequential + round-trip (the paper-parity baseline,
         kept for benchmarking the cache and the co-scheduler).
         """
-        a_vec = np.asarray(a_vec, dtype=object)
-        R, E = a_vec.shape
-        x_vec = np.asarray(x_vec, dtype=object)
+        # Machine-int operands stay machine ints on the resident path
+        # (vectorized marshalling); the host paths below use exact
+        # object ints.
+        a_in = np.asarray(a_vec)
+        x_in = np.asarray(x_vec)
+        R, E = a_in.shape
         if k is None:
             # engine policy, clamped to what the crossbar can hold
             k = (min(self.effective_coschedule_k("mac", n), E)
@@ -595,8 +600,11 @@ class Engine:
             else:
                 rex.reset()
             for e in range(E):
-                rex.step(a_vec[:, e], x_vec[:, e])
+                rex.step(a_in[:, e], x_in[:, e])
             return rex.drain(), rex.chain_cycles(E)
+
+        a_vec = a_in.astype(object)
+        x_vec = x_in.astype(object)
 
         if not use_compiler or k == 1:
             exe = (self.compile("mac", n, backend=bk) if use_compiler
@@ -659,9 +667,8 @@ class Engine:
         ``k`` co-schedules the per-row MAC stream and ``resident``
         selects the device-resident chain path — see
         :meth:`inner_product`)."""
-        A = np.asarray(A, dtype=object)
-        m, e = A.shape
-        X = np.tile(np.asarray(x, dtype=object)[None, :], (m, 1))
+        A = np.asarray(A)
+        X = np.broadcast_to(np.asarray(x)[None, :], A.shape)
         return self.inner_product(A, X, n, use_compiler=use_compiler,
                                   backend=backend, k=k, resident=resident)
 

@@ -1,9 +1,10 @@
-"""Pallas TPU kernels (interpret=True validated on CPU; see ops.py).
+"""Pallas TPU kernels and their jnp references.
 
-The ``*_packed`` variants are the bit-plane packed executors (rows
-packed 32-per-uint32 word, bitwise gate evaluation, macro-fused
-cycles); backends select them via ``pack=true`` policy — see
-:mod:`repro.engine.backends`.
+Kernels lower to Mosaic on a TPU and run under the Pallas interpreter
+on the CPU (:mod:`repro.runtime` decides). The ``*_packed``
+variants are the bit-plane packed executors (rows packed 32-per-uint32
+word, bitwise gate evaluation); backends select them via ``pack=true``
+policy — see :mod:`repro.engine.backends`.
 """
 from .crossbar_step import crossbar_run_pallas_packed
 from .ops import (bitserial_matmul, bitserial_matmul_ref, crossbar_run,

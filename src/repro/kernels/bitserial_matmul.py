@@ -27,11 +27,14 @@ simulator's fixed-point semantics (validated in tests).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.runtime import resolve_interpret
 
 __all__ = ["bitserial_matmul_pallas"]
 
@@ -72,7 +75,8 @@ def _run(x_planes, w, *, bm, bn, bk, interpret, n_bits):
 
 def bitserial_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray, n_bits: int = 8,
                             bm: int = 128, bn: int = 128, bk: int = 128,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: Optional[bool] = None
+                            ) -> jnp.ndarray:
     """``x`` (M, K) non-negative ints < 2^n_bits, ``w`` (K, N) f32.
 
     Returns f32 (M, N) == x @ w computed via bit-plane accumulation.
@@ -88,6 +92,7 @@ def bitserial_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray, n_bits: int = 8,
     n_pad = int(np.ceil(N / bn) * bn)
     planes = jnp.pad(planes, ((0, 0), (0, m_pad - M), (0, k_pad - K)))
     w_p = jnp.pad(w.astype(jnp.float32), ((0, k_pad - K), (0, n_pad - N)))
-    out = _run(planes, w_p, bm=bm, bn=bn, bk=bk, interpret=interpret,
+    out = _run(planes, w_p, bm=bm, bn=bn, bk=bk,
+               interpret=resolve_interpret(interpret),
                n_bits=n_bits)
     return out[:M, :N]

@@ -27,16 +27,19 @@ comfortably inside the ~16 MB VMEM budget.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.executor import PackedProgram, gate_eval_packed
+from repro.core.executor import PackedProgram
 from repro.core.isa import Gate
+from repro.runtime import resolve_interpret
 
-__all__ = ["crossbar_run_pallas", "crossbar_run_pallas_packed"]
+__all__ = ["crossbar_run_pallas", "crossbar_run_pallas_packed", "op_stream"]
 
 
 def _gate_eval(gid, x0, x1, x2):
@@ -115,130 +118,169 @@ def _run(state, gate_id, in0, in1, in2, out_col, init_mask, *,
 # ------------------------------------------------ bit-plane packed ----
 #
 # The packed variant trades the one-hot-matmul mapping for word-wide
-# bitwise execution: crossbar rows are packed 32-per-uint32 word
-# (repro.core.bits.pack_rows), the state tile is (Wb, C) int32 words,
-# and every gate is a pure VPU bitwise op (NOR = ~(x0|x1), MIN3 =
-# ~majority3). Gather/scatter columns come from the static macro-fused
-# tables, so operand access is lax.dynamic_slice along the lane axis
-# (scalar column index — no dynamic per-lane gather needed), and the
-# grid executes ceil(T/macro) loop steps with the macro factor unrolled
-# inside. Scatter is a read-modify-write AND of the single output lane,
-# applied sequentially per op — exact AND accumulation even for the
-# duplicate scratch-column writes of NOP padding.
+# bitwise execution. Crossbar rows are packed 32-per-uint32 word
+# (repro.core.bits.pack_rows) and the state is held *column-major*:
+# ``(C, Wr, 128)`` int32, so one crossbar column of a tile is a full
+# ``(SUBLANES, 128)`` vreg reached by a dynamic index on the untiled
+# leading axis — Mosaic never indexes the lane axis dynamically.
+#
+# The program runs as a flat stream of self-describing entries read from
+# SMEM (:func:`op_stream`): every gate is rewritten as a (possibly
+# complemented) 3-input majority over state columns, with two constant
+# columns (all-zeros, all-ones) appended to the state,
+#
+#   res = maj(x_a, x_b, x_c) ^ inv        new = (old & res) | set
+#
+# so NOT/NOR/MIN3/NAND/OR/COPY, a MAGIC AND-write, an INIT SET and a
+# padding NOP are one branch-free loop body. Ops of one cycle touch
+# disjoint partitions (Program.validate), so executing a cycle's ops in
+# sequence is exact; the stream builder checks that per cycle. The
+# stream is tiled over an ``arbitrary`` grid axis in SMEM blocks of
+# ``STREAM_CHUNK`` entries while the state tile stays in its VMEM output
+# block across the chunks.
+
+SUBLANES = 8            # words per tile = SUBLANES * 128 (one vreg/column)
+STREAM_CHUNK = 2048     # stream entries per SMEM block
+_COL_BITS = 14          # column index field width (C + 2 < 16384)
 
 
-def _packed_kernel(state_ref, gate_ref, in0_ref, in1_ref, in2_ref,
-                   out_ref, init_ref, o_ref, *, n_macro: int, factor: int,
-                   max_ops: int):
-    st = state_ref[...]
-
-    def body(t, st):
-        for j in range(factor):
-            gid = gate_ref[t, j]
-            i0, i1, i2 = in0_ref[t, j], in1_ref[t, j], in2_ref[t, j]
-            ocs = out_ref[t, j]
-            st = st | init_ref[t, j][None, :]
-            # Gather every operand lane before any write (ops within a
-            # cycle observe pre-cycle state).
-            cols = []
-            for m in range(max_ops):
-                x0 = jax.lax.dynamic_index_in_dim(st, i0[m], 1)
-                x1 = jax.lax.dynamic_index_in_dim(st, i1[m], 1)
-                x2 = jax.lax.dynamic_index_in_dim(st, i2[m], 1)
-                cols.append((x0, x1, x2))
-            for m in range(max_ops):
-                x0, x1, x2 = cols[m]
-                res = gate_eval_packed(jnp, gid[m], x0, x1, x2)
-                old = jax.lax.dynamic_index_in_dim(st, ocs[m], 1)
-                st = jax.lax.dynamic_update_slice_in_dim(
-                    st, old & res, ocs[m], 1)
-        return st
-
-    o_ref[...] = jax.lax.fori_loop(0, n_macro, body, st)
+def _majority_operands(gid: int, ins, zero: int, one: int):
+    """``(a, b, c, inv)`` with ``maj(a, b, c) ^ inv`` == gate ``gid``."""
+    x0, x1, x2 = (int(v) for v in ins)
+    if gid == Gate.NOT:
+        return x0, x0, x0, 1
+    if gid == Gate.NOR:
+        return x0, x1, one, 1
+    if gid == Gate.MIN3:
+        return x0, x1, x2, 1
+    if gid == Gate.NAND:
+        return x0, x1, zero, 1
+    if gid == Gate.OR:
+        return x0, x1, one, 0
+    if gid == Gate.COPY:
+        return x0, x0, x0, 0
+    raise ValueError(f"gate id {gid} has no packed encoding")
 
 
-@functools.partial(jax.jit, static_argnames=("word_block", "interpret",
-                                             "tm", "k", "m", "c"))
-def _run_packed(words, gate_id, in0, in1, in2, out_col, init_words, *,
-                word_block: int, interpret: bool, tm: int, k: int, m: int,
-                c: int):
-    n_words = words.shape[0]
-    grid = (n_words // word_block,)
-    kernel = functools.partial(_packed_kernel, n_macro=tm, factor=k,
-                               max_ops=m)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+def op_stream(packed: PackedProgram) -> np.ndarray:
+    """``packed`` as the kernel's ``(n_chunks * STREAM_CHUNK * 2,)``
+    int32 entry stream (memoized on ``packed``). Entry ``i`` is the word
+    pair ``a | b << 16`` and ``c | inv << 14 | set << 15 | out << 16``;
+    columns ``C`` and ``C + 1`` of the kernel state are the constant
+    zero and one columns. The tail is padded with NOPs."""
+    hit = getattr(packed, "_pallas_stream", None)
+    if hit is not None:
+        return hit
+    c = packed.init_mask.shape[1]
+    zero, one = c, c + 1
+    if one >= 1 << _COL_BITS:
+        raise ValueError(f"{c} columns exceed the packed kernel's "
+                         f"{_COL_BITS}-bit column field")
+    ents = []
+    for t in range(packed.n_cycles):
+        set_cols = np.flatnonzero(packed.init_mask[t])
+        ents.extend((zero, zero, zero, int(col), 1, 1) for col in set_cols)
+        real = packed.gate_id[t] != int(Gate.NOP)
+        outs = packed.out_col[t][real]
+        ins = packed.in_cols[t][real]
+        for j, (gid, out) in enumerate(zip(packed.gate_id[t][real], outs)):
+            others = np.delete(outs, j)
+            if np.isin(ins[j], others).any():
+                raise ValueError(
+                    f"cycle {t}: an op reads a column another op of the "
+                    f"same cycle writes; the packed kernel runs a "
+                    f"cycle's ops in sequence")
+            a, b, cc, inv = _majority_operands(int(gid), ins[j], zero, one)
+            ents.append((a, b, cc, int(out), inv, 0))
+    n = max(1, len(ents))
+    pad = -(-n // STREAM_CHUNK) * STREAM_CHUNK - len(ents)
+    ents.extend([(zero, zero, zero, zero, 1, 0)] * pad)   # NOP
+    e = np.asarray(ents, dtype=np.int64)
+    w0 = e[:, 0] | (e[:, 1] << 16)
+    w1 = e[:, 2] | (e[:, 4] << 14) | (e[:, 5] << 15) | (e[:, 3] << 16)
+    stream = np.stack([w0, w1], axis=1).reshape(-1)
+    stream = stream.astype(np.uint32).view(np.int32)
+    packed._pallas_stream = stream
+    return stream
+
+
+def _packed_kernel(tab_ref, st_ref, o_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _load():
+        o_ref[...] = st_ref[...]
+
+    srl = jax.lax.shift_right_logical
+
+    def body(i, carry):
+        w0 = tab_ref[2 * i]
+        w1 = tab_ref[2 * i + 1]
+        xa = o_ref[w0 & 0xFFFF]
+        xb = o_ref[srl(w0, 16)]
+        xc = o_ref[w1 & ((1 << _COL_BITS) - 1)]
+        inv = -(srl(w1, _COL_BITS) & 1)
+        set_ = -(srl(w1, 15) & 1)
+        res = ((xa & xb) | (xc & (xa | xb))) ^ inv
+        out = srl(w1, 16)
+        o_ref[out] = (o_ref[out] & res) | set_
+        return carry
+
+    jax.lax.fori_loop(0, STREAM_CHUNK, body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _run_packed(words, stream, *, interpret: bool):
+    """``(W, C)`` uint32 words -> final words, one Pallas launch."""
+    n_words, c = words.shape
+    tile = SUBLANES * 128
+    w_pad = -(-max(n_words, 1) // tile) * tile
+    st = jax.lax.bitcast_convert_type(words, jnp.int32).T
+    st = jnp.pad(st, ((0, 0), (0, w_pad - n_words)))
+    st = jnp.concatenate([st, jnp.zeros((1, w_pad), jnp.int32),
+                          jnp.full((1, w_pad), -1, jnp.int32)])
+    st = st.reshape(c + 2, w_pad // 128, 128)
+    n_chunks = stream.shape[0] // (2 * STREAM_CHUNK)
+    block = (c + 2, SUBLANES, 128)
+    out = pl.pallas_call(
+        _packed_kernel,
+        grid=(w_pad // tile, n_chunks),
         in_specs=[
-            pl.BlockSpec((word_block, c), lambda i: (i, 0)),
-            pl.BlockSpec((tm, k, m), lambda i: (0, 0, 0)),
-            pl.BlockSpec((tm, k, m), lambda i: (0, 0, 0)),
-            pl.BlockSpec((tm, k, m), lambda i: (0, 0, 0)),
-            pl.BlockSpec((tm, k, m), lambda i: (0, 0, 0)),
-            pl.BlockSpec((tm, k, m), lambda i: (0, 0, 0)),
-            pl.BlockSpec((tm, k, c), lambda i: (0, 0, 0)),
+            pl.BlockSpec((2 * STREAM_CHUNK,), lambda i, j: (j,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(block, lambda i, j: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((word_block, c), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_words, c), jnp.int32),
+        out_specs=pl.BlockSpec(block, lambda i, j: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(st.shape, jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(words, gate_id, in0, in1, in2, out_col, init_words)
+    )(stream, st)
+    out = out[:c].reshape(c, w_pad)[:, :n_words].T
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 def crossbar_run_pallas_packed(state_words: jnp.ndarray,
                                packed: PackedProgram, *,
-                               macro: int = 1,
-                               word_block: int = 8,
-                               interpret: bool = True) -> jnp.ndarray:
+                               interpret: Optional[bool] = None
+                               ) -> jnp.ndarray:
     """Run a packed PIM program on bit-plane packed ``(W, C)`` uint32
-    words (:func:`repro.core.bits.pack_rows` with ``word_bits=32``).
-
-    Words are padded to ``word_block`` (the int32 sublane tile is 8) and
-    columns to a 128-lane multiple; returns the final ``(W, C)`` uint32
-    words. ``macro`` is the macro-cycle fusion factor
-    (:mod:`repro.compiler.macrocycle`). ``interpret=True`` emulates on
-    CPU; non-interpret lowering relies on Mosaic's scalar
-    dynamic-slice/update along the lane axis.
+    words (:func:`repro.core.bits.pack_rows` with ``word_bits=32``);
+    returns the final ``(W, C)`` uint32 words. Words are tiled
+    ``SUBLANES * 128`` at a time; ``interpret`` defaults to the
+    platform (:func:`repro.runtime.resolve_interpret`).
     """
-    from repro.compiler.macrocycle import fuse_macrocycles
-    n_words, cols = state_words.shape
-    c_pad = int(np.ceil(cols / 128) * 128)
-    w_pad = int(np.ceil(max(n_words, 1) / word_block) * word_block)
-    st = jnp.zeros((w_pad, c_pad), jnp.int32)
-    st = st.at[:n_words, :cols].set(
-        jax.lax.bitcast_convert_type(state_words, jnp.int32))
-
-    mt = fuse_macrocycles(packed, macro)
-    tm, k, m = mt.gate_id.shape
-    # Padded, device-resident tables memoized per (factor, c_pad):
-    # decode traffic re-runs the same program, so the lane-padded
-    # init-word build and the host->device uploads happen once, not per
-    # call (the hot-path cost would otherwise be hundreds of KB per
-    # token for the wide multipliers).
-    cache = getattr(packed, "_pallas_table_cache", None)
-    if cache is None:
-        cache = {}
-        packed._pallas_table_cache = cache
-    tabs = cache.get((mt.factor, c_pad))
-    if tabs is None:
-        init_words = np.zeros((tm, k, c_pad), np.int32)
-        init_words[:, :, :mt.init_words.shape[2]] = \
-            mt.init_words.view(np.int32)
-        tabs = (jnp.asarray(mt.gate_id),
-                jnp.asarray(mt.in_cols[:, :, :, 0]),
-                jnp.asarray(mt.in_cols[:, :, :, 1]),
-                jnp.asarray(mt.in_cols[:, :, :, 2]),
-                jnp.asarray(mt.out_col),
-                jnp.asarray(init_words))
-        cache[(mt.factor, c_pad)] = tabs
-    out = _run_packed(st, *tabs,
-                      word_block=word_block, interpret=interpret,
-                      tm=tm, k=k, m=m, c=c_pad)
-    return jax.lax.bitcast_convert_type(out[:n_words, :cols], jnp.uint32)
+    # Device-resident stream memoized per program: decode traffic re-runs
+    # the same program, so the encode and the upload happen once.
+    stream = getattr(packed, "_pallas_stream_dev", None)
+    if stream is None:
+        stream = jnp.asarray(op_stream(packed))
+        packed._pallas_stream_dev = stream
+    return _run_packed(state_words, stream,
+                       interpret=resolve_interpret(interpret))
 
 
 def crossbar_run_pallas(state_bits: jnp.ndarray, packed: PackedProgram,
                         row_block: int = 256,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Run a packed PIM program on a (rows, cols) {0,1} state tensor.
 
     Rows are padded to ``row_block`` and columns to a 128-lane multiple;
@@ -260,5 +302,6 @@ def crossbar_run_pallas(state_bits: jnp.ndarray, packed: PackedProgram,
                jnp.asarray(packed.in_cols[:, :, 2]),
                jnp.asarray(packed.out_col),
                jnp.asarray(init),
-               row_block=row_block, interpret=interpret, t=T, m=M, c=c_pad)
+               row_block=row_block, interpret=resolve_interpret(interpret),
+               t=T, m=M, c=c_pad)
     return out[:rows, :cols].astype(jnp.uint8)

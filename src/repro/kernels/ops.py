@@ -1,9 +1,13 @@
 """Public jit'd entry points for the kernels (Pallas with jnp fallback).
 
-``interpret=True`` everywhere on CPU (this container); on a real TPU the
-same calls lower to Mosaic with the documented BlockSpecs.
+``interpret=None`` derives the mode from the platform
+(:func:`repro.runtime.resolve_interpret`): the Pallas
+interpreter on the CPU, Mosaic lowering with the documented BlockSpecs
+on a TPU.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax.numpy as jnp
 
@@ -18,7 +22,7 @@ __all__ = ["crossbar_run", "crossbar_run_cached", "bitserial_matmul",
 
 
 def crossbar_run(state_bits: jnp.ndarray, packed: PackedProgram, *,
-                 use_pallas: bool = True, interpret: bool = True,
+                 use_pallas: bool = True, interpret: Optional[bool] = None,
                  row_block: int = 256) -> jnp.ndarray:
     if use_pallas:
         return crossbar_run_pallas(state_bits, packed,
@@ -28,7 +32,8 @@ def crossbar_run(state_bits: jnp.ndarray, packed: PackedProgram, *,
 
 def crossbar_run_cached(state_bits: jnp.ndarray, kind: str, n: int, *,
                         flags=None, use_pallas: bool = True,
-                        interpret: bool = True, row_block: int = 256
+                        interpret: Optional[bool] = None,
+                        row_block: int = 256
                         ) -> jnp.ndarray:
     """Run a named program through the shared engine's program cache: the
     schedule is built, optimized, verified and packed once per OpSpec;
@@ -46,7 +51,8 @@ def crossbar_run_cached(state_bits: jnp.ndarray, kind: str, n: int, *,
 
 
 def bitserial_matmul(x: jnp.ndarray, w: jnp.ndarray, n_bits: int = 8, *,
-                     use_pallas: bool = True, interpret: bool = True,
+                     use_pallas: bool = True,
+                     interpret: Optional[bool] = None,
                      bm: int = 128, bn: int = 128, bk: int = 128
                      ) -> jnp.ndarray:
     if use_pallas:

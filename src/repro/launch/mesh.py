@@ -14,11 +14,18 @@ import jax
 __all__ = ["make_production_mesh", "make_host_mesh", "dp_axes", "tp_axis"]
 
 
+def _auto(n_axes: int):
+    """Auto axis types: the compiler propagates shardings from the
+    ``with_sharding_constraint`` annotations (``jax.make_mesh`` defaults
+    to Explicit axes, under which every unannotated gather raises)."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -26,7 +33,7 @@ def make_host_mesh(model_parallel: int = 1):
     n = jax.device_count()
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"), axis_types=_auto(2))
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

@@ -47,7 +47,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import json
 import time
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +58,11 @@ import numpy as np
 from repro import obs
 from repro.configs import get_config
 from repro.engine import get_engine
+from repro.engine.backends import platform_default_spec
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.models.transformer import encode
+from repro.runtime import setup_compile_cache
 from repro.train import make_serve_step
 
 # No logging side effects at import time: handlers attach only when
@@ -128,8 +132,9 @@ def _log_report(rep) -> None:
              s["token_p50_us"], s["token_p99_us"])
 
 
-def _run_traffic(args) -> None:
-    """--traffic mode: continuous-batching load run, no model build."""
+def _run_traffic(args):
+    """--traffic mode: continuous-batching load run, no model build.
+    Returns the continuous run's :class:`repro.serve.LoadReport`."""
     from repro.engine import get_engine, resolve_backend
     from repro.pim import plan_serve_slots
     from repro.serve import (DECODE_ELEMS, TrafficConfig, compare_modes,
@@ -142,7 +147,7 @@ def _run_traffic(args) -> None:
         # rerun replays the identical fault sequence.
         from repro.faults import get_fault_model
         fault_spec = f"flip@{args.fault_rate:g}@{args.fault_seed}"
-        base = args.pim_backend or "numpy"
+        base = args.pim_backend or platform_default_spec()
         sep = "," if ":" in base else ":"
         args.pim_backend = f"{base}{sep}faults={fault_spec}"
         get_fault_model(fault_spec).reset()
@@ -267,13 +272,27 @@ def _run_traffic(args) -> None:
     if args.metrics:
         obs.write_metrics(args.metrics)
         log.info("metrics snapshot -> %s", args.metrics)
+    return cont
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Optional[Dict[str, Any]]:
+    """Run the server from ``argv`` (default ``sys.argv``).
+
+    Traffic mode returns ``{"report": LoadReport}``; model mode returns
+    ``{"cfg", "model", "params", "prompts", "tokens", "last_logits",
+    "recompiles"}`` — ``tokens`` is the ``(batch, gen)`` greedy output,
+    ``last_logits`` the prefill's last-position logits — so a caller in
+    the same process can check the run against the model API.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-9b",
                     help="architecture name (repro.configs registry)")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--override", default="",
+                    help="JSON dict of ModelConfig overrides, e.g. "
+                         "'{\"n_layers\": 8}' to cut depth")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
@@ -300,10 +319,12 @@ def main() -> None:
                          "q/k/v/o — all via co-scheduled crossbar groups")
     ap.add_argument("--pim-backend", default=None,
                     help="execution backend spec for the shared engine, "
-                         "e.g. 'jax:pack=true,macro=8' (bit-plane packed "
-                         "words — the fast path for wide decode batches) "
-                         "or 'pallas:interpret=false' on real TPU; "
-                         "default: the engine's numpy reference")
+                         "e.g. 'jax:pack=true' (bit-plane packed jax "
+                         "scan) or 'pallas:pack=true' (packed Pallas "
+                         "kernel: Mosaic on a TPU, the interpreter on "
+                         "the CPU); default: derived from the platform "
+                         "— 'jax:pack=true' on an accelerator, the numpy "
+                         "reference on the CPU")
     ap.add_argument("--device-config", default=None, metavar="CxGxBxX",
                     help="model a PIM device hierarchy (repro.device): "
                          "channels x bank-groups x banks x crossbars, "
@@ -375,8 +396,9 @@ def main() -> None:
     ap.add_argument("--metrics", default=None, metavar="OUT.json",
                     help="write the obs metrics snapshot (counters, "
                          "gauges, latency histograms) as JSON")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     obs.setup_logging()
+    setup_compile_cache()
     if args.trace:
         obs.enable()
 
@@ -387,11 +409,15 @@ def main() -> None:
                     args.pim_k)
 
     if args.traffic is not None:
-        _run_traffic(args)
-        return
+        return {"report": _run_traffic(args)}
 
     pim = args.smoke if args.pim is None else args.pim
     cfg = get_config(args.arch, smoke=args.smoke)
+    if args.override:
+        cfg = cfg.scaled(**json.loads(args.override))
+    log.info("model %s: n_layers=%d d_model=%d d_ff=%d vocab=%d "
+             "(%.2fB params)", cfg.name, cfg.n_layers, cfg.d_model,
+             cfg.d_ff, cfg.vocab_size, cfg.param_count() / 1e9)
     if pim:
         block_mode = {"head": "none", "ffn": "ffn",
                       "full": "full"}[args.pim_scope]
@@ -400,7 +426,9 @@ def main() -> None:
                                   pim_block_mode=block_mode)
     model = build_model(cfg)
     mesh = make_host_mesh(args.model_parallel)
-    params = model.init(jax.random.PRNGKey(0))
+    # Jitted so the stacked layer weights are generated in place (an
+    # eager init holds every per-layer copy and the stack at once).
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     engine = get_engine()
     if args.pim_k is not None:
         engine.coschedule_k = args.pim_k
@@ -437,7 +465,7 @@ def main() -> None:
                         device, len(plan.shed), ", ".join(plan.shed),
                         list(plan.scopes))
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     prompts = jnp.asarray(rng.integers(3, cfg.vocab_size,
                                        (args.batch, args.prompt_len)))
 
@@ -452,6 +480,8 @@ def main() -> None:
                   prompt_len=args.prompt_len):
         logits, states = model.forward(params, prompts, states=states)
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        last_logits = np.asarray(logits[:, -1])
+        del logits
     log.info("prefill %d x %d: %.2fs", args.batch, args.prompt_len,
              time.time() - t0)
 
@@ -477,6 +507,7 @@ def main() -> None:
     dt = time.time() - t0
     post = engine.stats()
     gen = np.concatenate(out, axis=1)
+    recompiles = post["compiles"] - pre["compiles"]
     log.info("generated %d x %d tokens in %.2fs (%.1f tok/s/seq)",
              args.batch, args.gen, dt, (args.gen - 1) / max(dt, 1e-9))
     if args.gen > 1:
@@ -489,7 +520,6 @@ def main() -> None:
     obs.gauge("serve.engine_runs").set(post["runs"])
     log.info("sample: %s", gen[0][:16].tolist())
     if pim:
-        recompiles = post["compiles"] - pre["compiles"]
         log.info("engine cache: hits=%d misses=%d disk_hits=%d entries=%d "
                  "| recompiles during decode=%d",
                  post["hits"], post["misses"], post["disk_hits"],
@@ -567,6 +597,9 @@ def main() -> None:
     if args.metrics:
         obs.write_metrics(args.metrics)
         log.info("metrics snapshot -> %s", args.metrics)
+    return {"cfg": cfg, "model": model, "params": params,
+            "prompts": prompts, "tokens": gen, "last_logits": last_logits,
+            "recompiles": recompiles}
 
 
 if __name__ == "__main__":

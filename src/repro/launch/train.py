@@ -25,6 +25,7 @@ from repro.data import DataConfig, make_batch_fn
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig
+from repro.runtime import setup_compile_cache
 from repro.train import (RetryingRunner, latest_step, make_train_step,
                          restore_checkpoint)
 
@@ -57,6 +58,7 @@ def main() -> None:
                     help="write the obs metrics snapshot as JSON")
     args = ap.parse_args()
     obs.setup_logging()
+    setup_compile_cache()
     if args.trace:
         obs.enable()
 

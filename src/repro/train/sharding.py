@@ -28,17 +28,10 @@ __all__ = ["param_shardings", "batch_shardings", "state_shardings",
 
 
 def abstract_mesh(axis_sizes: Tuple[int, ...], axis_names: Tuple[str, ...]):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    jax <= 0.4.x takes a tuple of ``(name, size)`` pairs; 0.5+ takes
-    ``(axis_sizes, axis_names)``. The divisibility-guard rules only need
-    ``axis_names``/``shape``, which both forms provide.
-    """
+    """A device-free ``jax.sharding.AbstractMesh`` for rule checks."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+
 
 # trailing-dims rules by leaf name
 _RULES: Dict[str, Tuple[Optional[str], ...]] = {

@@ -9,7 +9,7 @@ def _isolated_program_disk_cache(tmp_path, monkeypatch):
     """Point the compiled-program disk cache at a per-test tmp dir.
 
     Keeps the suite from reading stale artifacts out of the developer's
-    real ``~/.cache/repro`` (which would skip the compile+verify paths
+    real ``<checkout>/.repro-cache`` (which would skip the compile+verify paths
     under test after a compiler edit) and from polluting it. Tests that
     exercise the disk cache explicitly re-monkeypatch ``REPRO_CACHE_DIR``
     themselves.

@@ -160,17 +160,21 @@ def test_ragged_rows_packed_parity(op, n, rows):
 
 @pytest.mark.parametrize("macro", [1, 3, 8, 1000])
 def test_macro_factor_parity(macro):
-    """Any fusion depth (including one larger than the program) is
-    bit-identical to the unpacked reference."""
+    """Any fusion depth of the packed scan (including one larger than
+    the program) is bit-identical to the unpacked reference, as is the
+    packed Pallas kernel, which runs a flat op stream and has no fusion
+    knob."""
     eng = Engine()
     exe = eng.compile("multpim", 8)
     rng = np.random.default_rng(macro)
     batch = {"a": rng.integers(0, 256, 50), "b": rng.integers(0, 256, 50)}
     ref = exe.run(batch, backend="numpy")
-    for name in ("jax", "pallas"):
-        got = exe.run(batch, backend=f"{name}:pack=true,macro={macro}")
+    for spec in (f"jax:pack=true,macro={macro}", "pallas:pack=true"):
+        got = exe.run(batch, backend=spec)
         assert all(int(a) == int(b)
-                   for a, b in zip(ref["out"], got["out"])), name
+                   for a, b in zip(ref["out"], got["out"])), spec
+    with pytest.raises(ValueError, match="pallas"):
+        resolve_backend(f"pallas:pack=true,macro={macro}")
 
 
 def test_fuse_macrocycles_shapes_and_memo():

@@ -92,3 +92,27 @@ def test_paper_claims_summary():
                       "multpim-area": 320}                    # Table II
     assert floatpim_matvec_latency(8, 32) == 109616           # Table III
     assert matvec_latency_formula(8, 32) == 4292
+
+
+def test_serve_cli_smoke_with_depth_override(tmp_path):
+    """The serve CLI takes train's ``--override`` JSON, so depth is cut
+    through the normal entry point; the run decodes with the PIM LM head
+    and holds its compile-once check (a violation exits non-zero). Run
+    as a child process: the entry point sets JAX's compilation cache,
+    here pointed at a scratch directory."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"),
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src")]
+                   + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--smoke",
+         "--override", '{"n_layers": 2}', "--batch", "2",
+         "--prompt-len", "8", "--gen", "3", "--cache-len", "16"],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = proc.stderr + proc.stdout
+    assert "n_layers=2 " in out
+    assert "compile-once verified" in out
