@@ -139,6 +139,16 @@ class ResidentIndex:
     res_out: Optional[np.ndarray] = None  # residue outputs r3 ++ r7
 
 
+def _until_ready(out):
+    """``out``, waited for while the tracer is on: the ``backend.kernel``
+    span around a dispatch then ends when the kernel does, and
+    ``backend.unpack`` holds only the host's unpacking. With tracing off
+    nothing waits."""
+    if obs.enabled():
+        out.block_until_ready()
+    return out
+
+
 class _ChainBase:
     """Shared packing helpers for the resident chains. A chain owns the
     live device state representation for ``rows`` parallel MAC chains
@@ -335,24 +345,25 @@ class _JaxChain(_ChainBase):
                         rows=self.rows, cycles=cycles, fused=programs)
 
     def first(self, planes: np.ndarray):
+        words = self._pack(planes)
         with self._kernel_span("mac", self.mac.n_cycles):
-            return self._first(self._pack(planes))
+            return _until_ready(self._first(words))
 
     def step(self, dev, planes: np.ndarray, fresh: np.ndarray):
+        words, fresh_w = self._pack(planes), self._pack_mask(fresh)
         with self._kernel_span("stage+mac",
                                self.stage.n_cycles + self.mac.n_cycles):
-            return self._step(dev, self._pack(planes),
-                              self._pack_mask(fresh))
+            return _until_ready(self._step(dev, words, fresh_w))
 
     def drain(self, dev) -> np.ndarray:
         with self._kernel_span("recomb", self.recomb.n_cycles):
-            out = self._drain(dev)
+            out = _until_ready(self._drain(dev))
         with obs.span("backend.unpack", backend=self.name, rows=self.rows):
             return unpack_rows(np.asarray(out), self.rows)
 
     def residue(self, dev) -> np.ndarray:
         with self._kernel_span("residue", self.res.n_cycles):
-            out = self._residue(dev)
+            out = _until_ready(self._residue(dev))
         with obs.span("backend.unpack", backend=self.name, rows=self.rows):
             return unpack_rows(np.asarray(out), self.rows)
 
@@ -427,8 +438,8 @@ class _PallasChain(_EagerPackedChain):
         from repro.kernels.crossbar_step import crossbar_run_pallas_packed
         with obs.span("backend.kernel", backend=self.backend.name,
                       rows=self.rows, cycles=packed.n_cycles):
-            return crossbar_run_pallas_packed(
-                st, packed, interpret=self.backend.interpret)
+            return _until_ready(crossbar_run_pallas_packed(
+                st, packed, interpret=self.backend.interpret))
 
 
 class _FaultyJaxChain(_EagerPackedChain):
@@ -449,8 +460,8 @@ class _FaultyJaxChain(_EagerPackedChain):
         from repro.kernels.ref import crossbar_run_ref_packed_faulty
         with obs.span("backend.kernel", backend=self.backend.name,
                       rows=self.rows, cycles=packed.n_cycles, faulty=True):
-            return crossbar_run_ref_packed_faulty(st, packed, self.model,
-                                                  self.rows)
+            return _until_ready(crossbar_run_ref_packed_faulty(
+                st, packed, self.model, self.rows))
 
 
 # ---------------------------------------------------------------- numpy ----
@@ -638,6 +649,7 @@ class JaxBackend:
                     final = crossbar_run_ref_packed(
                         jnp.asarray(words), packed,
                         macro=_macro_factor(self.macro))
+                _until_ready(final)
             with obs.span("backend.unpack", backend=self.name, rows=rows):
                 return unpack_rows(np.asarray(final), rows)
         with obs.span("backend.kernel", backend=self.name,
@@ -736,6 +748,7 @@ class PallasBackend:
                     final = crossbar_run_pallas_packed(
                         jnp.asarray(words), packed,
                         interpret=self.interpret)
+                _until_ready(final)
             with obs.span("backend.unpack", backend=self.name, rows=rows):
                 return unpack_rows(np.asarray(final), rows)
         with obs.span("backend.kernel", backend=self.name,

@@ -713,15 +713,16 @@ class Engine:
             wq = quantize(w, n_bits, axis=0)
             if use_pallas:
                 from repro.kernels.ops import bitserial_matmul
-                prod = bitserial_matmul(xq.q, wq.q.astype(jnp.float32),
-                                        n_bits)
-                k = x2.shape[-1]
-                corr = (xq.zero * jnp.sum(wq.q.astype(jnp.float32), axis=0,
-                                          keepdims=True)
-                        + wq.zero * jnp.sum(xq.q.astype(jnp.float32),
-                                            axis=-1, keepdims=True)
-                        - k * xq.zero * wq.zero)
-                y = (prod - corr) * xq.scale * wq.scale
+                with obs.scope(obs.PIM_MATMUL):
+                    prod = bitserial_matmul(xq.q, wq.q.astype(jnp.float32),
+                                            n_bits)
+                    k = x2.shape[-1]
+                    corr = (xq.zero * jnp.sum(wq.q.astype(jnp.float32),
+                                              axis=0, keepdims=True)
+                            + wq.zero * jnp.sum(xq.q.astype(jnp.float32),
+                                                axis=-1, keepdims=True)
+                            - k * xq.zero * wq.zero)
+                    y = (prod - corr) * xq.scale * wq.scale
             else:
                 y = qmatmul_exact(xq, wq)
             y = y.reshape(*lead, w.shape[-1])

@@ -11,6 +11,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from .layers import softcap as _softcap
 
 __all__ = ["attend", "decode_attend", "KVCache", "projection_shapes"]
@@ -184,10 +186,11 @@ def decode_attend(q: jnp.ndarray, cache: KVCache, k_new: jnp.ndarray,
     """
     t = cache.k.shape[1]
     slot = jnp.mod(cache.length, t)
-    k = jax.lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype),
-                                     (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype),
-                                     (0, slot, 0, 0))
+    with obs.scope(obs.KV_CACHE):
+        k = jax.lax.dynamic_update_slice(
+            cache.k, k_new.astype(cache.k.dtype), (0, slot, 0, 0))
+        v = jax.lax.dynamic_update_slice(
+            cache.v, v_new.astype(cache.v.dtype), (0, slot, 0, 0))
     new_len = cache.length + 1
 
     d = q.shape[-1]

@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 
 from .attention import KVCache, attend, decode_attend
@@ -133,49 +134,59 @@ def _qkv(cfg: ModelConfig, p, xn, pos):
     return q, k, v
 
 
+def _prefill_cache(state, k, v):
+    """The block's decode state with the prompt's K/V left behind."""
+    s = k.shape[1]
+    t = state["self"]["k"].shape[1]
+    with obs.scope(obs.KV_CACHE):
+        kc, vc = k, v
+        if s < t:
+            kc = jnp.pad(k, ((0, 0), (0, t - s), (0, 0), (0, 0)))
+            vc = jnp.pad(v, ((0, 0), (0, t - s), (0, 0), (0, 0)))
+        elif s > t:            # windowed: keep the most recent slice,
+            # rotated so token j sits at ring slot j % t.
+            kc = jnp.roll(k[:, -t:], s % t, axis=1)
+            vc = jnp.roll(v[:, -t:], s % t, axis=1)
+        new_state = dict(state)
+        new_state["self"] = {
+            "k": kc.astype(state["self"]["k"].dtype),
+            "v": vc.astype(state["self"]["v"].dtype),
+            "length": jnp.asarray(s, jnp.int32)}
+    return new_state
+
+
 def apply_attn_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode,
                      kind: str):
     b, s, d = x.shape
     window = cfg.window if kind == "l" else None
-    xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, p, xn, pos)
-    new_state = state
-    if mode in ("full", "encode"):
-        o = attend(q, k, v, causal=(mode != "encode"), window=window,
-                   cap=cfg.softcap_attn)
-        if state is not None:     # prefill: leave the KV behind
-            t = state["self"]["k"].shape[1]
-            kc, vc = k, v
-            if s < t:
-                kc = jnp.pad(k, ((0, 0), (0, t - s), (0, 0), (0, 0)))
-                vc = jnp.pad(v, ((0, 0), (0, t - s), (0, 0), (0, 0)))
-            elif s > t:            # windowed: keep the most recent slice,
-                # rotated so token j sits at ring slot j % t.
-                kc = jnp.roll(k[:, -t:], s % t, axis=1)
-                vc = jnp.roll(v[:, -t:], s % t, axis=1)
+    with obs.scope(obs.ATTENTION):
+        xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, p, xn, pos)
+        new_state = state
+        if mode in ("full", "encode"):
+            o = attend(q, k, v, causal=(mode != "encode"), window=window,
+                       cap=cfg.softcap_attn)
+            if state is not None:     # prefill: leave the KV behind
+                new_state = _prefill_cache(state, k, v)
+        else:
+            o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
+                                     window=window, cap=cfg.softcap_attn)
             new_state = dict(state)
-            new_state["self"] = {
-                "k": kc.astype(state["self"]["k"].dtype),
-                "v": vc.astype(state["self"]["v"].dtype),
-                "length": jnp.asarray(s, jnp.int32)}
-    else:
-        o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
-                                 window=window, cap=cfg.softcap_attn)
-        new_state = dict(state)
-        new_state["self"] = cache._asdict()
-    x = x + pim_proj(cfg, o.reshape(b, s, cfg.q_dim), p["wo"], scope="attn")
-
-    if cfg.family == "encdec" and enc_out is not None:
-        xn2 = rms_norm(x, p["lnx"], cfg.norm_eps)
-        qx = pim_proj(cfg, xn2, p["xq"], scope="attn").reshape(
-            b, s, cfg.n_heads, cfg.hd)
-        kx = pim_proj(cfg, enc_out, p["xk"], scope="attn").reshape(
-            b, enc_out.shape[1], cfg.n_kv_heads, cfg.hd)
-        vx = pim_proj(cfg, enc_out, p["xv"], scope="attn").reshape(
-            b, enc_out.shape[1], cfg.n_kv_heads, cfg.hd)
-        ox = attend(qx, kx, vx, causal=False)
-        x = x + pim_proj(cfg, ox.reshape(b, s, cfg.q_dim), p["xo"],
+            new_state["self"] = cache._asdict()
+        x = x + pim_proj(cfg, o.reshape(b, s, cfg.q_dim), p["wo"],
                          scope="attn")
+
+        if cfg.family == "encdec" and enc_out is not None:
+            xn2 = rms_norm(x, p["lnx"], cfg.norm_eps)
+            qx = pim_proj(cfg, xn2, p["xq"], scope="attn").reshape(
+                b, s, cfg.n_heads, cfg.hd)
+            kx = pim_proj(cfg, enc_out, p["xk"], scope="attn").reshape(
+                b, enc_out.shape[1], cfg.n_kv_heads, cfg.hd)
+            vx = pim_proj(cfg, enc_out, p["xv"], scope="attn").reshape(
+                b, enc_out.shape[1], cfg.n_kv_heads, cfg.hd)
+            ox = attend(qx, kx, vx, causal=False)
+            x = x + pim_proj(cfg, ox.reshape(b, s, cfg.q_dim), p["xo"],
+                             scope="attn")
 
     xn3 = rms_norm(x, p["ln2"], cfg.norm_eps)
     x = x + _apply_mlp(cfg, p["mlp"], xn3)
@@ -254,17 +265,18 @@ def _moe_ffn_chunk(cfg: ModelConfig, p, x2: jnp.ndarray) -> jnp.ndarray:
 
 def apply_moe_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode):
     b, s, d = x.shape
-    xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, p, xn, pos)
-    new_state = state
-    if mode == "full":
-        o = attend(q, k, v, causal=True, cap=cfg.softcap_attn)
-    else:
-        o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
-                                 cap=cfg.softcap_attn)
-        new_state = dict(state)
-        new_state["self"] = cache._asdict()
-    x = x + (o.reshape(b, s, cfg.q_dim) @ p["wo"])
+    with obs.scope(obs.ATTENTION):
+        xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, p, xn, pos)
+        new_state = state
+        if mode == "full":
+            o = attend(q, k, v, causal=True, cap=cfg.softcap_attn)
+        else:
+            o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
+                                     cap=cfg.softcap_attn)
+            new_state = dict(state)
+            new_state["self"] = cache._asdict()
+        x = x + (o.reshape(b, s, cfg.q_dim) @ p["wo"])
     xn2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     return x + moe_ffn(cfg, p, xn2), new_state
 

@@ -17,6 +17,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 
 from .blocks import apply_block, init_block, init_state
@@ -251,18 +252,21 @@ def decode_step(cfg: ModelConfig, params, token: jnp.ndarray,
             blks, li = xs
             out_states = []
             for j, kind in enumerate(unit):
-                st_j = jax.tree.map(
-                    lambda s: jax.lax.dynamic_index_in_dim(
-                        s, li, 0, keepdims=False), scan_states[j])
+                with obs.scope(obs.KV_CACHE):
+                    st_j = jax.tree.map(
+                        lambda s: jax.lax.dynamic_index_in_dim(
+                            s, li, 0, keepdims=False), scan_states[j])
                 h, ns = apply_block(cfg, kind, blks[j], h, pos=position,
                                     state=st_j, enc_out=enc_out,
                                     mode="decode")
                 out_states.append(ns)
-            scan_states = [
-                jax.tree.map(
-                    lambda s, n: jax.lax.dynamic_update_index_in_dim(
-                        s, n.astype(s.dtype), li, 0), scan_states[j], ns_j)
-                for j, ns_j in enumerate(out_states)]
+            with obs.scope(obs.KV_CACHE):
+                scan_states = [
+                    jax.tree.map(
+                        lambda s, n: jax.lax.dynamic_update_index_in_dim(
+                            s, n.astype(s.dtype), li, 0), scan_states[j],
+                        ns_j)
+                    for j, ns_j in enumerate(out_states)]
             return (h, scan_states), None
 
         (x, out), _ = jax.lax.scan(

@@ -1,6 +1,6 @@
 """repro.obs — zero-dependency observability for the whole stack.
 
-Three pieces, one import surface:
+Four pieces, one import surface:
 
 * **Spans** (:mod:`.trace`) — ``with obs.span("compile.fuse", op=...):``
   wall-time intervals from the compiler, cache, engine, executors, and
@@ -16,6 +16,27 @@ Three pieces, one import surface:
   (partition occupancy, gate activity, switching) derived from compiled
   programs, merged into the same trace file; plus the
   ``energy_proxy`` switching-activity scalar on ``ExecCost``.
+* **Device scopes** (:mod:`.scopes`) — where a jitted program's device
+  time goes. Model code marks its work with ``with
+  obs.scope(obs.KV_CACHE):`` from one vocabulary, :data:`SCOPES`:
+  ``kv_cache`` (the decode caches' reads and writes), ``attention``
+  (projections, rope, scores, softmax, weighted sum), ``pim.quantize``
+  (the PIM linears' quantize, dequantize and scale reductions) and
+  ``pim.matmul`` (their integer product and zero-point corrections);
+  the innermost wins where they nest. Scopes are ``jax.named_scope``
+  names: they label HLO metadata only, so the executable is unchanged,
+  and an operator sees them in XProf as each op's ``tf_op``.
+  ``obs.register_program(jitted, *args)`` (done by
+  ``make_serve_step``) keeps a program's abstract arguments;
+  ``obs.device_scopes()`` compiles the registered programs from the
+  caches, only when asked, and maps each op, ``"<module>/<op>"`` as a
+  profiler trace names it, to its scope (``None`` outside all, or
+  ``obs.CONTAINER`` for a ``while``/``conditional``/``call`` whose body
+  ops hold its time). Call it after a measured window, never inside.
+  The compile listener (``obs.watch_compiles()``, started by the first
+  registration) counts every backend compile in ``jax.compiles`` and,
+  while the tracer is on, records each compile phase as a ``jax.compile``
+  span, so a compile inside a traced window is named among its gaps.
 
 Import layering: ``repro.obs`` depends only on :mod:`repro.core` — the
 compiler/engine/pim layers all import it, so it must sit below them.
@@ -27,6 +48,9 @@ from typing import Optional
 from .logging import get_logger, setup_logging
 from .metrics import (Counter, Gauge, Histogram, Registry,
                       WindowedHistogram, get_registry)
+from .scopes import (ATTENTION, COMPILES, CONTAINER, KV_CACHE, PIM_MATMUL,
+                     PIM_QUANTIZE, SCOPES, device_scopes, register_program,
+                     scope, watch_compiles)
 from .trace import NULL_SPAN, PID_SPANS, Span, Tracer, get_tracer
 from .waterfall import (cycle_occupancy, switching_activity,
                         switching_profile, waterfall_events)
@@ -43,6 +67,10 @@ __all__ = [
     # waterfall
     "cycle_occupancy", "switching_profile", "switching_activity",
     "waterfall_events",
+    # device scopes
+    "scope", "SCOPES", "KV_CACHE", "ATTENTION", "PIM_QUANTIZE",
+    "PIM_MATMUL", "CONTAINER", "COMPILES", "register_program",
+    "device_scopes", "watch_compiles",
     # logging
     "setup_logging", "get_logger",
 ]

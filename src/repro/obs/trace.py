@@ -157,6 +157,16 @@ class Tracer:
         with self._lock:
             self._events.append(ev)
 
+    def complete(self, name: str, seconds: float, cat: str = "repro",
+                 **args) -> None:
+        """A span that ends now and lasted ``seconds``, for an interval
+        known only after the fact (a ``jax.monitoring`` duration). No-op
+        while disabled, like spans."""
+        if not self.enabled:
+            return
+        t1 = _clock_ns()
+        self._record(name, cat, t1 - int(seconds * 1e9), t1, args)
+
     def _record(self, name: str, cat: str, t0: int, t1: int,
                 args: Dict) -> None:
         ev = {"name": name, "cat": cat, "ph": "X",

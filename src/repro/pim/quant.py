@@ -12,6 +12,8 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = ["QTensor", "quantize", "dequantize", "qmatmul_exact",
            "qragged_matmul_exact"]
 
@@ -24,16 +26,18 @@ class QTensor(NamedTuple):
 
 
 def quantize(x: jnp.ndarray, n_bits: int = 8, axis=None) -> QTensor:
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    scale = jnp.maximum(amax, 1e-8) / (2 ** (n_bits - 1) - 1)
-    zero = 2 ** (n_bits - 1)
-    q = jnp.clip(jnp.round(x / scale) + zero, 0, 2 ** n_bits - 1)
-    return QTensor(q.astype(jnp.int32), scale.astype(jnp.float32),
-                   n_bits, zero)
+    with obs.scope(obs.PIM_QUANTIZE):
+        amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
+        scale = jnp.maximum(amax, 1e-8) / (2 ** (n_bits - 1) - 1)
+        zero = 2 ** (n_bits - 1)
+        q = jnp.clip(jnp.round(x / scale) + zero, 0, 2 ** n_bits - 1)
+        return QTensor(q.astype(jnp.int32), scale.astype(jnp.float32),
+                       n_bits, zero)
 
 
 def dequantize(t: QTensor) -> jnp.ndarray:
-    return (t.q.astype(jnp.float32) - t.zero) * t.scale
+    with obs.scope(obs.PIM_QUANTIZE):
+        return (t.q.astype(jnp.float32) - t.zero) * t.scale
 
 
 def qmatmul_exact(xq: QTensor, wq: QTensor) -> jnp.ndarray:
@@ -51,11 +55,12 @@ def qmatmul_exact(xq: QTensor, wq: QTensor) -> jnp.ndarray:
     xi = xq.q
     wi = wq.q
     k = xi.shape[-1]
-    prod = xi @ wi                      # int32: exact
-    corr = (xq.zero * jnp.sum(wi, axis=0, keepdims=True)
-            + wq.zero * jnp.sum(xi, axis=-1, keepdims=True)
-            - k * xq.zero * wq.zero)
-    return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
+    with obs.scope(obs.PIM_MATMUL):
+        prod = xi @ wi                  # int32: exact
+        corr = (xq.zero * jnp.sum(wi, axis=0, keepdims=True)
+                + wq.zero * jnp.sum(xi, axis=-1, keepdims=True)
+                - k * xq.zero * wq.zero)
+        return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
 
 
 def qragged_matmul_exact(xq: QTensor, wq: QTensor,
@@ -74,14 +79,16 @@ def qragged_matmul_exact(xq: QTensor, wq: QTensor,
     xi = xq.q
     wi = wq.q                                          # (E, D, F)
     k = xi.shape[-1]
-    # int32 accumulation end-to-end (see qmatmul_exact): exact where a
-    # float32 ragged_dot drifts once the per-row dot passes 2^24.
-    prod = jax.lax.ragged_dot(xi, wi, counts)
-    # Per-row sum_d w[expert(row), d, :]: expand the per-expert column
-    # sums along the ragged segments (counts sum to T by construction).
-    wsum = jnp.repeat(jnp.sum(wi, axis=1), counts, axis=0,
-                      total_repeat_length=xi.shape[0])
-    corr = (xq.zero * wsum
-            + wq.zero * jnp.sum(xi, axis=-1, keepdims=True)
-            - k * xq.zero * wq.zero)
-    return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
+    with obs.scope(obs.PIM_MATMUL):
+        # int32 accumulation end-to-end (see qmatmul_exact): exact where
+        # a float32 ragged_dot drifts once the per-row dot passes 2^24.
+        prod = jax.lax.ragged_dot(xi, wi, counts)
+        # Per-row sum_d w[expert(row), d, :]: expand the per-expert
+        # column sums along the ragged segments (counts sum to T by
+        # construction).
+        wsum = jnp.repeat(jnp.sum(wi, axis=1), counts, axis=0,
+                          total_repeat_length=xi.shape[0])
+        corr = (xq.zero * wsum
+                + wq.zero * jnp.sum(xi, axis=-1, keepdims=True)
+                - k * xq.zero * wq.zero)
+        return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale

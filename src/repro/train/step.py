@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.models.model import Model
 from repro.optim.adamw import (AdamWConfig, OptState, adamw_init,
                                adamw_update)
@@ -123,12 +124,16 @@ def make_serve_step(model: Model, mesh):
         ps = param_shardings(mesh, params_like)
         ss = state_shardings(mesh, states_like)
         bs = batch_shardings(mesh, batch_like)
-        return jax.jit(
+        step = jax.jit(
             serve_step,
             in_shardings=(ps, ss, bs["token"], bs["position"]),
             out_shardings=(bs["token"], ss),
             donate_argnums=(1,),
         )
+        # Shapes only, for obs.device_scopes() after a measured window.
+        obs.register_program(step, params_like, states_like,
+                             batch_like["token"], batch_like["position"])
+        return step
     return serve_step, jit_for
 
 
