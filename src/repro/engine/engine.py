@@ -682,11 +682,18 @@ class Engine:
         finetuning). In ``pim`` mode the Section-VI MAC for ``n_bits`` is
         compiled through this engine's shared cache, so serving traffic
         pays schedule compilation once per width, and the per-layer cost
-        model rides the same verified program.
+        model rides the same verified program. In ``pim`` mode ``w`` may
+        also be a :class:`~repro.pim.quant.PlannedWeight` (quantized
+        once, :func:`repro.models.transformer.plan_weights`); a float
+        ``w`` is planned in the call. Either way the activations are
+        quantized per call and multiplied by the centred codes in one
+        integer product (:func:`~repro.pim.quant.qmatmul_planned`).
         """
         import jax.numpy as jnp
 
-        from repro.pim.quant import dequantize, qmatmul_exact, quantize
+        from repro.pim.quant import (PlannedWeight, dequantize,
+                                     plan_weight, qmatmul_planned,
+                                     quantize)
         if mode == "float":
             y = x @ w
         elif mode == "fake":
@@ -710,8 +717,10 @@ class Engine:
             lead = x.shape[:-1]
             x2 = x.reshape(-1, in_dim)
             xq = quantize(x2, n_bits)
-            wq = quantize(w, n_bits, axis=0)
             if use_pallas:
+                if isinstance(w, PlannedWeight):
+                    raise ValueError("the Pallas route takes float weights")
+                wq = quantize(w, n_bits, axis=0)
                 from repro.kernels.ops import bitserial_matmul
                 with obs.scope(obs.PIM_MATMUL):
                     prod = bitserial_matmul(xq.q, wq.q.astype(jnp.float32),
@@ -724,7 +733,9 @@ class Engine:
                             - k * xq.zero * wq.zero)
                     y = (prod - corr) * xq.scale * wq.scale
             else:
-                y = qmatmul_exact(xq, wq)
+                if not isinstance(w, PlannedWeight):
+                    w = plan_weight(w, n_bits)
+                y = qmatmul_planned(xq, w)
             y = y.reshape(*lead, w.shape[-1])
         else:
             raise ValueError(mode)

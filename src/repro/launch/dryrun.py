@@ -29,7 +29,9 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 """
 import argparse
+import functools
 import json
+import math
 import re
 import sys
 import time
@@ -132,6 +134,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         lambda s_, sh: jax.ShapeDtypeStruct(s_.shape, s_.dtype, sharding=sh),
         params_like, ps)
 
+    kept = 0
     if shape.kind == "train":
         from repro.optim.adamw import adamw_init
         mb = MICROBATCHES_BY_ARCH.get((arch, shape.name),
@@ -185,6 +188,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         jitted = jit_for(params_like, states_like, batch_like)
         lowered = jitted.lower(params_like, states_like,
                                batch_like["token"], batch_like["position"])
+        kept = _kept_float_bytes(model.cfg, params_like)
 
     t_lower = time.time() - t0
     compiled = lowered.compile()
@@ -210,8 +214,10 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             "argument_bytes": getattr(mem, "argument_size_in_bytes", 0),
             "output_bytes": getattr(mem, "output_size_in_bytes", 0),
             "temp_bytes": getattr(mem, "temp_size_in_bytes", 0),
+            "kept_float_bytes": kept,
             "peak_bytes": (getattr(mem, "argument_size_in_bytes", 0)
-                           + getattr(mem, "temp_size_in_bytes", 0)),
+                           + getattr(mem, "temp_size_in_bytes", 0)
+                           + kept),
         },
         "collective_bytes": coll,
     }
@@ -225,6 +231,24 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
               f"(lower {t_lower:.0f}s, compile {t_compile:.0f}s)",
               flush=True)
     return rec
+
+
+def _kept_float_bytes(cfg, params_like) -> int:
+    """Per-device bytes of the float weights a serve step keeps beside
+    their plan: the step's arguments hold the plan, and ``ServeStep``
+    the params it planned from."""
+    from repro.models.transformer import plan_weights
+    from repro.pim import PlannedWeight
+
+    def is_plan(w):
+        return isinstance(w, PlannedWeight)
+
+    plan = jax.eval_shape(functools.partial(plan_weights, cfg), params_like)
+    planned = {path for path, w in jax.tree_util.tree_flatten_with_path(
+        plan, is_leaf=is_plan)[0] if is_plan(w)}
+    return sum(math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
+               for path, s in jax.tree_util.tree_flatten_with_path(
+                   params_like)[0] if path in planned)
 
 
 def main() -> None:

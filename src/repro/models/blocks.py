@@ -33,7 +33,8 @@ from repro.configs.base import ModelConfig
 from .attention import KVCache, attend, decode_attend
 from .layers import Initializer, rms_norm, rope
 
-__all__ = ["init_block", "apply_block", "init_state", "pim_proj"]
+__all__ = ["init_block", "apply_block", "init_state", "pim_proj",
+           "pim_weights"]
 
 
 # ------------------------------------------------------ PIM offload ----
@@ -55,6 +56,29 @@ def pim_proj(cfg: ModelConfig, x: jnp.ndarray, w: jnp.ndarray, *,
     from repro.engine import get_engine   # lazy: models stay engine-free
     mode = "pim" if cfg.pim_linear_mode == "off" else cfg.pim_linear_mode
     return get_engine().linear(x, w, n_bits=cfg.pim_linear_bits, mode=mode)
+
+
+_MLP = ("w1", "w2", "w3")
+
+
+def pim_weights(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
+    """The weights a block of ``kind`` passes to :func:`pim_proj`, by
+    scope, as key paths into its params (a path may be absent, e.g.
+    ``w3`` of a gelu MLP). :func:`repro.models.transformer.plan_weights`
+    quantizes these once; the MoE expert stacks (``_pim_ragged``) are
+    not here. Keep it beside the ``apply_*`` functions' ``pim_proj``
+    calls: ``tests/test_weight_plan.py`` traces every architecture's
+    decode step on the plan and fails on a float weight in ``pim`` mode."""
+    def mlp(key):
+        return tuple((key, n) for n in _MLP)
+    if kind in ("g", "l", "d"):
+        attn = ("wq", "wk", "wv", "wo", "xq", "xk", "xv", "xo")
+        return {"attn": tuple((n,) for n in attn), "ffn": mlp("mlp")}
+    if kind == "m":                       # its "wo" is a plain matmul
+        return {"attn": (("wq",), ("wk",), ("wv",)), "ffn": mlp("shared")}
+    if kind == "r" and cfg.family != "rwkv":
+        return {"ffn": mlp("mlp")}
+    return {}
 
 
 def _pim_ragged(cfg: ModelConfig, xs: jnp.ndarray, we: jnp.ndarray,

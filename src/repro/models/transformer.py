@@ -20,11 +20,11 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs.base import ModelConfig
 
-from .blocks import apply_block, init_block, init_state
+from .blocks import apply_block, init_block, init_state, pim_weights
 from .layers import Initializer, rms_norm, softcap
 
 __all__ = ["stack_plan", "init_params", "forward", "decode_step",
-           "init_decode_state", "encode", "head_matmul"]
+           "init_decode_state", "encode", "head_matmul", "plan_weights"]
 
 
 def head_matmul(cfg: ModelConfig, x: jnp.ndarray,
@@ -112,6 +112,56 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                                    scale=cfg.d_model ** -0.5, dtype=dtype)
     params = jax.tree.map(lambda x: x.astype(dtype), params)
     return params
+
+
+# ---------------------------------------------------------- weight plan ----
+def plan_weights(cfg: ModelConfig, params: Dict[str, Any]) -> Dict[str, Any]:
+    """``params`` with every weight that a ``pim``-mode linear of
+    :func:`decode_step` would quantize replaced by its
+    :class:`~repro.pim.quant.PlannedWeight` at ``cfg.pim_linear_bits``,
+    stacked like the float weight; the other leaves are the same objects.
+
+    The weights are those each block kind passes to
+    :func:`~repro.models.blocks.pim_proj` (:func:`~.blocks.pim_weights`)
+    under ``cfg.pim_scopes()``, and the LM head; a tied head is planned
+    as ``lm_head``, which :func:`decode_step` reads before ``embed.T``.
+    The identity when no linear runs in ``pim`` mode. Traceable, so
+    ``jax.eval_shape`` gives the plan's shapes.
+    """
+    from repro.pim.quant import plan_weight   # lazy: as head_matmul's
+
+    scopes = () if cfg.pim_linear_mode == "fake" else cfg.pim_scopes()
+    if not scopes:
+        return params
+    bits = cfg.pim_linear_bits
+    # One program a weight: op by op, each temporary is a weight's size.
+    plan_one = jax.jit(plan_weight, static_argnums=1)
+
+    def planned(p, path):
+        key, *rest = path
+        if key not in p:                  # e.g. w3 of a gelu MLP
+            return p
+        p = dict(p)
+        p[key] = planned(p[key], rest) if rest else plan_one(p[key], bits)
+        return p
+
+    def block(p, kind):
+        for scope, paths in pim_weights(cfg, kind).items():
+            if scope in scopes:
+                for path in paths:
+                    p = planned(p, path)
+        return p
+
+    prefix, unit, _, suffix = stack_plan(cfg)
+    out = dict(params)
+    for group, kinds in (("prefix", prefix), ("scan", unit),
+                         ("suffix", suffix)):
+        out[group] = [block(p, k) for p, k in zip(params[group], kinds)]
+    if "head" in scopes:
+        head = (params["lm_head"] if "lm_head" in params
+                else params["embed"].T)
+        out["lm_head"] = plan_one(head, bits)
+    return out
 
 
 # ------------------------------------------------------------- encoder ----

@@ -11,7 +11,8 @@ Four pieces, one import surface:
   streaming histograms; ``obs.dump()`` snapshots everything (a superset
   of ``Engine.stats()``), ``obs.write_metrics(path)`` saves it.
   Always on: recording a counter or latency sample is cheap enough to
-  not need a switch.
+  not need a switch. :data:`WEIGHT_PLANS` and :data:`PLAN_REUSES` name
+  the serve step's counters of weight plans made and reused.
 * **Waterfall** (:mod:`.waterfall`) — modeled-cycle counter tracks
   (partition occupancy, gate activity, switching) derived from compiled
   programs, merged into the same trace file; plus the
@@ -71,9 +72,16 @@ __all__ = [
     "scope", "SCOPES", "KV_CACHE", "ATTENTION", "PIM_QUANTIZE",
     "PIM_MATMUL", "CONTAINER", "COMPILES", "register_program",
     "device_scopes", "watch_compiles",
+    # counter names
+    "WEIGHT_PLANS", "PLAN_REUSES",
     # logging
     "setup_logging", "get_logger",
 ]
+
+
+# Counters of the weight-stationary serve step (repro.train.ServeStep).
+WEIGHT_PLANS = "pim.weight_plans"    # weight plans made
+PLAN_REUSES = "pim.plan_reuses"      # steps served from a stored plan
 
 
 # --------------------------------------------------------------- spans ----

@@ -5,17 +5,27 @@ per-channel affine quantization with an unsigned-offset trick so the
 in-memory multiplier sees non-negative operands (the standard deployment
 choice for PIM crossbars): ``q = clip(round(x/s) + 2^(n-1), 0, 2^n - 1)``
 and matmuls correct the offset analytically.
+
+Weights that stay put while serving are quantized once
+(:func:`plan_weight`) into a :class:`PlannedWeight`: centred codes
+``q - 2^(n-1)`` (``int8`` up to 8 bits) and per-column scales. Against
+it :func:`qmatmul_planned` forms :func:`qmatmul_exact`'s exact integer
+sum in one product, with no offset correction; :func:`qmatmul_exact`
+stays as the reference that the planned product is tested against.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro import obs
 
 __all__ = ["QTensor", "quantize", "dequantize", "qmatmul_exact",
-           "qragged_matmul_exact"]
+           "qragged_matmul_exact", "PlannedWeight", "plan_weight",
+           "qmatmul_planned"]
 
 
 class QTensor(NamedTuple):
@@ -25,10 +35,16 @@ class QTensor(NamedTuple):
     zero: int             # unsigned offset 2^(n-1)
 
 
+def _scale(x: jnp.ndarray, n_bits: int, axis) -> jnp.ndarray:
+    """The scale that maps the largest magnitude along ``axis`` (all of
+    ``x`` when None) to ``2^(n-1) - 1``."""
+    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
+    return jnp.maximum(amax, 1e-8) / (2 ** (n_bits - 1) - 1)
+
+
 def quantize(x: jnp.ndarray, n_bits: int = 8, axis=None) -> QTensor:
     with obs.scope(obs.PIM_QUANTIZE):
-        amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-        scale = jnp.maximum(amax, 1e-8) / (2 ** (n_bits - 1) - 1)
+        scale = _scale(x, n_bits, axis)
         zero = 2 ** (n_bits - 1)
         q = jnp.clip(jnp.round(x / scale) + zero, 0, 2 ** n_bits - 1)
         return QTensor(q.astype(jnp.int32), scale.astype(jnp.float32),
@@ -92,3 +108,61 @@ def qragged_matmul_exact(xq: QTensor, wq: QTensor,
                 + wq.zero * jnp.sum(xi, axis=-1, keepdims=True)
                 - k * xq.zero * wq.zero)
         return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
+
+
+@dataclasses.dataclass(frozen=True)
+class PlannedWeight:
+    """A weight quantized once, for :func:`qmatmul_planned`.
+
+    ``q`` holds the centred codes ``q - 2^(n-1)`` of :func:`quantize`
+    along the input axis (``int8`` for ``n_bits <= 8``, else ``int32``),
+    shaped like the float weight (layer-stacked ones too); ``scale`` the
+    ``float32`` per-column scales, ``(..., 1, out)``. A pytree whose
+    leaves are ``q`` and ``scale``; ``n_bits`` is static.
+    """
+    q: jnp.ndarray
+    scale: jnp.ndarray
+    n_bits: int
+
+    @property
+    def shape(self):
+        return self.q.shape
+
+
+jax.tree_util.register_dataclass(PlannedWeight, data_fields=["q", "scale"],
+                                 meta_fields=["n_bits"])
+
+
+def plan_weight(w: jnp.ndarray, n_bits: int) -> PlannedWeight:
+    """``w`` (..., in, out) float -> its :class:`PlannedWeight`: codes and
+    scales per column, each layer of a stack on its own, exactly what
+    :func:`quantize` with ``axis=0`` gives one (in, out) weight in the
+    same setting (traced, or op by op), less its offset."""
+    zero = 2 ** (n_bits - 1)
+    dtype = jnp.int8 if n_bits <= 8 else jnp.int32
+    with obs.scope(obs.PIM_QUANTIZE):
+        scale = _scale(w, n_bits, -2)
+        # Clipped in centred form: op by op, no int32 copy of the weight.
+        q = jnp.clip(jnp.round(w / scale), -zero, zero - 1).astype(dtype)
+        return PlannedWeight(q, scale.astype(jnp.float32), n_bits)
+
+
+def qmatmul_planned(xq: QTensor, w: PlannedWeight) -> jnp.ndarray:
+    """:func:`qmatmul_exact` against a planned weight.
+
+    ``sum (x - zx)(w - zw)`` is the corrected integer sum that
+    :func:`qmatmul_exact` forms; with both operands centred it is one
+    product (``int8 x int8 -> int32`` up to 8 bits). The scales apply
+    in the same order, so with the same codes and scales the result is
+    :func:`qmatmul_exact`'s.
+    """
+    if xq.n_bits != w.n_bits:
+        raise ValueError(f"activations at {xq.n_bits} bits, weight "
+                         f"planned at {w.n_bits}")
+    with obs.scope(obs.PIM_QUANTIZE):
+        xc = (xq.q - xq.zero).astype(w.q.dtype)
+    with obs.scope(obs.PIM_MATMUL):
+        prod = jax.lax.dot_general(
+            xc, w.q, (((xc.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        return prod.astype(jnp.float32) * xq.scale * w.scale

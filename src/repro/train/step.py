@@ -8,9 +8,13 @@ the per-layer remat scan this is what lets seq=4096 x batch=256 fit the
 16 GB/chip budget.
 
 ``make_serve_step``: one-token decode against a sharded KV cache
-(batch -> data, kv-heads -> model), cache buffers donated in place.
+(batch -> data, kv-heads -> model), cache buffers donated in place; the
+PIM linears' weights are quantized once per weight set
+(:class:`ServeStep`), not on every step.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,14 +22,17 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.models.model import Model
+from repro.models.transformer import plan_weights
 from repro.optim.adamw import (AdamWConfig, OptState, adamw_init,
                                adamw_update)
 from repro.optim.compress import ef_compress_tree
+from repro.pim import PlannedWeight
 
 from .sharding import (batch_shardings, param_shardings, state_shardings,
                        zero1_shardings, zero1_spec)
 
-__all__ = ["make_train_step", "make_serve_step", "make_prefill"]
+__all__ = ["make_train_step", "make_serve_step", "make_prefill",
+           "ServeStep"]
 
 
 def make_train_step(model: Model, opt_cfg: AdamWConfig, mesh, *,
@@ -112,8 +119,66 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig, mesh, *,
     return train_step, init_fn, jit_for
 
 
+class ServeStep:
+    """A jitted serve step behind a weight-stationary plan.
+
+    ``step(params, states, token, position)`` plans ``params``
+    (:func:`repro.models.transformer.plan_weights`) when their leaves are
+    not the objects it last planned from, else reuses the stored plan:
+    JAX arrays are immutable, so the same objects hold the same weights.
+    It then runs the jitted ``serve_step(plan, states, token, position)``.
+    It holds the params it planned from, and their plan. Counters:
+    ``pim.weight_plans`` (plans made) and ``pim.plan_reuses`` (calls
+    served from a stored plan).
+    """
+
+    def __init__(self, cfg, jitted, plan_shardings):
+        self._jitted = jitted
+        self._cfg = cfg
+        self._shardings = plan_shardings
+        self._source = None      # the leaves of the params last planned
+        self._plan = None
+        self._plans = obs.counter(obs.WEIGHT_PLANS)
+        self._reuses = obs.counter(obs.PLAN_REUSES)
+
+    def plan(self, params):
+        """The stored plan of ``params``, made anew if they changed."""
+        leaves = jax.tree_util.tree_leaves(params)
+        if (self._source is not None and len(leaves) == len(self._source)
+                and all(a is b for a, b in zip(leaves, self._source))):
+            self._reuses.inc()
+            return self._plan
+        self._plan = None                 # free the old plan first
+        self._plan = jax.tree.map(
+            lambda w, sh: (jax.device_put(w, sh)
+                           if isinstance(w, PlannedWeight) else w),
+            plan_weights(self._cfg, params), self._shardings,
+            is_leaf=lambda w: isinstance(w, PlannedWeight))
+        self._source = leaves
+        self._plans.inc()
+        return self._plan
+
+    def __call__(self, params, states, token, position):
+        return self._jitted(self.plan(params), states, token, position)
+
+    def lower(self, params_like, states_like, token, position):
+        """Lower the jitted step for ``params_like`` (arrays or
+        ``ShapeDtypeStruct`` leaves), planned in shapes only."""
+        return self._jitted.lower(_plan_like(self._cfg, params_like),
+                                 states_like, token, position)
+
+
+def _plan_like(cfg, params_like):
+    return jax.eval_shape(functools.partial(plan_weights, cfg), params_like)
+
+
 def make_serve_step(model: Model, mesh):
-    """Returns (serve_step, jit_for(params, states, batch))."""
+    """Returns (serve_step, jit_for(params, states, batch)).
+
+    ``serve_step(params, states, token, position)`` takes float or
+    planned params; ``jit_for`` returns a :class:`ServeStep` that plans
+    the weights once per weight set and jits ``serve_step`` on the plan.
+    """
 
     def serve_step(params, states, token, position):
         logits, states = model.decode_step(params, token, position, states)
@@ -121,7 +186,8 @@ def make_serve_step(model: Model, mesh):
         return next_tok, states
 
     def jit_for(params_like, states_like, batch_like):
-        ps = param_shardings(mesh, params_like)
+        plan_like = _plan_like(model.cfg, params_like)
+        ps = param_shardings(mesh, plan_like)
         ss = state_shardings(mesh, states_like)
         bs = batch_shardings(mesh, batch_like)
         step = jax.jit(
@@ -131,9 +197,9 @@ def make_serve_step(model: Model, mesh):
             donate_argnums=(1,),
         )
         # Shapes only, for obs.device_scopes() after a measured window.
-        obs.register_program(step, params_like, states_like,
+        obs.register_program(step, plan_like, states_like,
                              batch_like["token"], batch_like["position"])
-        return step
+        return ServeStep(model.cfg, step, ps)
     return serve_step, jit_for
 
 

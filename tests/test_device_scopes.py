@@ -15,6 +15,7 @@ from repro.configs import get_config
 from repro.engine import Engine
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
+from repro.models import plan_weights
 from repro.obs import scopes
 from repro.train import make_serve_step
 
@@ -126,6 +127,7 @@ def test_registration_compiles_nothing_and_records_no_span(
     obs.watch_compiles()
     compiles = obs.counter(obs.COMPILES)
     model, params = tiny_pim_decoder()
+    plan_weights(model.cfg, params)     # compiles once per weight shape
     tracer = obs.get_tracer()
     assert not tracer.enabled
     events_before = len(tracer)
