@@ -1,7 +1,7 @@
-from .base import ModelConfig, MoEConfig
+from .base import MLAConfig, ModelConfig, MoEConfig, RopeScaling
 from .registry import ARCHS, get_config
 from .shapes import SHAPES, ShapeSpec, cells_for, all_cells, shape_applicable
 
-__all__ = ["ModelConfig", "MoEConfig", "ARCHS", "get_config",
-           "SHAPES", "ShapeSpec", "cells_for", "all_cells",
+__all__ = ["ModelConfig", "MoEConfig", "MLAConfig", "RopeScaling", "ARCHS",
+           "get_config", "SHAPES", "ShapeSpec", "cells_for", "all_cells",
            "shape_applicable"]

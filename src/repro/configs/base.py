@@ -14,15 +14,56 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-__all__ = ["MoEConfig", "ModelConfig"]
+__all__ = ["MoEConfig", "MLAConfig", "RopeScaling", "ModelConfig"]
 
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int             # the router's outputs
     top_k: int
     n_shared: int = 0
     d_ff_dense: int = 0        # dense layers inside a MoE stack ('d')
+    # "topk_softmax": softmax over the top-k logits (renormalized gates);
+    # "softmax": softmax over every routed expert, the top-k
+    # probabilities are the gates as they are (DeepSeekMoE, DeepSeek-V2).
+    scoring: str = "topk_softmax"
+    # The chip's share under expert parallelism: this layer holds experts
+    # [expert_offset, expert_offset + experts_held) and computes only the
+    # picks that land there (None: all of them).
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come
+    from one ``kv_lora_rank`` latent per token, and a ``qk_rope_head_dim``
+    rotary key shared by every head."""
+    kv_lora_rank: int
+    q_lora_rank: Optional[int]         # None: a plain query projection
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (``rope_scaling`` with ``type: yarn``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -43,6 +84,9 @@ class ModelConfig:
     softcap_attn: Optional[float] = None
     softcap_final: Optional[float] = None
     moe: Optional[MoEConfig] = None
+    # latent attention for the 'd'/'m' blocks, and YaRN rope scaling
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[RopeScaling] = None
     # enc-dec (whisper): encoder consumes precomputed frame embeddings
     enc_layers: int = 0
     enc_frames: int = 1500
@@ -105,15 +149,29 @@ class ModelConfig:
         kinds = set(self.layer_kinds())
         return (kinds <= {"r", "l"} and self.family not in ("encdec", "vlm"))
 
+    def attn_param_count(self) -> int:
+        """Weights of one attention block's projections."""
+        d = self.d_model
+        if self.mla is not None:
+            a = self.mla
+            return (d * self.n_heads * a.qk_head_dim
+                    + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                    + a.kv_lora_rank * (1 + self.n_heads
+                                        * (a.qk_nope_head_dim
+                                           + a.v_head_dim))
+                    + self.n_heads * a.v_head_dim * d)
+        return d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks)."""
-        d, hd = self.d_model, self.hd
+        """Approximate parameter count (embeddings + blocks); a MoE layer
+        counts the experts it holds."""
+        d = self.d_model
         nm = 3 if self.mlp_type == "swiglu" else 2
         n = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         kinds = self.layer_kinds()
         for k in kinds:
             if k in ("g", "l"):
-                n += d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+                n += self.attn_param_count()
                 n += nm * d * self.d_ff
             elif k == "r":
                 if self.family == "rwkv":
@@ -122,12 +180,12 @@ class ModelConfig:
                     n += 2 * d * d + 3 * d + 3 * d * self.d_ff
             elif k == "m":
                 e = self.moe
-                n += d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
-                n += (e.n_experts + e.n_shared) * nm * d * self.d_ff
+                n += self.attn_param_count()
+                n += (e.held + e.n_shared) * nm * d * self.d_ff
                 n += d * e.n_experts
             elif k == "d":
                 e = self.moe
-                n += d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+                n += self.attn_param_count()
                 n += nm * d * (e.d_ff_dense or self.d_ff)
             n += 2 * d  # norms
         if self.enc_layers:
@@ -149,12 +207,19 @@ class ModelConfig:
             moe = dataclasses.replace(
                 moe, n_experts=min(4, moe.n_experts),
                 top_k=min(2, moe.top_k), n_shared=min(1, moe.n_shared),
-                d_ff_dense=64 if moe.d_ff_dense else 0)
+                d_ff_dense=64 if moe.d_ff_dense else 0,
+                experts_held=None if moe.experts_held is None
+                else min(2, moe.experts_held), expert_offset=0)
+        mla = self.mla
+        if mla is not None:
+            mla = MLAConfig(kv_lora_rank=32, q_lora_rank=None,
+                            qk_nope_head_dim=16, qk_rope_head_dim=8,
+                            v_head_dim=16)
         return dataclasses.replace(
             self, name=self.name + "-smoke", n_layers=layers,
             layer_pattern=pattern, d_model=64, n_heads=4,
             n_kv_heads=min(4, max(1, self.n_kv_heads)),
             head_dim=16, d_ff=128, vocab_size=256, window=32,
             enc_layers=min(2, self.enc_layers), enc_frames=8,
-            n_patches=min(4, self.n_patches), moe=moe,
+            n_patches=min(4, self.n_patches), moe=moe, mla=mla,
             rwkv_head_dim=16)

@@ -12,13 +12,14 @@ from .recurrentgemma_9b import CONFIG as _recurrentgemma_9b
 from .whisper_small import CONFIG as _whisper_small
 from .phi35_moe import CONFIG as _phi35_moe
 from .deepseek_moe_16b import CONFIG as _deepseek_moe_16b
+from .deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 from .pixtral_12b import CONFIG as _pixtral_12b
 from .rwkv6_7b import CONFIG as _rwkv6_7b
 
 ARCHS: Dict[str, ModelConfig] = {c.name: c for c in [
     _deepseek_7b, _qwen3_8b, _granite_20b, _gemma2_9b,
     _recurrentgemma_9b, _whisper_small, _phi35_moe,
-    _deepseek_moe_16b, _pixtral_12b, _rwkv6_7b,
+    _deepseek_moe_16b, _pixtral_12b, _rwkv6_7b, _deepseek_v2_lite,
 ]}
 
 

@@ -27,8 +27,22 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma2-9b \
       --shape train_4k [--multi-pod] [--out results.json]
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
+
+One chip's share of a deployment (``--one-chip``: a 1 x 1 mesh) at its
+own batch and cache, with ModelConfig overrides (``moe`` takes a dict
+of MoEConfig fields), e.g. a DeepSeek-V2-Lite pipeline stage of 9
+layers holding 8 of each layer's 64 experts, served with PIM FFNs:
+  PYTHONPATH=src python -m repro.launch.dryrun --arch deepseek-v2-lite \
+      --shape decode_32k --one-chip --batch 32 --seq-len 4096 \
+      --dtype float32 \
+      --override '{"n_layers": 9, "moe": {"experts_held": 8},
+                   "pim_linear_mode": "pim", "pim_block_mode": "ffn"}'
+A decode cell's ``per_device`` gives the decode state's bytes
+(``state_bytes``: KV caches, latents, recurrent state) beside the float
+weights a PIM serve step keeps beside its plan (``kept_float_bytes``).
 """
 import argparse
+import dataclasses
 import functools
 import json
 import math
@@ -36,7 +50,7 @@ import re
 import sys
 import time
 import traceback
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +61,7 @@ from repro.configs import ARCHS, SHAPES, get_config, shape_applicable
 # No logging side effects at import time: handlers attach only when
 # main() calls obs.setup_logging() (see repro.obs.logging).
 log = obs.get_logger("dryrun")
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_one_chip_mesh, make_production_mesh
 from repro.models.model import build_model, input_specs
 from repro.optim.adamw import AdamWConfig, OptState
 from repro.train.sharding import (batch_shardings, param_shardings,
@@ -113,21 +127,39 @@ def _fmt_bytes(n: float) -> str:
     return f"{n:.2f}EB"
 
 
-def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
-               verbose: bool = True) -> Dict[str, Any]:
+def configure(arch: str, overrides: Optional[Dict[str, Any]] = None):
+    """``arch``'s config with ModelConfig ``overrides`` (``moe``: a dict
+    of MoEConfig fields)."""
     cfg = get_config(arch)
+    over = dict(overrides or {})
+    if isinstance(over.get("moe"), dict):
+        over["moe"] = dataclasses.replace(cfg.moe, **over["moe"])
+    return dataclasses.replace(cfg, **over) if over else cfg
+
+
+def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
+               verbose: bool = True, one_chip: bool = False,
+               batch: Optional[int] = None, seq_len: Optional[int] = None,
+               overrides: Optional[Dict[str, Any]] = None,
+               dtype=jnp.bfloat16) -> Dict[str, Any]:
+    cfg = configure(arch, overrides)
     shape = next(s for s in SHAPES if s.name == shape_name)
+    shape = dataclasses.replace(shape, global_batch=batch or
+                                shape.global_batch,
+                                seq_len=seq_len or shape.seq_len)
     ok, why = shape_applicable(cfg, shape)
     if not ok:
         return {"arch": arch, "shape": shape_name, "status": "skipped",
                 "reason": why}
-    mesh = make_production_mesh(multi_pod=multi_pod)
+    mesh = (make_one_chip_mesh() if one_chip
+            else make_production_mesh(multi_pod=multi_pod))
     t0 = time.time()
     model = build_model(cfg, remat=(shape.kind == "train"))
+    state = 0
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
     specs = input_specs(cfg, shape)
-    params_like = jax.eval_shape(lambda k: model.init(k, jnp.bfloat16),
+    params_like = jax.eval_shape(lambda k: model.init(k, dtype),
                                  jax.random.PRNGKey(0))
     ps = param_shardings(mesh, params_like)
     params_like = jax.tree.map(
@@ -174,7 +206,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         serve_step, jit_for = make_serve_step(model, mesh)
         states_like = jax.eval_shape(
             lambda: model.init_decode_state(shape.global_batch,
-                                            shape.seq_len, jnp.bfloat16))
+                                            shape.seq_len, dtype))
         ss = state_shardings(mesh, states_like)
         states_like = jax.tree.map(
             lambda s_, sh: jax.ShapeDtypeStruct(s_.shape, s_.dtype,
@@ -189,6 +221,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         lowered = jitted.lower(params_like, states_like,
                                batch_like["token"], batch_like["position"])
         kept = _kept_float_bytes(model.cfg, params_like)
+        state = _shard_bytes(states_like)
 
     t_lower = time.time() - t0
     compiled = lowered.compile()
@@ -205,7 +238,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     coll = collective_bytes(compiled.as_text())
     rec = {
         "arch": arch, "shape": shape_name,
-        "mesh": "2x16x16" if multi_pod else "16x16",
+        "mesh": "1x1" if one_chip else "2x16x16" if multi_pod else "16x16",
         "status": "ok",
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "flops": cost.get("flops", -1.0),
@@ -215,6 +248,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             "output_bytes": getattr(mem, "output_size_in_bytes", 0),
             "temp_bytes": getattr(mem, "temp_size_in_bytes", 0),
             "kept_float_bytes": kept,
+            "state_bytes": state,
             "peak_bytes": (getattr(mem, "argument_size_in_bytes", 0)
                            + getattr(mem, "temp_size_in_bytes", 0)
                            + kept),
@@ -233,6 +267,12 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     return rec
 
 
+def _shard_bytes(tree) -> int:
+    """Per-device bytes of the sharded ShapeDtypeStructs in ``tree``."""
+    return sum(math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
+               for s in jax.tree.leaves(tree))
+
+
 def _kept_float_bytes(cfg, params_like) -> int:
     """Per-device bytes of the float weights a serve step keeps beside
     their plan: the step's arguments hold the plan, and ``ServeStep``
@@ -246,9 +286,8 @@ def _kept_float_bytes(cfg, params_like) -> int:
     plan = jax.eval_shape(functools.partial(plan_weights, cfg), params_like)
     planned = {path for path, w in jax.tree_util.tree_flatten_with_path(
         plan, is_leaf=is_plan)[0] if is_plan(w)}
-    return sum(math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
-               for path, s in jax.tree_util.tree_flatten_with_path(
-                   params_like)[0] if path in planned)
+    return _shard_bytes([s for path, s in jax.tree_util.tree_flatten_with_path(
+        params_like)[0] if path in planned])
 
 
 def main() -> None:
@@ -259,7 +298,23 @@ def main() -> None:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--out", default="dryrun_results.json")
+    ap.add_argument("--one-chip", action="store_true",
+                    help="a 1 x 1 mesh: one chip's share")
+    ap.add_argument("--batch", type=int, default=None,
+                    help="the shape's global batch, overridden")
+    ap.add_argument("--seq-len", type=int, default=None,
+                    help="the shape's sequence (cache) length, overridden")
+    ap.add_argument("--dtype", default="bfloat16",
+                    choices=("bfloat16", "float32"),
+                    help="weights' and decode state's dtype")
+    ap.add_argument("--override", default="",
+                    help="JSON dict of ModelConfig overrides; 'moe' takes "
+                         "a dict of MoEConfig fields")
     args = ap.parse_args()
+    cell_kw = dict(one_chip=args.one_chip, batch=args.batch,
+                   seq_len=args.seq_len,
+                   overrides=json.loads(args.override or "{}"),
+                   dtype=jnp.dtype(args.dtype))
     obs.setup_logging()
 
     cells = []
@@ -277,7 +332,8 @@ def main() -> None:
     for arch, shp in cells:
         for mp in meshes:
             try:
-                results.append(lower_cell(arch, shp, multi_pod=mp))
+                results.append(lower_cell(arch, shp, multi_pod=mp,
+                                          **cell_kw))
             except Exception as e:   # noqa: BLE001
                 failed += 1
                 traceback.print_exc()

@@ -11,7 +11,8 @@ from typing import Optional, Tuple
 
 import jax
 
-__all__ = ["make_production_mesh", "make_host_mesh", "dp_axes", "tp_axis"]
+__all__ = ["make_production_mesh", "make_host_mesh", "make_one_chip_mesh",
+           "dp_axes", "tp_axis"]
 
 
 def _auto(n_axes: int):
@@ -34,6 +35,12 @@ def make_host_mesh(model_parallel: int = 1):
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
                          ("data", "model"), axis_types=_auto(2))
+
+
+def make_one_chip_mesh():
+    """A 1 x 1 (data, model) mesh on the first device: one chip's share."""
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2),
+                         devices=jax.devices()[:1])
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

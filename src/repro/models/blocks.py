@@ -11,6 +11,9 @@ Every block is a pair of pure functions:
 
 * attention ('g'/'l'): :class:`repro.models.attention.KVCache`
   (+ a cross-attention KV pair for enc-dec decoders)
+* latent attention ('m'/'d' of a config with ``cfg.mla``):
+  :class:`repro.models.attention.LatentCache`, the normed latent and
+  the shared rope key per position instead of per-head K/V
 * RG-LRU ('r', hybrid): {"h": (B, D), "conv": (B, 3, D)}
 * RWKV-6 ('r', rwkv): {"wkv": (B, H, dh, dh), "tshift"/"cshift": (B, D)}
 * MoE ('m'/'d'): same as attention (the FFN is stateless).
@@ -18,7 +21,9 @@ Every block is a pair of pure functions:
 MoE dispatch is dropless sort->grouped-GEMM->gather (ragged per-expert
 segments via ``jax.lax.ragged_dot``; the expert weight stacks shard over
 the 'model' axis as (E, D, F)). Dropless keeps the layer
-token-independent, so prefill and decode agree bit-for-bit.
+token-independent, so prefill and decode agree bit-for-bit. A layer told
+its share (``MoEConfig.experts_held``/``expert_offset``) routes over
+every expert and computes the picks that land in the experts it holds.
 """
 from __future__ import annotations
 
@@ -30,8 +35,9 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs.base import ModelConfig
 
-from .attention import KVCache, attend, decode_attend
-from .layers import Initializer, rms_norm, rope
+from .attention import (KVCache, LatentCache, attend, decode_attend,
+                        latent_decode_attend)
+from .layers import Initializer, rms_norm, rope, yarn_inv_freq, yarn_mscale
 
 __all__ = ["init_block", "apply_block", "init_state", "pim_proj",
            "pim_weights"]
@@ -71,11 +77,13 @@ def pim_weights(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
     decode step on the plan and fails on a float weight in ``pim`` mode."""
     def mlp(key):
         return tuple((key, n) for n in _MLP)
+    attn = ("wq", "wk", "wv", "wo", "xq", "xk", "xv", "xo")
+    if cfg.mla is not None and kind in ("m", "d"):
+        attn = ("wq", "wkv_a", "wo")      # wkv_b is absorbed, digital
     if kind in ("g", "l", "d"):
-        attn = ("wq", "wk", "wv", "wo", "xq", "xk", "xv", "xo")
         return {"attn": tuple((n,) for n in attn), "ffn": mlp("mlp")}
-    if kind == "m":                       # its "wo" is a plain matmul
-        return {"attn": (("wq",), ("wk",), ("wv",)), "ffn": mlp("shared")}
+    if kind == "m":
+        return {"attn": tuple((n,) for n in attn), "ffn": mlp("shared")}
     if kind == "r" and cfg.family != "rwkv":
         return {"ffn": mlp("mlp")}
     return {}
@@ -94,8 +102,11 @@ def _pim_ragged(cfg: ModelConfig, xs: jnp.ndarray, we: jnp.ndarray,
 
 
 # ============================================================ attention ====
-def _init_attn_core(cfg: ModelConfig, ini: Initializer) -> Dict[str, Any]:
+def _init_attn_core(cfg: ModelConfig, ini: Initializer,
+                    kind: str = "g") -> Dict[str, Any]:
     d = cfg.d_model
+    if cfg.mla is not None and kind in ("m", "d"):
+        return _init_mla(cfg, ini)
     p = {
         "wq": ini(d, cfg.q_dim, scale=d ** -0.5),
         "wk": ini(d, cfg.kv_dim, scale=d ** -0.5),
@@ -130,7 +141,7 @@ def _apply_mlp(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray):
 def init_attn_block(cfg: ModelConfig, ini: Initializer, kind: str,
                     d_ff: Optional[int] = None) -> Dict[str, Any]:
     p = {"ln1": ini.zeros(cfg.d_model), "ln2": ini.zeros(cfg.d_model)}
-    p.update(_init_attn_core(cfg, ini))
+    p.update(_init_attn_core(cfg, ini, kind))
     p["mlp"] = _init_mlp(cfg, ini, d_ff or cfg.d_ff)
     if cfg.family == "encdec":
         d = cfg.d_model
@@ -158,25 +169,121 @@ def _qkv(cfg: ModelConfig, p, xn, pos):
     return q, k, v
 
 
-def _prefill_cache(state, k, v):
-    """The block's decode state with the prompt's K/V left behind."""
-    s = k.shape[1]
-    t = state["self"]["k"].shape[1]
+def _prefill_cache(state, **entries):
+    """The block's decode state with the prompt's entries (``k``/``v``,
+    or the latent ``c``/``kpe``; (B, S, ...) each) left behind."""
+    s = next(iter(entries.values())).shape[1]
     with obs.scope(obs.KV_CACHE):
-        kc, vc = k, v
-        if s < t:
-            kc = jnp.pad(k, ((0, 0), (0, t - s), (0, 0), (0, 0)))
-            vc = jnp.pad(v, ((0, 0), (0, t - s), (0, 0), (0, 0)))
-        elif s > t:            # windowed: keep the most recent slice,
-            # rotated so token j sits at ring slot j % t.
-            kc = jnp.roll(k[:, -t:], s % t, axis=1)
-            vc = jnp.roll(v[:, -t:], s % t, axis=1)
+        cache = {}
+        for name, a in entries.items():
+            t = state["self"][name].shape[1]
+            if s < t:
+                a = jnp.pad(a, ((0, 0), (0, t - s)) + ((0, 0),) * (a.ndim - 2))
+            elif s > t:        # windowed: keep the most recent slice,
+                # rotated so token j sits at ring slot j % t.
+                a = jnp.roll(a[:, -t:], s % t, axis=1)
+            cache[name] = a.astype(state["self"][name].dtype)
+        cache["length"] = jnp.asarray(s, jnp.int32)
         new_state = dict(state)
-        new_state["self"] = {
-            "k": kc.astype(state["self"]["k"].dtype),
-            "v": vc.astype(state["self"]["v"].dtype),
-            "length": jnp.asarray(s, jnp.int32)}
+        new_state["self"] = cache
     return new_state
+
+
+# ------------------------------------------------- latent attention (MLA) --
+def _init_mla(cfg: ModelConfig, ini: Initializer) -> Dict[str, Any]:
+    a, d, h = cfg.mla, cfg.d_model, cfg.n_heads
+    if a.q_lora_rank is not None:
+        raise NotImplementedError("MLA with a compressed query "
+                                  "(q_lora_rank) is not implemented")
+    r = a.kv_lora_rank
+    o_in = h * a.v_head_dim
+    return {
+        "wq": ini(d, h * a.qk_head_dim, scale=d ** -0.5),
+        "wkv_a": ini(d, r + a.qk_rope_head_dim, scale=d ** -0.5),
+        "kv_norm": ini.zeros(r),
+        "wkv_b": ini(r, h * (a.qk_nope_head_dim + a.v_head_dim),
+                     scale=r ** -0.5),
+        "wo": ini(o_in, d, scale=(o_in * 2 * cfg.n_layers) ** -0.5),
+    }
+
+
+def mla_rope(cfg: ModelConfig):
+    """-> (rope frequencies or None, rope output factor, softmax scale)
+    of latent attention; with YaRN, HF's ``DeepseekV2Attention``: the
+    softmax scale is ``qk_head_dim^-1/2 * m^2`` with ``m`` the magnitude
+    at ``mscale_all_dim``, and cos/sin carry ``m(mscale) / m(all)``."""
+    a, rs = cfg.mla, cfg.rope_scaling
+    scale = a.qk_head_dim ** -0.5
+    if rs is None:
+        return None, 1.0, scale
+    inv = yarn_inv_freq(a.qk_rope_head_dim, cfg.rope_theta, rs.factor,
+                        rs.original_max_position, rs.beta_fast,
+                        rs.beta_slow)
+    m_all = yarn_mscale(rs.factor, rs.mscale_all_dim)
+    return inv, yarn_mscale(rs.factor, rs.mscale) / m_all, scale * m_all ** 2
+
+
+def _mla_attention(cfg: ModelConfig, p, xn, pos, state, mode):
+    """Latent attention of the normed ``xn`` (B, S, D) -> (o (B, S,
+    H * v_head_dim), new_state). The prefill decompresses per-head keys
+    and values from the latent; decode attends over the cached latents
+    in the absorbed form (:func:`~.attention.latent_decode_attend`)."""
+    a, h = cfg.mla, cfg.n_heads
+    dn, dr, r = a.qk_nope_head_dim, a.qk_rope_head_dim, a.kv_lora_rank
+    b, s, _ = xn.shape
+    inv_freq, m, scale = mla_rope(cfg)
+
+    def roped(x):
+        y = rope(x, pos, cfg.rope_theta, inv_freq)
+        return y if m == 1.0 else y * m
+
+    q = pim_proj(cfg, xn, p["wq"], scope="attn").reshape(b, s, h, dn + dr)
+    q_nope, q_pe = q[..., :dn], roped(q[..., dn:])
+    ckv = pim_proj(cfg, xn, p["wkv_a"], scope="attn")
+    c = rms_norm(ckv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = roped(ckv[..., None, r:])                       # (B, S, 1, dr)
+    wkv_b = p["wkv_b"].reshape(r, h, dn + a.v_head_dim)
+    new_state = state
+    if mode == "decode":
+        o, cache = latent_decode_attend(
+            q_nope, q_pe, LatentCache(**state["self"]), c, k_pe[:, :, 0],
+            wkv_b[..., :dn], wkv_b[..., dn:], scale=scale)
+        new_state = dict(state)
+        new_state["self"] = cache._asdict()
+    else:
+        kv = jnp.einsum("bsr,rhd->bshd", c, wkv_b)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, h, dr))], axis=-1)
+        o = attend(jnp.concatenate([q_nope, q_pe], axis=-1), k,
+                   kv[..., dn:], causal=True, cap=cfg.softcap_attn,
+                   scale=scale)
+        if state is not None:         # prefill: leave the latents behind
+            new_state = _prefill_cache(state, c=c, kpe=k_pe[:, :, 0])
+    return o.reshape(b, s, h * a.v_head_dim), new_state
+
+
+def _self_attention(cfg: ModelConfig, p, x, *, pos, state, mode,
+                    window=None, kind="g"):
+    """``x`` plus the block's self-attention sublayer -> (x, new_state)."""
+    b, s, _ = x.shape
+    xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+    if cfg.mla is not None and kind in ("m", "d"):
+        o, new_state = _mla_attention(cfg, p, xn, pos, state, mode)
+    else:
+        q, k, v = _qkv(cfg, p, xn, pos)
+        new_state = state
+        if mode in ("full", "encode"):
+            o = attend(q, k, v, causal=(mode != "encode"), window=window,
+                       cap=cfg.softcap_attn)
+            if state is not None:     # prefill: leave the KV behind
+                new_state = _prefill_cache(state, k=k, v=v)
+        else:
+            o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
+                                     window=window, cap=cfg.softcap_attn)
+            new_state = dict(state)
+            new_state["self"] = cache._asdict()
+        o = o.reshape(b, s, cfg.q_dim)
+    return x + pim_proj(cfg, o, p["wo"], scope="attn"), new_state
 
 
 def apply_attn_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode,
@@ -184,21 +291,8 @@ def apply_attn_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode,
     b, s, d = x.shape
     window = cfg.window if kind == "l" else None
     with obs.scope(obs.ATTENTION):
-        xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, p, xn, pos)
-        new_state = state
-        if mode in ("full", "encode"):
-            o = attend(q, k, v, causal=(mode != "encode"), window=window,
-                       cap=cfg.softcap_attn)
-            if state is not None:     # prefill: leave the KV behind
-                new_state = _prefill_cache(state, k, v)
-        else:
-            o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
-                                     window=window, cap=cfg.softcap_attn)
-            new_state = dict(state)
-            new_state["self"] = cache._asdict()
-        x = x + pim_proj(cfg, o.reshape(b, s, cfg.q_dim), p["wo"],
-                         scope="attn")
+        x, new_state = _self_attention(cfg, p, x, pos=pos, state=state,
+                                       mode=mode, window=window, kind=kind)
 
         if cfg.family == "encdec" and enc_out is not None:
             xn2 = rms_norm(x, p["lnx"], cfg.norm_eps)
@@ -222,11 +316,11 @@ def init_moe_block(cfg: ModelConfig, ini: Initializer) -> Dict[str, Any]:
     e = cfg.moe
     d, f = cfg.d_model, cfg.d_ff
     p = {"ln1": ini.zeros(d), "ln2": ini.zeros(d)}
-    p.update(_init_attn_core(cfg, ini))
-    p["router"] = ini(d, e.n_experts, scale=d ** -0.5)
-    p["we1"] = ini(e.n_experts, d, f, scale=d ** -0.5)
-    p["we3"] = ini(e.n_experts, d, f, scale=d ** -0.5)
-    p["we2"] = ini(e.n_experts, f, d, scale=(f * 2 * cfg.n_layers) ** -0.5)
+    p.update(_init_attn_core(cfg, ini, "m"))
+    p["router"] = ini(d, e.n_experts, scale=d ** -0.5)   # every expert
+    p["we1"] = ini(e.held, d, f, scale=d ** -0.5)         # the held ones
+    p["we3"] = ini(e.held, d, f, scale=d ** -0.5)
+    p["we2"] = ini(e.held, f, d, scale=(f * 2 * cfg.n_layers) ** -0.5)
     if e.n_shared:
         p["shared"] = _init_mlp(cfg, ini, f * e.n_shared)
     return p
@@ -253,6 +347,24 @@ def moe_ffn(cfg: ModelConfig, p, x3: jnp.ndarray) -> jnp.ndarray:
     return _moe_ffn_chunk(cfg, p, x3.reshape(b * s, d)).reshape(b, s, d)
 
 
+def route(cfg: ModelConfig, p, x2: jnp.ndarray):
+    """(T, D) -> gates (T, k) and expert ids (T, k) over every routed
+    expert, by ``cfg.moe.scoring``: ``"softmax"`` takes the top-k of the
+    softmax over all experts as the gates (DeepSeek's, unrenormalized);
+    ``"topk_softmax"`` softmaxes the top-k logits (gates sum to 1)."""
+    e = cfg.moe
+    logits = x2 @ p["router"]
+    if e.scoring == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        gate, idx = jax.lax.top_k(probs, e.top_k)
+    elif e.scoring == "topk_softmax":
+        gate, idx = jax.lax.top_k(logits, e.top_k)
+        gate = jax.nn.softmax(gate.astype(jnp.float32), axis=-1)
+    else:
+        raise ValueError(f"unknown MoE scoring {e.scoring!r}")
+    return gate.astype(x2.dtype), idx
+
+
 def _moe_ffn_chunk(cfg: ModelConfig, p, x2: jnp.ndarray) -> jnp.ndarray:
     """Dropless dispatch: sort token-expert pairs by expert, then grouped
     GEMMs over the ragged per-expert segments (``jax.lax.ragged_dot``).
@@ -264,43 +376,43 @@ def _moe_ffn_chunk(cfg: ModelConfig, p, x2: jnp.ndarray) -> jnp.ndarray:
     is computed, so the layer is token-independent and prefill ==
     decode exactly. Memory stays O(T*k) activations — same order as the
     old (E, C, D) capacity buffers at capacity factor 1.25.
+
+    The layer holds experts ``[expert_offset, expert_offset + held)``:
+    picks of other experts sort last, outside every segment, and are
+    zero in the grouped GEMMs' inputs and outputs, so what the absent
+    experts would add is left out (and a PIM call's activation scale is
+    over the held experts' rows alone).
     """
     e = cfg.moe
     t, d = x2.shape
-    logits = x2 @ p["router"]
-    gate, idx = jax.lax.top_k(logits, e.top_k)            # (T, k)
-    gate = jax.nn.softmax(gate.astype(jnp.float32), axis=-1).astype(x2.dtype)
-
-    flat_e = idx.reshape(-1)                               # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(t), e.top_k)
-    order = jnp.argsort(flat_e)                            # stable
-    st, sg = flat_t[order], gate.reshape(-1)[order]
-    counts = jnp.bincount(flat_e, length=e.n_experts).astype(jnp.int32)
-
-    xs = x2[st]                                            # (T*k, d)
-    h = _pim_ragged(cfg, xs, p["we1"], counts)
-    h3 = _pim_ragged(cfg, xs, p["we3"], counts)
-    y = _pim_ragged(cfg, jax.nn.silu(h) * h3, p["we2"], counts)
-    out = jnp.zeros_like(x2).at[st].add(y * sg[:, None])
+    held = e.held
+    with obs.scope(obs.MOE_ROUTE):
+        gate, idx = route(cfg, p, x2)                      # (T, k)
+        local = idx.reshape(-1) - e.expert_offset          # (T*k,)
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        flat_t = jnp.repeat(jnp.arange(t), e.top_k)
+        order = jnp.argsort(key)                           # stable
+        st, sg, sm = flat_t[order], gate.reshape(-1)[order], mine[order]
+        counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        sm = sm[:, None]
+        xs = jnp.where(sm, x2[st], 0)                      # (T*k, d)
+    with obs.scope(obs.MOE_EXPERTS):
+        h = _pim_ragged(cfg, xs, p["we1"], counts)
+        h3 = _pim_ragged(cfg, xs, p["we3"], counts)
+        g = jnp.where(sm, jax.nn.silu(h) * h3, 0)
+        y = jnp.where(sm, _pim_ragged(cfg, g, p["we2"], counts), 0)
+    with obs.scope(obs.MOE_ROUTE):
+        out = jnp.zeros_like(x2).at[st].add(y * sg[:, None])
     if e.n_shared:
         out = out + _apply_mlp(cfg, p["shared"], x2)
     return out
 
 
 def apply_moe_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode):
-    b, s, d = x.shape
     with obs.scope(obs.ATTENTION):
-        xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, p, xn, pos)
-        new_state = state
-        if mode == "full":
-            o = attend(q, k, v, causal=True, cap=cfg.softcap_attn)
-        else:
-            o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
-                                     cap=cfg.softcap_attn)
-            new_state = dict(state)
-            new_state["self"] = cache._asdict()
-        x = x + (o.reshape(b, s, cfg.q_dim) @ p["wo"])
+        x, new_state = _self_attention(cfg, p, x, pos=pos, state=state,
+                                       mode=mode, kind="m")
     xn2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     return x + moe_ffn(cfg, p, xn2), new_state
 
@@ -459,7 +571,7 @@ def init_block(cfg: ModelConfig, ini: Initializer, kind: str):
     if kind == "m":
         return init_moe_block(cfg, ini)
     if kind == "d":
-        return init_attn_block(cfg, ini, "g",
+        return init_attn_block(cfg, ini, "d",
                                d_ff=cfg.moe.d_ff_dense or cfg.d_ff)
     if kind == "r":
         return (init_rwkv_block(cfg, ini) if cfg.family == "rwkv"
@@ -474,7 +586,7 @@ def apply_block(cfg: ModelConfig, kind: str, p, x, *, pos, state=None,
                                 enc_out=enc_out, mode=mode, kind=kind)
     if kind == "d":
         return apply_attn_block(cfg, p, x, pos=pos, state=state,
-                                enc_out=enc_out, mode=mode, kind="g")
+                                enc_out=enc_out, mode=mode, kind="d")
     if kind == "m":
         return apply_moe_block(cfg, p, x, pos=pos, state=state,
                                enc_out=enc_out, mode=mode)
@@ -488,6 +600,13 @@ def apply_block(cfg: ModelConfig, kind: str, p, x, *, pos, state=None,
 def init_state(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
                dtype=jnp.float32, enc_len: int = 0):
     """Zero decode-state for one block."""
+    if cfg.mla is not None and kind in ("m", "d"):
+        a = cfg.mla
+        return {"self": {
+            "c": jnp.zeros((batch, max(cache_len, 1), a.kv_lora_rank), dtype),
+            "kpe": jnp.zeros((batch, max(cache_len, 1), a.qk_rope_head_dim),
+                             dtype),
+            "length": jnp.zeros((), jnp.int32)}}
     if kind in ("g", "l", "m", "d"):
         t = cache_len if kind != "l" else min(cfg.window, cache_len)
         t = max(t, 1)

@@ -23,8 +23,11 @@ Four pieces, one import surface:
   ``kv_cache`` (the decode caches' reads and writes), ``attention``
   (projections, rope, scores, softmax, weighted sum), ``pim.quantize``
   (the PIM linears' quantize, dequantize and scale reductions) and
-  ``pim.matmul`` (their integer product and zero-point corrections);
-  the innermost wins where they nest. Scopes are ``jax.named_scope``
+  ``pim.matmul`` (their integer product and zero-point corrections),
+  ``moe.route`` (router, gates, top-k, sort, gather, combine) and
+  ``moe.experts`` (the held experts' grouped products with their
+  quantization); the innermost wins where they nest, save that
+  ``moe.experts`` holds the ``pim.*`` ops inside it. Scopes are ``jax.named_scope``
   names: they label HLO metadata only, so the executable is unchanged,
   and an operator sees them in XProf as each op's ``tf_op``.
   ``obs.register_program(jitted, *args)`` (done by
@@ -49,9 +52,9 @@ from typing import Optional
 from .logging import get_logger, setup_logging
 from .metrics import (Counter, Gauge, Histogram, Registry,
                       WindowedHistogram, get_registry)
-from .scopes import (ATTENTION, COMPILES, CONTAINER, KV_CACHE, PIM_MATMUL,
-                     PIM_QUANTIZE, SCOPES, device_scopes, register_program,
-                     scope, watch_compiles)
+from .scopes import (ATTENTION, COMPILES, CONTAINER, KV_CACHE, MOE_EXPERTS,
+                     MOE_ROUTE, PIM_MATMUL, PIM_QUANTIZE, SCOPES,
+                     device_scopes, register_program, scope, watch_compiles)
 from .trace import NULL_SPAN, PID_SPANS, Span, Tracer, get_tracer
 from .waterfall import (cycle_occupancy, switching_activity,
                         switching_profile, waterfall_events)
@@ -70,7 +73,7 @@ __all__ = [
     "waterfall_events",
     # device scopes
     "scope", "SCOPES", "KV_CACHE", "ATTENTION", "PIM_QUANTIZE",
-    "PIM_MATMUL", "CONTAINER", "COMPILES", "register_program",
+    "PIM_MATMUL", "MOE_ROUTE", "MOE_EXPERTS", "CONTAINER", "COMPILES", "register_program",
     "device_scopes", "watch_compiles",
     # counter names
     "WEIGHT_PLANS", "PLAN_REUSES",

@@ -6,7 +6,10 @@ labels the ops' HLO metadata (``op_name=".../kv_cache/..."``), so the
 compiled program runs the same with or without the labels. Where scopes
 nest, the innermost name from the vocabulary wins: the cache write
 inside the attention block counts as :data:`KV_CACHE`, and a PIM
-linear's quantisation inside it as :data:`PIM_QUANTIZE`.
+linear's quantisation inside it as :data:`PIM_QUANTIZE`. One exception:
+inside :data:`MOE_EXPERTS` the ``pim.*`` ops of the experts' grouped
+products count as :data:`MOE_EXPERTS`, so ``pim.*`` stays the dense
+linears'.
 
 A jitted program is registered with :func:`register_program` (the
 function and the abstract shapes of its arguments; nothing on the
@@ -39,7 +42,7 @@ from .metrics import get_registry
 from .trace import get_tracer
 
 __all__ = ["KV_CACHE", "ATTENTION", "PIM_QUANTIZE", "PIM_MATMUL",
-           "SCOPES", "CONTAINER", "scope", "scope_of", "hlo_scopes",
+           "MOE_ROUTE", "MOE_EXPERTS", "SCOPES", "CONTAINER", "scope", "scope_of", "hlo_scopes",
            "register_program", "device_scopes", "watch_compiles",
            "COMPILE_EVENT", "COMPILES"]
 
@@ -47,7 +50,10 @@ KV_CACHE = "kv_cache"          # reads and writes of the decode caches
 ATTENTION = "attention"        # projections, rope, scores, softmax, sum
 PIM_QUANTIZE = "pim.quantize"  # PIM linears' quantize, dequantize, scales
 PIM_MATMUL = "pim.matmul"      # PIM linears' integer product, corrections
-SCOPES = (KV_CACHE, ATTENTION, PIM_QUANTIZE, PIM_MATMUL)
+MOE_ROUTE = "moe.route"        # router, gates, top-k, sort, gather, combine
+MOE_EXPERTS = "moe.experts"    # held experts' grouped products, quantized
+SCOPES = (KV_CACHE, ATTENTION, PIM_QUANTIZE, PIM_MATMUL, MOE_ROUTE,
+          MOE_EXPERTS)
 
 CONTAINER = "container"
 _CONTAINER_OPS = frozenset({"while", "conditional", "call"})
@@ -67,9 +73,13 @@ def scope(name: str):
 
 
 def scope_of(op_name: str) -> Optional[str]:
-    """The innermost vocabulary scope in an HLO ``op_name`` path."""
-    for part in reversed(op_name.split("/")):
+    """The innermost vocabulary scope in an HLO ``op_name`` path; a
+    ``pim.*`` scope inside :data:`MOE_EXPERTS` reads as the latter."""
+    parts = op_name.split("/")
+    for part in reversed(parts):
         if part in SCOPES:
+            if part.startswith("pim.") and MOE_EXPERTS in parts:
+                return MOE_EXPERTS
             return part
     return None
 

@@ -125,7 +125,9 @@ def block_linears(cfg) -> List[BlockLinear]:
     cannot drift from what the blocks compute; FFN covers dense blocks,
     the MoE ragged path's active per-expert GEMMs and the RG-LRU block
     MLP; the LM head is its own scope. The router and the recurrent
-    gate projections stay digital (tiny, latency-critical).
+    gate projections stay digital (tiny, latency-critical). A MoE layer
+    told its share computes at most ``min(top_k, experts_held)`` of a
+    token's picks (:func:`moe_active`).
     """
     from repro.models.attention import projection_shapes
     d = cfg.d_model
@@ -161,12 +163,18 @@ def block_linears(cfg) -> List[BlockLinear]:
 
     ffn("ffn", cfg.d_ff, n_dense + n_rglru + n_enc)
     if n_moe:
-        e = cfg.moe
-        ffn("moe.expert", cfg.d_ff, n_moe * (e.top_k + e.n_shared))
+        ffn("moe.expert", cfg.d_ff, n_moe * moe_active(cfg))
     if n_dmoe:
         ffn("moe.dense", cfg.moe.d_ff_dense or cfg.d_ff, n_dmoe)
     out.append(BlockLinear("lm_head", "head", d, cfg.vocab_size, 1))
     return out
+
+
+def moe_active(cfg) -> int:
+    """Expert FFNs a token runs in one MoE layer on this chip: the picks
+    that can land in the held experts, and the shared experts."""
+    e = cfg.moe
+    return min(e.top_k, e.held) + e.n_shared
 
 
 @dataclass
@@ -472,7 +480,20 @@ def gemms_from_config(cfg, batch_tokens: int = 1) -> List[GemmShape]:
     n_densef = sum(1 for k in kinds if k in ("g", "l"))
     n_dmoe = sum(1 for k in kinds if k == "d")
 
-    if n_attn:
+    if n_attn and cfg.mla is not None:
+        # Latent attention's projections, and wkv_b, whose absorbed
+        # products (query into the latent, latent out to the values)
+        # take its weights once a token.
+        a = cfg.mla
+        g.append(GemmShape("attn.q", m, d, cfg.n_heads * a.qk_head_dim,
+                           n_attn))
+        g.append(GemmShape("attn.kv_a", m, d,
+                           a.kv_lora_rank + a.qk_rope_head_dim, n_attn))
+        g.append(GemmShape("attn.kv_b", m, a.kv_lora_rank, cfg.n_heads
+                           * (a.qk_nope_head_dim + a.v_head_dim), n_attn))
+        g.append(GemmShape("attn.o", m, cfg.n_heads * a.v_head_dim, d,
+                           n_attn))
+    elif n_attn:
         g.append(GemmShape("attn.q", m, d, cfg.q_dim, n_attn))
         g.append(GemmShape("attn.kv", m, d, 2 * cfg.kv_dim, n_attn))
         g.append(GemmShape("attn.o", m, cfg.q_dim, d, n_attn))
@@ -488,7 +509,7 @@ def gemms_from_config(cfg, batch_tokens: int = 1) -> List[GemmShape]:
         g.append(GemmShape("ffn", m, d, nm * cfg.d_ff, n_densef))
     if n_moe:
         e = cfg.moe
-        active = e.top_k + e.n_shared
+        active = moe_active(cfg)
         g.append(GemmShape("moe.ffn", m, d, nm * cfg.d_ff, n_moe * active))
         g.append(GemmShape("moe.router", m, d, e.n_experts, n_moe))
     if n_dmoe:

@@ -28,6 +28,10 @@ __all__ = ["QTensor", "quantize", "dequantize", "qmatmul_exact",
            "qmatmul_planned"]
 
 
+#: Rows a tile of :func:`qragged_matmul_exact` multiplies at a time.
+RAGGED_TILE = 512
+
+
 class QTensor(NamedTuple):
     q: jnp.ndarray        # int32, in [0, 2^n)
     scale: jnp.ndarray    # per-channel or scalar, f32
@@ -79,35 +83,70 @@ def qmatmul_exact(xq: QTensor, wq: QTensor) -> jnp.ndarray:
         return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
 
 
-def qragged_matmul_exact(xq: QTensor, wq: QTensor,
-                         counts: jnp.ndarray) -> jnp.ndarray:
+def qragged_matmul_exact(xq: QTensor, wq: QTensor, counts: jnp.ndarray,
+                         *, tile: int = RAGGED_TILE) -> jnp.ndarray:
     """Ragged grouped-GEMM variant of :func:`qmatmul_exact` for the MoE
     dropless dispatch: ``xq.q`` is the (T, D) expert-sorted token block,
     ``wq.q`` the (E, D, F) per-expert weight stack (per-tensor scale so
-    one offset correction covers every expert), ``counts`` the (E,)
-    per-expert segment lengths. Row ``t`` multiplies against its
-    segment's expert exactly as ``jax.lax.ragged_dot`` would on the
-    float path, with the same analytic zero-point correction — so the
-    per-expert GEMMs are bit-identical to what the in-memory
-    MultPIM-MAC computes on the quantized operands.
+    one offset covers every expert), ``counts`` the (E,) per-expert
+    segment lengths. Row ``t`` multiplies against its segment's expert
+    exactly as ``jax.lax.ragged_dot`` would on the float path; rows past
+    the segments read 0 (a TPU ``ragged_dot`` does not zero them).
+
+    The corrected integer sum ``sum (x - zx)(w - zw)`` of
+    :func:`qmatmul_exact` is formed from the centred codes (``int8 x int8
+    -> int32`` up to 8 bits, as :func:`qmatmul_planned`), so the
+    per-expert GEMMs are bit-identical to what the in-memory MultPIM-MAC
+    computes on the quantized operands. Up to ``tile`` rows (a decode
+    step's) every expert takes all of them, its rows of other experts
+    masked to 0, in one batched product. Past that, each expert's rows
+    are laid out in tiles of ``tile`` rows of their own (the last one
+    padded with zero rows), and the tiles multiply against their
+    experts in one batched product: at most ``T / tile + E`` tiles, so
+    the product and its temporaries grow with the rows, not with rows
+    times experts.
     """
-    import jax
-    xi = xq.q
-    wi = wq.q                                          # (E, D, F)
-    k = xi.shape[-1]
+    dtype = jnp.int8 if max(xq.n_bits, wq.n_bits) <= 8 else jnp.int32
+    t, d = xq.q.shape
+    e, _, f = wq.q.shape
+    batched = (((2,), (1,)), ((0,), (0,)))
+    with obs.scope(obs.PIM_QUANTIZE):
+        xc = (xq.q - xq.zero).astype(dtype)
+        wc = (wq.q - wq.zero).astype(dtype)
     with obs.scope(obs.PIM_MATMUL):
-        # int32 accumulation end-to-end (see qmatmul_exact): exact where
-        # a float32 ragged_dot drifts once the per-row dot passes 2^24.
-        prod = jax.lax.ragged_dot(xi, wi, counts)
-        # Per-row sum_d w[expert(row), d, :]: expand the per-expert
-        # column sums along the ragged segments (counts sum to T by
-        # construction).
-        wsum = jnp.repeat(jnp.sum(wi, axis=1), counts, axis=0,
-                          total_repeat_length=xi.shape[0])
-        corr = (xq.zero * wsum
-                + wq.zero * jnp.sum(xi, axis=-1, keepdims=True)
-                - k * xq.zero * wq.zero)
-        return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
+        first_row = jnp.cumsum(counts) - counts
+        i = jnp.arange(t)
+        g = jnp.searchsorted(first_row + counts, i, side="right")
+        if t <= tile:
+            prods = jax.lax.dot_general(                   # (E, T, F)
+                jnp.broadcast_to(xc, (e, t, d)), wc, batched,
+                preferred_element_type=jnp.int32)
+            mine = g[None, :, None] == jnp.arange(e)[:, None, None]
+            prod = jnp.sum(jnp.where(mine, prods, 0), axis=0)
+            return prod.astype(jnp.float32) * xq.scale * wq.scale
+        n = -(-t // tile) + e
+        tiles = -(-counts // tile)                         # (E,)
+        first_tile = jnp.cumsum(tiles) - tiles
+        # Each tile's expert; tiles past the last one hold no row.
+        te = jnp.minimum(jnp.searchsorted(first_tile + tiles, jnp.arange(n),
+                                          side="right"), e - 1)
+        # Slot s of tile s // tile takes its expert's row (s // tile -
+        # first_tile) * tile + s % tile, or a zero row past the segment.
+        se = jnp.repeat(te, tile)
+        s = jnp.arange(n * tile)
+        k = (s // tile - first_tile[se]) * tile + s % tile
+        src = jnp.where(k < counts[se], first_row[se] + k, t)
+        xt = jnp.take(xc, src, axis=0, mode="fill", fill_value=0)
+        prods = jax.lax.dot_general(                       # (n, tile, F)
+            xt.reshape(n, tile, d), wc[te], batched,
+            preferred_element_type=jnp.int32)
+        # And back: row i's slot, or none past the segments.
+        gc = jnp.minimum(g, e - 1)
+        slot = jnp.where(g < e, first_tile[gc] * tile + i - first_row[gc],
+                         n * tile)
+        prod = jnp.take(prods.reshape(n * tile, f), slot, axis=0,
+                        mode="fill", fill_value=0)
+        return prod.astype(jnp.float32) * xq.scale * wq.scale
 
 
 @dataclasses.dataclass(frozen=True)

@@ -47,6 +47,8 @@ _RULES: Dict[str, Tuple[Optional[str], ...]] = {
     "xq": (None, "model"), "xk": (None, "model"), "xv": (None, "model"),
     "xo": ("model", None),
     "qn": (None,), "kn": (None,),
+    # latent attention (wq as above)
+    "wkv_a": (None, None), "wkv_b": (None, "model"), "kv_norm": (None,),
     "ln1": (None,), "ln2": (None,), "lnx": (None,),
     # mlp
     "w1": (None, "model"), "w3": (None, "model"), "w2": ("model", None),
@@ -135,10 +137,12 @@ def state_shardings(mesh: Mesh, states: Any):
         # find batch axis: stacked states have a leading layer axis
         specs = [None] * len(shp)
         b_ax = 0
-        # heuristics: (L?, B, T, H, D) KV / (L?, B, nh, hd, hd) wkv /
-        # (L?, B, D) vectors / (L?, B, 3, D) conv
+        # heuristics: (L?, B, T, H, D) KV / (L?, B, T, R) latents /
+        # (L?, B, nh, hd, hd) wkv / (L?, B, D) vectors / (L?, B, 3, D) conv
         if name in ("k", "v") or (len(shp) >= 4 and name in ("wkv",)):
             b_ax = len(shp) - 4
+        elif name in ("c", "kpe"):
+            b_ax = len(shp) - 3
         elif name in ("h", "tshift", "cshift"):
             b_ax = len(shp) - 2
         elif name == "conv":
@@ -157,6 +161,8 @@ def state_shardings(mesh: Mesh, states: Any):
                 # cache write scatters to the owning shard). Cuts
                 # decode_32k peak memory ~16x for gemma2/qwen3/pixtral.
                 specs[-3] = "model"
+        if name in ("c", "kpe") and shp[-2] % tp == 0 and tp > 1:
+            specs[-2] = "model"              # no heads: the sequence
         if name == "wkv" and shp[-3] % tp == 0 and tp > 1:
             specs[-3] = "model"
         if name in ("h", "tshift", "cshift") and shp[-1] % tp == 0 and tp > 1:

@@ -11,7 +11,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.engine import Engine, get_engine
-from repro.pim import (block_linears, plan_block, qmatmul_exact,
+from repro.pim import (QTensor, block_linears, plan_block, qmatmul_exact,
                        qragged_matmul_exact, quantize)
 
 pytestmark = pytest.mark.pim
@@ -59,6 +59,27 @@ def test_block_linears_moe_counts_active_experts():
     assert names["moe.expert.w2"].in_dim == cfg.d_ff
     assert "moe.dense.w1" in names          # the 'd' layer rides along
     assert all(l.name != "moe.router" for l in block_linears(cfg))
+
+
+@pytest.mark.parametrize("held", [None, 8, 4])
+def test_block_linears_mla_projections_and_held_experts(held):
+    """Latent attention's projections (its wkv_b is absorbed, digital)
+    and, for a MoE layer told its share, at most min(top_k, held) of a
+    token's picks a layer beside the shared experts."""
+    cfg = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(
+        cfg, pim_linear_mode="pim", pim_block_mode="full",
+        moe=dataclasses.replace(cfg.moe, experts_held=held))
+    names = {l.name: l for l in block_linears(cfg)}
+    assert {n for n in names if n.startswith("attn.")} == {
+        "attn.q", "attn.kv_a", "attn.o"}
+    assert (names["attn.q"].in_dim, names["attn.q"].out_dim) == (2048,
+                                                                 16 * 192)
+    assert names["attn.kv_a"].out_dim == 512 + 64
+    assert (names["attn.o"].in_dim, names["attn.o"].count) == (16 * 128, 27)
+    picks = 6 if held is None else min(6, held)
+    assert names["moe.expert.w1"].count == 26 * (picks + 2)
+    assert names["moe.dense.w1"].out_dim == 10944
 
 
 def test_block_linears_encdec_counts_cross_attention_and_encoder():
@@ -186,9 +207,9 @@ def test_moe_block_runs_under_ffn_scope():
 
 # ------------------------------------------------------- quantized MoE ----
 def test_qragged_matmul_matches_dense_per_segment():
-    """The ragged zero-point correction == the dense correction applied
-    expert by expert (so the MoE path is bit-identical to running each
-    expert's GEMM through qmatmul_exact)."""
+    """The ragged product of centred codes == the dense correction
+    applied expert by expert (so the MoE path is bit-identical to
+    running each expert's GEMM through qmatmul_exact)."""
     rng = np.random.default_rng(3)
     e, d, f = 3, 8, 5
     counts = jnp.asarray([4, 0, 2], jnp.int32)
@@ -236,6 +257,74 @@ def test_quantized_matmuls_exact_at_model_widths():
     want_r = np.concatenate([xi[:3] @ wie[0], xi[3:] @ wie[1]]).astype(
         np.float64) * np.asarray(xq.scale, np.float64) * float(wqe.scale)
     np.testing.assert_allclose(got_r, want_r, rtol=1e-6)
+
+
+@pytest.mark.parametrize("n_bits,dtype", [(8, "i8"), (12, "i32")])
+@pytest.mark.parametrize("tile,shapes", [
+    (16, ("3x12x64", "3x64x16", "3x12x16")),   # 12 rows: every expert all
+    (4, ("6x4x64", "6x64x16", "6x4x16"))])     # 3 tiles + 1 per expert
+def test_ragged_product_operands_in_lowered_hlo(n_bits, dtype, tile,
+                                                shapes):
+    """The ragged PIM product multiplies centred codes, int8 up to 8 bits
+    (exact on the MXU), in one batched product, and accumulates in
+    int32: up to a tile of rows, every expert against all the rows;
+    past it, tiles of each expert's own rows against their experts."""
+    xs = jax.ShapeDtypeStruct((12, 64), jnp.float32)
+    we = jax.ShapeDtypeStruct((3, 64, 16), jnp.float32)
+    counts = jax.ShapeDtypeStruct((3,), jnp.int32)
+    text = jax.jit(lambda x, w, c: qragged_matmul_exact(
+        quantize(x, n_bits), quantize(w, n_bits), c, tile=tile)).lower(
+            xs, we, counts).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert len(dots) == 1
+    x, w, out = shapes
+    assert (f"(tensor<{x}x{dtype}>, tensor<{w}x{dtype}>) -> "
+            f"tensor<{out}xi32>") in dots[0]
+
+
+@pytest.mark.parametrize("tile", [1, 4, 7, 64])
+def test_qragged_tiles_span_experts_exactly(tile):
+    """Tiles of one row, of several that end inside a segment, and one
+    tile of all 33 rows; empty experts and rows past the segments:
+    every row gets its own expert's int64 sum, the rows past the
+    segments 0."""
+    rng = np.random.default_rng(5)
+    counts = np.array([7, 0, 13, 1, 0, 9, 0])
+    t, d, f = 33, 32, 6                       # 3 rows past the segments
+    xq = quantize(jnp.asarray(rng.standard_normal((t, d)), jnp.float32), 8)
+    wq = quantize(jnp.asarray(rng.standard_normal((7, d, f)), jnp.float32),
+                  8)
+    got = np.asarray(qragged_matmul_exact(
+        xq, wq, jnp.asarray(counts, jnp.int32), tile=tile), np.float64)
+    xi = np.asarray(xq.q, np.int64) - xq.zero
+    wi = np.asarray(wq.q, np.int64) - wq.zero
+    want = np.zeros((t, f))
+    lo = 0
+    for j, c in enumerate(counts):
+        want[lo:lo + c] = xi[lo:lo + c] @ wi[j]
+        lo += c
+    want *= float(xq.scale) * float(wq.scale)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert not got[lo:].any()
+
+
+def test_qragged_temporaries_grow_with_rows_not_rows_times_experts():
+    """At 64 experts the product's temporaries stay under an eighth of
+    one (E, T, F) int32 buffer, which a batched product of every row
+    against every expert holds whole (1.13 of it, compiled here)."""
+    e, t, d, f = 64, 16384, 256, 512
+
+    def product(x, w, c):
+        return qragged_matmul_exact(QTensor(x, jnp.float32(0.01), 8, 128),
+                                    QTensor(w, jnp.float32(0.01), 8, 128),
+                                    c)
+
+    compiled = jax.jit(product).lower(
+        jax.ShapeDtypeStruct((t, d), jnp.int32),
+        jax.ShapeDtypeStruct((e, d, f), jnp.int32),
+        jax.ShapeDtypeStruct((e,), jnp.int32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < e * t * f * 4 / 8
 
 
 def test_engine_ragged_linear_modes():

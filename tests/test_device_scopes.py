@@ -32,6 +32,16 @@ def tiny_pim_decoder():
     return model, jax.jit(model.init)(jax.random.key(0))
 
 
+def tiny_pim_moe_decoder():
+    """deepseek-v2-lite's smoke config (latent attention, held experts)
+    with PIM FFNs: the MoE scopes sit in its step."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite", smoke=True),
+                              pim_linear_mode="pim", pim_linear_bits=8,
+                              pim_block_mode="ffn")
+    model = build_model(cfg)
+    return model, jax.jit(model.init)(jax.random.key(0))
+
+
 def serve_args(model, batch, cache_len=16):
     states = model.init_decode_state(batch, cache_len)
     tok = jnp.zeros((batch, 1), jnp.int32)
@@ -57,11 +67,38 @@ def test_serve_step_scopes_cover_the_four_scopes(programs):
     m = obs.device_scopes()
     assert all(k.startswith("jit_serve_step/") for k in m)
     found = set(m.values())
-    for s in obs.SCOPES:
+    for s in (obs.KV_CACHE, obs.ATTENTION, obs.PIM_QUANTIZE,
+              obs.PIM_MATMUL):
         assert s in found, f"no op in scope {s}"
+    assert not found & {obs.MOE_ROUTE, obs.MOE_EXPERTS}   # no experts
     containers = [k for k, v in m.items() if v == obs.CONTAINER]
     assert containers and all("/while" in k for k in containers)
     assert obs.device_scopes() == m          # built once, then kept
+
+
+def test_moe_serve_step_scopes_route_and_experts(programs):
+    """A MoE decoder with PIM FFNs: the router's work reads as
+    ``moe.route``, the held experts' grouped products with their
+    quantization as ``moe.experts`` (not ``pim.*``), and the dense PIM
+    linears (shared experts, dense layer, head) still as ``pim.*``."""
+    model, params = tiny_pim_moe_decoder()
+    states, tok, pos = serve_args(model, 2)
+    _, jit_for = make_serve_step(model, make_host_mesh(1))
+    step = jit_for(params, states, {"token": tok, "position": pos})
+    step(params, states, tok, pos)
+    found = set(obs.device_scopes().values())
+    for s in obs.SCOPES:
+        assert s in found, f"no op in scope {s}"
+
+
+def test_pim_ops_inside_the_experts_read_as_the_experts():
+    assert scopes.scope_of("jit(f)/moe.experts/pim.quantize/max") \
+        == obs.MOE_EXPERTS
+    assert scopes.scope_of("jit(f)/moe.experts/pim.matmul/dot") \
+        == obs.MOE_EXPERTS
+    assert scopes.scope_of("jit(f)/moe.route/sort") == obs.MOE_ROUTE
+    assert scopes.scope_of("jit(f)/attention/pim.matmul/dot") \
+        == obs.PIM_MATMUL
 
 
 def test_hlo_scopes_innermost_scope_and_containers():
@@ -105,18 +142,23 @@ def _stripped_hlo(model, params, batch):
     serve_step, _ = make_serve_step(model, make_host_mesh(1))
     text = jax.jit(serve_step, donate_argnums=(1,)).lower(
         params, states, tok, pos).compile().as_text()
-    text = text.split("\nFileNames")[0]
+    # The source tables printed before the computations, and the
+    # metadata that points into them, name source lines alone.
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n(?:.+\n)*", "\n", text)
     return re.sub(r", metadata=\{[^}]*\}", "", text)
 
 
-def test_scopes_change_only_metadata(monkeypatch):
+@pytest.mark.parametrize("build", [tiny_pim_decoder, tiny_pim_moe_decoder])
+def test_scopes_change_only_metadata(monkeypatch, build):
     """The optimized serve step with and without the scopes differs in
     HLO metadata alone: the executable the hot path runs is the same."""
-    model, params = tiny_pim_decoder()
+    model, params = build()
     scoped = _stripped_hlo(model, params, 3)
     monkeypatch.setattr(obs, "scope", lambda name: contextlib.nullcontext())
     plain = _stripped_hlo(model, params, 3)
-    assert "kv_cache" not in plain and "pim.quantize" not in plain
+    assert "ENTRY" in plain and "FileNames" not in plain
+    assert not any(s in plain for s in obs.SCOPES)
     assert scoped == plain
 
 

@@ -82,6 +82,30 @@ def test_prefill_decode_consistency(arch):
             rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "deepseek-v2-lite"])
+def test_moe_prefill_hands_off_the_cache(arch):
+    """A prefill with states leaves the prompt's cache in every layer,
+    the MoE layers' too: decoding after it gives the full forward's
+    logits (float32 rounding, as above)."""
+    cfg = get_config(arch, smoke=True)
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(5))
+    b, s, p = 2, 10, 6
+    toks = jnp.asarray(np.random.default_rng(6).integers(
+        3, cfg.vocab_size, (b, s)))
+    full_logits, _ = m.forward(params, toks)
+    _, states = m.forward(params, toks[:, :p],
+                          states=m.init_decode_state(b, 16))
+    assert all(float(jnp.abs(a).sum()) > 0             # every layer's
+               for a in jax.tree.leaves(states))
+    for t in range(p, s):
+        logits, states = m.decode_step(
+            params, toks[:, t:t + 1], jnp.full((b, 1), t, jnp.int32), states)
+        np.testing.assert_allclose(
+            np.asarray(logits[:, 0]), np.asarray(full_logits[:, t]),
+            rtol=2e-3, atol=2e-3)
+
+
 def test_windowed_cache_ring_buffer():
     """Decode beyond the window: ring buffer wraps and matches a full
     forward restricted to the window."""

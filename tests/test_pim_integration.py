@@ -70,3 +70,21 @@ def test_planner_moe_counts_active_experts():
     plan = plan_model(gemms_from_config(cfg), n_bits=8)
     names = [g.name for g in plan.gemms]
     assert "moe.ffn" in names and "moe.router" in names
+
+
+def test_planner_mla_and_held_experts():
+    """DeepSeek-V2-Lite's step inventory: latent attention's four
+    products (wkv_b's absorbed ones take its weights once a token) and,
+    with 8 of 64 experts held, six picks and two shared a MoE layer."""
+    import dataclasses
+
+    from repro.configs import get_config
+    cfg = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, experts_held=8))
+    g = {x.name: x for x in gemms_from_config(cfg)}
+    assert {n for n in g if n.startswith("attn.")} == {
+        "attn.q", "attn.kv_a", "attn.kv_b", "attn.o"}
+    assert (g["attn.kv_b"].k, g["attn.kv_b"].n) == (512, 16 * 256)
+    assert g["moe.ffn"].count == 26 * 8
+    assert g["moe.router"].n == 64
