@@ -16,7 +16,8 @@ from repro import obs
 from .layers import softcap as _softcap
 
 __all__ = ["attend", "decode_attend", "KVCache", "LatentCache",
-           "latent_decode_attend", "projection_shapes"]
+           "latent_decode_attend", "projection_shapes", "write_position",
+           "POSITIONS_LAST"]
 
 
 def projection_shapes(cfg) -> "list[Tuple[str, int, int]]":
@@ -49,6 +50,12 @@ def projection_shapes(cfg) -> "list[Tuple[str, int, int]]":
 
 NEG_INF = -2.3819763e38
 
+# Cache buffers kept with positions along their last axis: the rope keys
+# are 64 wide, so positions-last fills a TPU tile's 128 lanes, where a
+# position-major array would be laid out time-minor by the compiler and
+# copied to row-major for every kernel that reads it.
+POSITIONS_LAST = ("kpe",)
+
 
 class KVCache(NamedTuple):
     """Ring-buffered KV cache. ``k``/``v``: (B, T, Hkv, D); ``length``:
@@ -61,8 +68,9 @@ class KVCache(NamedTuple):
 
 class LatentCache(NamedTuple):
     """Latent-attention cache: ``c`` (B, T, kv_lora_rank), the normed
-    latent; ``kpe`` (B, T, rope dims), the roped key every head shares;
-    ``length`` as :class:`KVCache`'s."""
+    latent; ``kpe`` (B, rope dims, T), the roped key every head shares,
+    positions along the last axis (:data:`POSITIONS_LAST`); ``length`` as
+    :class:`KVCache`'s."""
     c: jnp.ndarray
     kpe: jnp.ndarray
     length: jnp.ndarray
@@ -195,72 +203,175 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          q_offset=q_offset, scale=scale)
 
 
+def write_position(buf: jnp.ndarray, new: jnp.ndarray, slot,
+                   layer=None, axis: int = 1) -> jnp.ndarray:
+    """``buf`` with the token's entries ``new`` written at ring slot
+    ``slot`` of its time axis ``axis``, where ``new`` has size 1: of a
+    layer's cache (B, T, ... with ``axis`` 1), or of layer ``layer`` of
+    a layer stack (L, B, T, ...). Nothing else of ``buf`` is written, so
+    a donated stack is updated in place."""
+    new = new.astype(buf.dtype)
+    start = tuple(slot if i == axis else 0 for i in range(new.ndim))
+    if layer is not None:
+        new, start = new[None], (layer,) + start
+    return jax.lax.dynamic_update_slice(buf, new, start)
+
+
+def _lengths(length: jnp.ndarray, layer):
+    """-> (the layer's token count before this step, ``length`` counted
+    up by one token): a scalar, or entry ``layer`` of a layer stack's
+    counts."""
+    if layer is None:
+        return length, length + 1
+    n = jax.lax.dynamic_index_in_dim(length, layer, 0, keepdims=False)
+    return n, jax.lax.dynamic_update_slice(length, (n + 1)[None], (layer,))
+
+
+def _layer(buf: jnp.ndarray, layer) -> jnp.ndarray:
+    return (buf if layer is None
+            else jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False))
+
+
+def _n_valid(length, t: int, window: Optional[int] = None):
+    """The ring slots to attend over once the token is written: the
+    tokens written, capped at the ring and the window."""
+    n = jnp.minimum(length + 1, t)
+    return n if window is None else jnp.minimum(n, window)
+
+
+def _ring_valid(slot, n_valid, t: int):
+    """(T,) bool: the ring slots written within the last ``n_valid``
+    tokens, ``slot`` the newest."""
+    return jnp.mod(slot - jnp.arange(t), t) < n_valid     # age 0 = newest
+
+
 def decode_attend(q: jnp.ndarray, cache: KVCache, k_new: jnp.ndarray,
-                  v_new: jnp.ndarray, *, window: Optional[int] = None,
+                  v_new: jnp.ndarray, *, layer=None,
+                  window: Optional[int] = None,
                   cap: Optional[float] = None
                   ) -> Tuple[jnp.ndarray, KVCache]:
-    """One-token decode: append (k_new, v_new) then attend over the cache.
+    """One-token decode: write (k_new, v_new) at the ring slot, then
+    attend over the layer's cache.
 
-    q/k_new/v_new: (B, 1, H*, D). Ring-buffer write keeps the windowed
-    layers' cache O(window) for the 500k-context shapes.
+    q/k_new/v_new: (B, 1, H*, D). ``cache`` holds one layer's (B, T,
+    Hkv, D) buffers and scalar count, or, with ``layer``, a layer
+    stack's (L, B, T, Hkv, D) buffers and (L,) counts, of which layer
+    ``layer`` is this one. Only the token's entries and the count are
+    written (:func:`write_position`); the returned cache is ``cache``
+    with them. Ring-buffer writes keep the windowed layers' cache
+    O(window) for the 500k-context shapes.
     """
-    t = cache.k.shape[1]
-    slot = jnp.mod(cache.length, t)
+    t = cache.k.shape[-3]
+    length, new_length = _lengths(cache.length, layer)
+    slot = jnp.mod(length, t)
     with obs.scope(obs.KV_CACHE):
-        k = jax.lax.dynamic_update_slice(
-            cache.k, k_new.astype(cache.k.dtype), (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            cache.v, v_new.astype(cache.v.dtype), (0, slot, 0, 0))
-    new_len = cache.length + 1
-
+        k = write_position(cache.k, k_new, slot, layer)
+        v = write_position(cache.v, v_new, slot, layer)
     d = q.shape[-1]
-    scores = _grouped_scores(q, k) * (d ** -0.5)       # (B,H,1,T)
-    scores = _softcap(scores, cap)
-    kpos_slot = jnp.arange(t)
-    # valid slots: those written within the last min(new_len, window or T)
-    age = jnp.mod(slot - kpos_slot, t)                  # 0 = newest
-    valid = age < jnp.minimum(new_len, t)
-    if window is not None:
-        valid &= age < window
-    scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = _grouped_out(probs, v)
-    return out, KVCache(k, v, new_len)
+
+    def dense():
+        with obs.scope(obs.KV_CACHE):
+            k_l, v_l = _layer(k, layer), _layer(v, layer)
+        scores = _grouped_scores(q, k_l) * (d ** -0.5)     # (B,H,1,T)
+        scores = _softcap(scores, cap)
+        valid = _ring_valid(slot, _n_valid(length, t, window), t)
+        scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(q.dtype)
+        return _grouped_out(probs, v_l)
+
+    def kernel():
+        from repro.kernels.decode_attention import kv_decode_attention
+        b, _, hq, _ = q.shape
+        hkv = k.shape[-2]
+        qg = (q[:, 0] * d ** -0.5).reshape(b, hkv, hq // hkv, d)
+        o = kv_decode_attention(
+            qg.transpose(0, 2, 1, 3), *_stacks(k, v, layer=layer), slot,
+            _n_valid(length, t, window), cap=cap, interpret=False)
+        return o.transpose(0, 2, 1, 3).reshape(b, 1, hq, d)
+
+    return _read_in_place(kernel, dense), KVCache(k, v, new_length)
 
 
 def latent_decode_attend(q_nope: jnp.ndarray, q_pe: jnp.ndarray,
                          cache: LatentCache, c_new: jnp.ndarray,
                          kpe_new: jnp.ndarray, w_uk: jnp.ndarray,
-                         w_uv: jnp.ndarray, *, scale: float
+                         w_uv: jnp.ndarray, *, scale: float, layer=None
                          ) -> Tuple[jnp.ndarray, LatentCache]:
-    """One-token latent attention in the absorbed form: append the
-    token's latent and rope key, then attend over the latents.
+    """One-token latent attention in the absorbed form: write the
+    token's latent and rope key at the ring slot, then attend over the
+    layer's latents.
 
     q_nope (B, 1, H, dn), q_pe (B, 1, H, dr), c_new (B, 1, r), kpe_new
-    (B, 1, dr); ``w_uk`` (r, H, dn) and ``w_uv`` (r, H, dv) are the key
-    and value halves of ``wkv_b``. Per head the query is taken into the
-    latent (``q_lat = W_UK^T q_nope``), the scores are ``q_lat . c +
-    q_pe . k_pe``, and the latents' weighted sum leaves through ``W_UV``:
-    the same scores and output as decompressing every cached latent into
-    per-head keys and values, without doing so. -> (B, 1, H, dv).
+    (B, 1, dr), cached as (B, dr, T) (:class:`LatentCache`); ``w_uk``
+    (r, H, dn) and ``w_uv`` (r, H, dv) are the key and value halves of
+    ``wkv_b``. ``cache`` and ``layer`` as
+    :func:`decode_attend`'s: one layer's (B, T, ...) latents, or a
+    layer stack's with ``layer`` this one. Per head the query is taken
+    into the latent (``q_lat = W_UK^T q_nope``), the scores are ``q_lat
+    . c + q_pe . k_pe``, and the latents' weighted sum leaves through
+    ``W_UV``: the same scores and output as decompressing every cached
+    latent into per-head keys and values, without doing so. -> (B, 1,
+    H, dv).
     """
-    t = cache.c.shape[1]
-    slot = jnp.mod(cache.length, t)
+    t = cache.c.shape[-2]
+    length, new_length = _lengths(cache.length, layer)
+    slot = jnp.mod(length, t)
     with obs.scope(obs.KV_CACHE):
-        c = jax.lax.dynamic_update_slice(
-            cache.c, c_new.astype(cache.c.dtype), (0, slot, 0))
-        kpe = jax.lax.dynamic_update_slice(
-            cache.kpe, kpe_new.astype(cache.kpe.dtype), (0, slot, 0))
-    new_len = cache.length + 1
-
+        c = write_position(cache.c, c_new, slot, layer)
+        kpe = write_position(cache.kpe, kpe_new.swapaxes(1, 2), slot,
+                             layer, axis=2)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
-    scores = (jnp.einsum("bshr,btr->bhst", q_lat, c)
-              + jnp.einsum("bshd,btd->bhst", q_pe, kpe)) * scale
-    age = jnp.mod(slot - jnp.arange(t), t)              # 0 = newest
-    valid = age < jnp.minimum(new_len, t)
-    scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores.astype(jnp.float32),
-                           axis=-1).astype(q_nope.dtype)
-    o_lat = jnp.einsum("bhst,btr->bshr", probs, c)
+
+    def dense():
+        with obs.scope(obs.KV_CACHE):
+            c_l, kpe_l = _layer(c, layer), _layer(kpe, layer)
+        scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_l)
+                  + jnp.einsum("bshd,bdt->bhst", q_pe, kpe_l)) * scale
+        valid = _ring_valid(slot, _n_valid(length, t), t)
+        scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(q_nope.dtype)
+        return jnp.einsum("bhst,btr->bshr", probs, c_l)
+
+    def kernel():
+        from repro.kernels.decode_attention import latent_decode_attention
+        o = latent_decode_attention(
+            q_lat[:, 0], q_pe[:, 0], *_stacks(c, kpe, layer=layer), slot,
+            _n_valid(length, t), scale=scale, precision=_precision(),
+            interpret=False)
+        return o[:, None]
+
+    o_lat = _read_in_place(kernel, dense)
     out = jnp.einsum("bshr,rhd->bshd", o_lat, w_uv)
-    return out, LatentCache(c, kpe, new_len)
+    return out, LatentCache(c, kpe, new_length)
+
+
+def _stacks(*bufs, layer):
+    """-> (*layer stacks, the layer's index): a layer's own cache as a
+    stack of one."""
+    if layer is None:
+        return tuple(b[None] for b in bufs) + (0,)
+    return bufs + (layer,)
+
+
+def _precision():
+    """The float products' precision that ``jax_default_matmul_precision``
+    sets (None: the platform's default)."""
+    p = jax.config.jax_default_matmul_precision
+    try:
+        return None if p is None else jax.lax.Precision(p)
+    except ValueError:              # a dot algorithm, not a precision
+        return None
+
+
+def _read_in_place(kernel, dense):
+    """Attention over a layer of the written cache: on a TPU where the
+    process sees one device, the Pallas kernel, which reads the layer
+    where it lies in the stack (:mod:`repro.kernels.decode_attention`);
+    elsewhere ``dense``, the jnp form. The platform is the lowering's;
+    with several devices the cache may be sharded, and the jnp form is
+    what the partitioner can split along it."""
+    if jax.device_count() > 1:
+        return dense()
+    return jax.lax.platform_dependent(tpu=kernel, default=dense)

@@ -13,7 +13,8 @@ Every block is a pair of pure functions:
   (+ a cross-attention KV pair for enc-dec decoders)
 * latent attention ('m'/'d' of a config with ``cfg.mla``):
   :class:`repro.models.attention.LatentCache`, the normed latent and
-  the shared rope key per position instead of per-head K/V
+  the shared rope key per position instead of per-head K/V (the rope
+  keys with positions along their last axis)
 * RG-LRU ('r', hybrid): {"h": (B, D), "conv": (B, 3, D)}
 * RWKV-6 ('r', rwkv): {"wkv": (B, H, dh, dh), "tshift"/"cshift": (B, D)}
 * MoE ('m'/'d'): same as attention (the FFN is stateless).
@@ -35,12 +36,12 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs.base import ModelConfig
 
-from .attention import (KVCache, LatentCache, attend, decode_attend,
-                        latent_decode_attend)
+from .attention import (POSITIONS_LAST, KVCache, LatentCache, attend,
+                        decode_attend, latent_decode_attend)
 from .layers import Initializer, rms_norm, rope, yarn_inv_freq, yarn_mscale
 
 __all__ = ["init_block", "apply_block", "init_state", "pim_proj",
-           "pim_weights"]
+           "pim_weights", "writes_by_position"]
 
 
 # ------------------------------------------------------ PIM offload ----
@@ -171,18 +172,23 @@ def _qkv(cfg: ModelConfig, p, xn, pos):
 
 def _prefill_cache(state, **entries):
     """The block's decode state with the prompt's entries (``k``/``v``,
-    or the latent ``c``/``kpe``; (B, S, ...) each) left behind."""
+    or the latent ``c``/``kpe``; (B, S, ...) each) left behind, each in
+    its buffer's layout (:data:`~.attention.POSITIONS_LAST`)."""
     s = next(iter(entries.values())).shape[1]
     with obs.scope(obs.KV_CACHE):
         cache = {}
         for name, a in entries.items():
-            t = state["self"][name].shape[1]
+            buf = state["self"][name]
+            last = name in POSITIONS_LAST
+            t = buf.shape[-1 if last else 1]
             if s < t:
                 a = jnp.pad(a, ((0, 0), (0, t - s)) + ((0, 0),) * (a.ndim - 2))
             elif s > t:        # windowed: keep the most recent slice,
                 # rotated so token j sits at ring slot j % t.
                 a = jnp.roll(a[:, -t:], s % t, axis=1)
-            cache[name] = a.astype(state["self"][name].dtype)
+            if last:
+                a = jnp.moveaxis(a, 1, -1)
+            cache[name] = a.astype(buf.dtype)
         cache["length"] = jnp.asarray(s, jnp.int32)
         new_state = dict(state)
         new_state["self"] = cache
@@ -223,7 +229,7 @@ def mla_rope(cfg: ModelConfig):
     return inv, yarn_mscale(rs.factor, rs.mscale) / m_all, scale * m_all ** 2
 
 
-def _mla_attention(cfg: ModelConfig, p, xn, pos, state, mode):
+def _mla_attention(cfg: ModelConfig, p, xn, pos, state, mode, layer=None):
     """Latent attention of the normed ``xn`` (B, S, D) -> (o (B, S,
     H * v_head_dim), new_state). The prefill decompresses per-head keys
     and values from the latent; decode attends over the cached latents
@@ -247,7 +253,7 @@ def _mla_attention(cfg: ModelConfig, p, xn, pos, state, mode):
     if mode == "decode":
         o, cache = latent_decode_attend(
             q_nope, q_pe, LatentCache(**state["self"]), c, k_pe[:, :, 0],
-            wkv_b[..., :dn], wkv_b[..., dn:], scale=scale)
+            wkv_b[..., :dn], wkv_b[..., dn:], scale=scale, layer=layer)
         new_state = dict(state)
         new_state["self"] = cache._asdict()
     else:
@@ -263,12 +269,15 @@ def _mla_attention(cfg: ModelConfig, p, xn, pos, state, mode):
 
 
 def _self_attention(cfg: ModelConfig, p, x, *, pos, state, mode,
-                    window=None, kind="g"):
-    """``x`` plus the block's self-attention sublayer -> (x, new_state)."""
+                    window=None, kind="g", layer=None):
+    """``x`` plus the block's self-attention sublayer -> (x, new_state).
+    With ``layer``, ``state`` is a layer stack's decode state and this
+    block is its layer ``layer``: decode writes the token's entries into
+    the stack and attends over that layer of it."""
     b, s, _ = x.shape
     xn = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.mla is not None and kind in ("m", "d"):
-        o, new_state = _mla_attention(cfg, p, xn, pos, state, mode)
+        o, new_state = _mla_attention(cfg, p, xn, pos, state, mode, layer)
     else:
         q, k, v = _qkv(cfg, p, xn, pos)
         new_state = state
@@ -279,7 +288,8 @@ def _self_attention(cfg: ModelConfig, p, x, *, pos, state, mode,
                 new_state = _prefill_cache(state, k=k, v=v)
         else:
             o, cache = decode_attend(q, KVCache(**state["self"]), k, v,
-                                     window=window, cap=cfg.softcap_attn)
+                                     layer=layer, window=window,
+                                     cap=cfg.softcap_attn)
             new_state = dict(state)
             new_state["self"] = cache._asdict()
         o = o.reshape(b, s, cfg.q_dim)
@@ -287,12 +297,13 @@ def _self_attention(cfg: ModelConfig, p, x, *, pos, state, mode,
 
 
 def apply_attn_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode,
-                     kind: str):
+                     kind: str, layer=None):
     b, s, d = x.shape
     window = cfg.window if kind == "l" else None
     with obs.scope(obs.ATTENTION):
         x, new_state = _self_attention(cfg, p, x, pos=pos, state=state,
-                                       mode=mode, window=window, kind=kind)
+                                       mode=mode, window=window, kind=kind,
+                                       layer=layer)
 
         if cfg.family == "encdec" and enc_out is not None:
             xn2 = rms_norm(x, p["lnx"], cfg.norm_eps)
@@ -409,10 +420,11 @@ def _moe_ffn_chunk(cfg: ModelConfig, p, x2: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
-def apply_moe_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode):
+def apply_moe_block(cfg: ModelConfig, p, x, *, pos, state, enc_out, mode,
+                    layer=None):
     with obs.scope(obs.ATTENTION):
         x, new_state = _self_attention(cfg, p, x, pos=pos, state=state,
-                                       mode=mode, kind="m")
+                                       mode=mode, kind="m", layer=layer)
     xn2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     return x + moe_ffn(cfg, p, xn2), new_state
 
@@ -580,21 +592,31 @@ def init_block(cfg: ModelConfig, ini: Initializer, kind: str):
 
 
 def apply_block(cfg: ModelConfig, kind: str, p, x, *, pos, state=None,
-                enc_out=None, mode="full"):
-    if kind in ("g", "l"):
+                enc_out=None, mode="full", layer=None):
+    """One block -> (y, new_state). ``layer``: ``state`` is a layer
+    stack's decode state (:func:`writes_by_position`), of which this
+    block is layer ``layer``; the new state is the stack with this
+    layer's token written."""
+    if kind in ("g", "l", "d"):
         return apply_attn_block(cfg, p, x, pos=pos, state=state,
-                                enc_out=enc_out, mode=mode, kind=kind)
-    if kind == "d":
-        return apply_attn_block(cfg, p, x, pos=pos, state=state,
-                                enc_out=enc_out, mode=mode, kind="d")
+                                enc_out=enc_out, mode=mode, kind=kind,
+                                layer=layer)
     if kind == "m":
         return apply_moe_block(cfg, p, x, pos=pos, state=state,
-                               enc_out=enc_out, mode=mode)
+                               enc_out=enc_out, mode=mode, layer=layer)
     if kind == "r":
         fn = (apply_rwkv_block if cfg.family == "rwkv"
               else apply_rglru_block)
         return fn(cfg, p, x, pos=pos, state=state, enc_out=enc_out, mode=mode)
     raise ValueError(kind)
+
+
+def writes_by_position(state) -> bool:
+    """Whether a block's decode state has a time axis: a KV or latent
+    cache (``{"self": ...}``), which a decode step writes one position
+    of. Recurrent states (RG-LRU, RWKV-6) have none and are replaced
+    whole."""
+    return isinstance(state, dict) and "self" in state
 
 
 def init_state(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
@@ -604,7 +626,7 @@ def init_state(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
         a = cfg.mla
         return {"self": {
             "c": jnp.zeros((batch, max(cache_len, 1), a.kv_lora_rank), dtype),
-            "kpe": jnp.zeros((batch, max(cache_len, 1), a.qk_rope_head_dim),
+            "kpe": jnp.zeros((batch, a.qk_rope_head_dim, max(cache_len, 1)),
                              dtype),
             "length": jnp.zeros((), jnp.int32)}}
     if kind in ("g", "l", "m", "d"):

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs.base import ModelConfig
 
-from .blocks import apply_block, init_block, init_state, pim_weights
+from .blocks import (apply_block, init_block, init_state, pim_weights,
+                     writes_by_position)
 from .layers import Initializer, rms_norm, softcap
 
 __all__ = ["stack_plan", "init_params", "forward", "decode_step",
@@ -276,8 +277,15 @@ def init_decode_state(cfg: ModelConfig, batch: int, cache_len: int,
 
 def decode_step(cfg: ModelConfig, params, token: jnp.ndarray,
                 position: jnp.ndarray, states: Dict[str, Any]):
-    """One-token serve step. token (B,1); position (B,1) absolute."""
-    b = token.shape[0]
+    """One-token serve step. token (B,1); position (B,1) absolute.
+
+    A state with a time axis (:func:`~.blocks.writes_by_position`: KV
+    and latent caches) has only the token's entries written, in place;
+    a recurrent state is replaced whole. Tracing the step counts the
+    layer states of each kind in ``kv_cache.position_writes`` and
+    ``kv_cache.whole_writes`` (:data:`repro.obs.POSITION_WRITES`,
+    :data:`repro.obs.WHOLE_WRITES`).
+    """
     x = params["embed"][token] * (cfg.d_model ** 0.5 if cfg.family != "rwkv"
                                   else 1.0)
     enc_out = states.get("enc_out")
@@ -286,38 +294,53 @@ def decode_step(cfg: ModelConfig, params, token: jnp.ndarray,
     new_states["prefix"] = []
     new_states["suffix"] = []
 
+    def count(state, n):
+        """Count ``n`` layer states like ``state``; whether they are
+        written by position."""
+        by_position = writes_by_position(state)
+        obs.counter(obs.POSITION_WRITES if by_position
+                    else obs.WHOLE_WRITES).inc(n)
+        return by_position
+
     for i, kind in enumerate(prefix):
+        count(states["prefix"][i], 1)
         x, ns = apply_block(cfg, kind, params["prefix"][i], x, pos=position,
                             state=states["prefix"][i], enc_out=enc_out,
                             mode="decode")
         new_states["prefix"].append(ns)
 
     if n_units:
-        # The stacked caches ride the scan CARRY and are updated in place
-        # with dynamic_update_index: XLA keeps one buffer (donated), so a
-        # 32k-context cache costs its own bytes once — not once per scan
-        # ys copy.
+        # The stacked states ride the scan CARRY, donated. A cache is
+        # handed to its block whole, with the layer index: the block
+        # writes the token's entries at (li, slot) and attends over layer
+        # li of the stack, so no layer's cache is copied out or back. A
+        # recurrent state is read at li and written back whole.
+        by_position = [count(st, n_units) for st in states["scan"]]
+
         def step(carry, xs):
             h, scan_states = carry
             blks, li = xs
             out_states = []
             for j, kind in enumerate(unit):
+                st = scan_states[j]
+                if by_position[j]:
+                    h, st = apply_block(cfg, kind, blks[j], h, pos=position,
+                                        state=st, enc_out=enc_out,
+                                        mode="decode", layer=li)
+                    out_states.append(st)
+                    continue
                 with obs.scope(obs.KV_CACHE):
                     st_j = jax.tree.map(
                         lambda s: jax.lax.dynamic_index_in_dim(
-                            s, li, 0, keepdims=False), scan_states[j])
+                            s, li, 0, keepdims=False), st)
                 h, ns = apply_block(cfg, kind, blks[j], h, pos=position,
                                     state=st_j, enc_out=enc_out,
                                     mode="decode")
-                out_states.append(ns)
-            with obs.scope(obs.KV_CACHE):
-                scan_states = [
-                    jax.tree.map(
+                with obs.scope(obs.KV_CACHE):
+                    out_states.append(jax.tree.map(
                         lambda s, n: jax.lax.dynamic_update_index_in_dim(
-                            s, n.astype(s.dtype), li, 0), scan_states[j],
-                        ns_j)
-                    for j, ns_j in enumerate(out_states)]
-            return (h, scan_states), None
+                            s, n.astype(s.dtype), li, 0), st, ns))
+            return (h, out_states), None
 
         (x, out), _ = jax.lax.scan(
             step, (x, states["scan"]),
@@ -325,6 +348,7 @@ def decode_step(cfg: ModelConfig, params, token: jnp.ndarray,
         new_states["scan"] = out
 
     for i, kind in enumerate(suffix):
+        count(states["suffix"][i], 1)
         x, ns = apply_block(cfg, kind, params["suffix"][i], x, pos=position,
                             state=states["suffix"][i], enc_out=enc_out,
                             mode="decode")

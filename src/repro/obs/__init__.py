@@ -12,7 +12,9 @@ Four pieces, one import surface:
   of ``Engine.stats()``), ``obs.write_metrics(path)`` saves it.
   Always on: recording a counter or latency sample is cheap enough to
   not need a switch. :data:`WEIGHT_PLANS` and :data:`PLAN_REUSES` name
-  the serve step's counters of weight plans made and reused.
+  the serve step's counters of weight plans made and reused;
+  :data:`POSITION_WRITES` and :data:`WHOLE_WRITES` the decode step's
+  layer states written by position and whole, counted when it is traced.
 * **Waterfall** (:mod:`.waterfall`) — modeled-cycle counter tracks
   (partition occupancy, gate activity, switching) derived from compiled
   programs, merged into the same trace file; plus the
@@ -76,7 +78,7 @@ __all__ = [
     "PIM_MATMUL", "MOE_ROUTE", "MOE_EXPERTS", "CONTAINER", "COMPILES", "register_program",
     "device_scopes", "watch_compiles",
     # counter names
-    "WEIGHT_PLANS", "PLAN_REUSES",
+    "WEIGHT_PLANS", "PLAN_REUSES", "POSITION_WRITES", "WHOLE_WRITES",
     # logging
     "setup_logging", "get_logger",
 ]
@@ -85,6 +87,12 @@ __all__ = [
 # Counters of the weight-stationary serve step (repro.train.ServeStep).
 WEIGHT_PLANS = "pim.weight_plans"    # weight plans made
 PLAN_REUSES = "pim.plan_reuses"      # steps served from a stored plan
+# Counters of the decode step's state writes, counted as the step is
+# traced (repro.models.transformer.decode_step): layer states written
+# one position a step (KV and latent caches), and written whole
+# (recurrent states).
+POSITION_WRITES = "kv_cache.position_writes"
+WHOLE_WRITES = "kv_cache.whole_writes"
 
 
 # --------------------------------------------------------------- spans ----

@@ -23,6 +23,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.models.attention import POSITIONS_LAST
+
 __all__ = ["param_shardings", "batch_shardings", "state_shardings",
            "logits_sharding", "spec_for_leaf", "abstract_mesh"]
 
@@ -137,7 +139,8 @@ def state_shardings(mesh: Mesh, states: Any):
         # find batch axis: stacked states have a leading layer axis
         specs = [None] * len(shp)
         b_ax = 0
-        # heuristics: (L?, B, T, H, D) KV / (L?, B, T, R) latents /
+        # heuristics: (L?, B, T, H, D) KV / (L?, B, T, R) latents and
+        # (L?, B, R, T) rope keys /
         # (L?, B, nh, hd, hd) wkv / (L?, B, D) vectors / (L?, B, 3, D) conv
         if name in ("k", "v") or (len(shp) >= 4 and name in ("wkv",)):
             b_ax = len(shp) - 4
@@ -161,8 +164,9 @@ def state_shardings(mesh: Mesh, states: Any):
                 # cache write scatters to the owning shard). Cuts
                 # decode_32k peak memory ~16x for gemma2/qwen3/pixtral.
                 specs[-3] = "model"
-        if name in ("c", "kpe") and shp[-2] % tp == 0 and tp > 1:
-            specs[-2] = "model"              # no heads: the sequence
+        t_ax = -1 if name in POSITIONS_LAST else -2
+        if name in ("c", "kpe") and shp[t_ax] % tp == 0 and tp > 1:
+            specs[t_ax] = "model"            # no heads: the sequence
         if name == "wkv" and shp[-3] % tp == 0 and tp > 1:
             specs[-3] = "model"
         if name in ("h", "tshift", "cshift") and shp[-1] % tp == 0 and tp > 1:

@@ -156,7 +156,8 @@ def test_absorbed_decode_equals_decompressed():
     q_pe = jax.random.normal(ks[3], (b, 1, h, dr))
     wkv_b = jax.random.normal(ks[4], (r, h, dn + dv)) * r ** -0.5
     c_new, kpe_new = c[:, filled:filled + 1], kpe[:, filled:filled + 1]
-    cache = LatentCache(c.at[:, filled:].set(0), kpe.at[:, filled:].set(0),
+    cache = LatentCache(c.at[:, filled:].set(0),
+                        kpe.at[:, filled:].set(0).swapaxes(1, 2),
                         jnp.asarray(filled, jnp.int32))
     _, _, scale = mla_rope(cfg)
     with jax.default_matmul_precision("highest"):

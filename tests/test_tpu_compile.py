@@ -112,3 +112,81 @@ def test_resident_jax_chain_step_compiles(one_chip, eng):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= w * idx.c_mac * 4
     assert mem.temp_size_in_bytes < 16 * 2 ** 30     # v5e HBM
+
+
+@pytest.mark.parametrize("cell,t", [("deepseek-7b", 1024),
+                                    ("deepseek-v2-lite", 4096),
+                                    ("deepseek-7b", 1031),
+                                    ("deepseek-v2-lite", 4099)])
+def test_decode_attention_kernels_compile(one_chip, cell, t):
+    """The decode-attention kernels over the decode cells' stacks:
+    deepseek-7b's K/V (6 layers, batch 8, 1024 positions, 32 heads of
+    128) and deepseek-v2-lite's latents (8 layers, batch 32, 4096
+    positions, rank 512, rope keys of 64 positions-last); and at prime
+    cache lengths, where the last time block runs past the cache."""
+    from repro.kernels.decode_attention import (kv_decode_attention,
+                                                latent_decode_attention)
+    i32 = _spec(one_chip, (), jnp.int32)
+    if cell == "deepseek-7b":
+        kv = _spec(one_chip, (6, 8, t, 32, 128), jnp.float32)
+        q = _spec(one_chip, (8, 1, 32, 128), jnp.float32)
+        lowered = kv_decode_attention.lower(q, kv, kv, i32, i32, i32,
+                                            interpret=False)
+    else:
+        c = _spec(one_chip, (8, 32, t, 512), jnp.float32)
+        kpe = _spec(one_chip, (8, 32, 64, t), jnp.float32)
+        q_lat = _spec(one_chip, (32, 16, 512), jnp.float32)
+        q_pe = _spec(one_chip, (32, 16, 64), jnp.float32)
+        lowered = latent_decode_attention.lower(
+            q_lat, q_pe, c, kpe, i32, i32, i32, scale=0.1,
+            precision=jax.lax.Precision.HIGHEST, interpret=False)
+    _assert_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("arch", ["gemma2-9b", "deepseek-v2-lite"])
+def test_decode_step_copies_no_layer_of_a_cache(one_chip, arch):
+    """A whole decode step for one chip over stacked layers, at the
+    cells' cache widths (heads of 128; latents of 512 with rope keys of
+    64) and smoke widths elsewhere: gemma2's windowed ring beside a
+    global layer, and MLA's latents behind a dense layer. Attention is
+    the kernel; no copy or dynamic slice in the optimized HLO yields a
+    layer's cache or a stack, and the temporaries hold less than one
+    layer's cache. (At these sizes the compiler may keep a small stack in
+    fast memory through the scan: its asynchronous ``copy-start`` /
+    ``copy-done`` between memory spaces is not a copy of the cache.)"""
+    import re
+
+    from repro.configs import MLAConfig, get_config
+    from repro.models import build_model
+    cfg = get_config(arch, smoke=True)
+    if arch == "gemma2-9b":
+        cfg = cfg.scaled(n_layers=4, window=128, n_heads=2, n_kv_heads=1,
+                         head_dim=128)
+    else:
+        cfg = cfg.scaled(n_layers=4, layer_pattern="dmmm", n_heads=2,
+                         mla=MLAConfig(512, None, 128, 64, 128))
+    m = build_model(cfg)
+    on = lambda t: jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    params = on(jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+    states = on(jax.eval_shape(lambda: m.init_decode_state(8, 512)))
+    tok = _spec(one_chip, (8, 1), jnp.int32)
+    compiled = jax.jit(m.decode_step, donate_argnums=(3,)).lower(
+        params, tok, tok, states).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    caches = [a for a in jax.tree.leaves(states) if a.ndim >= 3]
+    shapes = set()
+    for a in caches:
+        shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    moved = []
+    for name, dims, op in re.findall(
+            r"%([\w.\-]+) = f32\[([\d,]*)\]\S* ([\w\-]+)\(", text):
+        kind = op if op != "fusion" else name
+        if op in ("copy-start", "copy-done"):
+            continue
+        if ("copy" in kind or "dynamic-slice" in kind) and tuple(
+                int(x) for x in dims.split(",") if x) in shapes:
+            moved.append((name, dims))
+    assert not moved, moved
+    layer = min(a.size // a.shape[0] * 4 for a in caches)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer
