@@ -1,11 +1,12 @@
-"""Section VI end-to-end through the engine: full-precision fixed-point
-matrix-vector multiplication on the simulated crossbar, a PIM-mode
-linear layer, and the LM-scale PIM plan.
+"""Section VI end to end: full-precision fixed-point matrix-vector
+multiplication on the simulated crossbar (through the engine), a
+PIM-mode linear layer (repro.pim), and the LM-scale PIM plan.
 
     PYTHONPATH=src python examples/pim_matvec.py
 """
 import numpy as np
 
+from repro import pim
 from repro.configs import get_config
 from repro.core.matvec import (floatpim_matvec_latency,
                                matvec_latency_formula)
@@ -29,18 +30,18 @@ res, cycles = eng.matvec(A, x, 8)
 ok = all(int(r) == int(w) for r, w in zip(res, A.astype(object) @ x))
 print(f"crossbar matvec 8x6 @ 8-bit: {cycles} cycles, bit-exact={ok}")
 
-# 3. the same MAC powering a neural linear layer (what the serve path
-#    runs for PIM-mode LM heads):
+# 3. the MAC's fixed-point semantics as a neural linear layer (what the
+#    serve path runs for PIM-mode LM heads):
 import jax.numpy as jnp
 xf = jnp.asarray(np.random.default_rng(2).standard_normal((4, 64)),
                  jnp.float32)
 wf = jnp.asarray(np.random.default_rng(3).standard_normal((64, 16)),
                  jnp.float32)
-y = eng.linear(xf, wf, n_bits=8, mode="pim")
+y = pim.linear(xf, wf, 8)
 yref = np.asarray(xf @ wf)
 err = float(np.max(np.abs(np.asarray(y) - yref)))
 print(f"PIM-mode linear 4x64x16 @ 8-bit: max |err| vs float = {err:.3f}")
-print(f"engine cache after matvec+linear: {eng.stats()}")
+print(f"engine cache after matvec: {eng.stats()}")
 
 # 4. what a PIM accelerator would do to a real LM layer stack:
 cfg = get_config("deepseek-7b")

@@ -99,19 +99,24 @@ class ModelConfig:
     norm_eps: float = 1e-6
     source: str = ""
     # PIM offload: run the LM-head linear under MultPIM fixed-point
-    # semantics via the shared repro.engine ("off" | "pim" | "fake").
+    # semantics (repro.pim.linear): "off" | "pim".
     pim_linear_mode: str = "off"
     pim_linear_bits: int = 8
-    # How much of each *block* also routes through the PIM engine
+    # How much of each *block* also runs as PIM linears
     # (co-scheduled crossbar groups; see repro.pim.planner):
     #   "none" — only the LM head (pim_linear_mode) is PIM-offloaded
     #   "ffn"  — + both FFN projections (incl. MoE per-expert GEMMs)
     #   "full" — + the attention q/k/v/o projections
     pim_block_mode: str = "none"
 
+    def __post_init__(self):
+        if self.pim_linear_mode not in ("off", "pim"):
+            raise ValueError(f"pim_linear_mode is 'off' or 'pim', not "
+                             f"{self.pim_linear_mode!r}")
+
     def pim_scopes(self) -> Tuple[str, ...]:
-        """Linear scopes routed through the PIM engine under the current
-        mode flags (subset of ("head", "ffn", "attn"))."""
+        """Linear scopes that run as PIM linears under the current mode
+        flags (subset of ("head", "ffn", "attn"))."""
         scopes = []
         if self.pim_linear_mode != "off":
             scopes.append("head")

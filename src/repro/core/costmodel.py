@@ -12,7 +12,7 @@ Two families of numbers flow through the framework:
 The tiling model maps a fixed-point GEMM onto crossbar tiles the way
 Section VI lays out matrix-vector products (one inner product per row,
 vector duplicated down the rows), giving the PIM-side latency/area/energy
-proxies that :mod:`repro.pim.planner` attaches to every PIMLinear layer.
+proxies that :mod:`repro.pim.planner` attaches to every PIM linear.
 """
 from __future__ import annotations
 

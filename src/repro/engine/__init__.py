@@ -26,17 +26,17 @@ partition/column ranges of one crossbar and returns a
 (``cost().cycles_per_program`` is the cycles-per-MAC the throughput
 benchmarks track);
 ``eng.multiply`` / ``eng.mac`` / ``eng.matvec`` / ``eng.inner_product``
-/ ``eng.linear`` are the high-level ops the examples, benchmarks and
-the PIM-mode serve path all share. Backends are pluggable
+are the high-level ops the examples and benchmarks share; the PIM serve
+path's planner (:func:`repro.pim.plan_block`) compiles its crossbar
+groups here. Backends are pluggable
 (:func:`register_backend`) and selectable per compile or per run:
 ``"numpy"``, ``"jax:pack=true"``, ``"pallas:pack=true"`` (the Pallas
 interpreter on the CPU, Mosaic on a TPU). With no backend named, the
 platform picks one: numpy on the CPU, ``jax:pack=true`` on a TPU.
 
 Legacy entry points (``repro.core.matvec.matvec``,
-``repro.kernels.ops.crossbar_run_cached``,
-``repro.pim.pim_linear_apply``) remain as thin deprecation shims that
-delegate here — new code should talk to the Engine.
+``repro.kernels.ops.crossbar_run_cached``) remain as thin deprecation
+shims that delegate here — new code should talk to the Engine.
 """
 from .backends import (DEFAULT_MACRO, Backend, JaxBackend, NumpyBackend,
                        PallasBackend, autotune_row_block, backend_names,

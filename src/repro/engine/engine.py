@@ -5,10 +5,9 @@ differential verify -> packed tables (all via the OpSpec-keyed
 :mod:`repro.compiler.cache`, including its disk spill) -> a
 :class:`~repro.engine.executable.Executable` bound to a
 :class:`~repro.engine.backends.Backend`. High-level ops (``multiply``,
-``mac``, ``inner_product``, ``matvec``, ``linear``) are built on that
-same compile path, so every layer of the stack — examples, benchmarks,
-the PIM-mode serve path — shares one program cache and one backend
-policy.
+``mac``, ``inner_product``, ``matvec``) are built on that same compile
+path, so every layer of the stack — examples, benchmarks, the PIM
+serve planner — shares one program cache and one backend policy.
 
 :meth:`Engine.compile_batch` is the multi-program co-scheduling entry:
 K copies of one verified program are relocated into disjoint
@@ -671,111 +670,6 @@ class Engine:
         X = np.broadcast_to(np.asarray(x)[None, :], A.shape)
         return self.inner_product(A, X, n, use_compiler=use_compiler,
                                   backend=backend, k=k, resident=resident)
-
-    def linear(self, x, w, b=None, *, n_bits: int = 8, mode: str = "pim",
-               use_pallas: bool = False):
-        """A linear layer under MultPIM fixed-point semantics.
-
-        ``mode``: ``float`` (plain matmul) | ``pim`` (quantize, integer
-        matmul bit-identical to the in-memory MultPIM-MAC, dequantize) |
-        ``fake`` (quantize-dequantize straight-through for PIM-aware
-        finetuning). In ``pim`` mode the Section-VI MAC for ``n_bits`` is
-        compiled through this engine's shared cache, so serving traffic
-        pays schedule compilation once per width, and the per-layer cost
-        model rides the same verified program. In ``pim`` mode ``w`` may
-        also be a :class:`~repro.pim.quant.PlannedWeight` (quantized
-        once, :func:`repro.models.transformer.plan_weights`); a float
-        ``w`` is planned in the call. Either way the activations are
-        quantized per call and multiplied by the centred codes in one
-        integer product (:func:`~repro.pim.quant.qmatmul_planned`).
-        """
-        import jax.numpy as jnp
-
-        from repro.pim.quant import (PlannedWeight, dequantize,
-                                     plan_weight, qmatmul_planned,
-                                     quantize)
-        if mode == "float":
-            y = x @ w
-        elif mode == "fake":
-            xq = quantize(x, n_bits)
-            wq = quantize(w, n_bits, axis=0)
-            y = dequantize(xq) @ dequantize(wq)
-        elif mode == "pim":
-            # The schedule actually accounted/executed in-memory: the
-            # co-scheduled K-MAC group, compiled once per (width, K)
-            # through the shared cache (hits afterwards) — decode-time
-            # traffic is accounted at ~K fewer crossbar passes per
-            # inner product than the sequential path. K is clamped to
-            # the crossbar's column budget (wide MACs fit fewer copies;
-            # a MAC too wide for any co-scheduling compiles plain).
-            k = self.effective_coschedule_k("mac", n_bits)
-            if k >= 2:
-                self.compile_batch("mac", n_bits, k)
-            else:
-                self.compile("mac", n_bits)
-            in_dim = x.shape[-1]
-            lead = x.shape[:-1]
-            x2 = x.reshape(-1, in_dim)
-            xq = quantize(x2, n_bits)
-            if use_pallas:
-                if isinstance(w, PlannedWeight):
-                    raise ValueError("the Pallas route takes float weights")
-                wq = quantize(w, n_bits, axis=0)
-                from repro.kernels.ops import bitserial_matmul
-                with obs.scope(obs.PIM_MATMUL):
-                    prod = bitserial_matmul(xq.q, wq.q.astype(jnp.float32),
-                                            n_bits)
-                    k = x2.shape[-1]
-                    corr = (xq.zero * jnp.sum(wq.q.astype(jnp.float32),
-                                              axis=0, keepdims=True)
-                            + wq.zero * jnp.sum(xq.q.astype(jnp.float32),
-                                                axis=-1, keepdims=True)
-                            - k * xq.zero * wq.zero)
-                    y = (prod - corr) * xq.scale * wq.scale
-            else:
-                if not isinstance(w, PlannedWeight):
-                    w = plan_weight(w, n_bits)
-                y = qmatmul_planned(xq, w)
-            y = y.reshape(*lead, w.shape[-1])
-        else:
-            raise ValueError(mode)
-        if b is not None:
-            y = y + b
-        return y
-
-    def ragged_linear(self, xs, we, counts, *, n_bits: int = 8,
-                      mode: str = "pim"):
-        """MoE dropless per-expert grouped GEMM under MultPIM fixed-point
-        semantics: ``xs`` (T, D) expert-sorted rows, ``we`` (E, D, F)
-        per-expert weight stack, ``counts`` (E,) ragged segment lengths.
-
-        Same mode contract as :meth:`linear` (``float`` | ``fake`` |
-        ``pim``); in ``pim`` mode every expert's GEMM is the quantized
-        integer path bit-identical to the in-memory MultPIM-MAC
-        (:func:`repro.pim.quant.qragged_matmul_exact`), compiled and
-        accounted through this engine's shared co-scheduled MAC group
-        exactly like the dense projections — the ragged path shares the
-        crossbar, it does not get a private one.
-        """
-        import jax
-
-        from repro.pim.quant import (dequantize, qragged_matmul_exact,
-                                     quantize)
-        if mode == "float":
-            return jax.lax.ragged_dot(xs, we, counts)
-        if mode == "fake":
-            xq = quantize(xs, n_bits)
-            wq = quantize(we, n_bits)
-            return jax.lax.ragged_dot(dequantize(xq), dequantize(wq), counts)
-        if mode != "pim":
-            raise ValueError(mode)
-        k = self.effective_coschedule_k("mac", n_bits)
-        if k >= 2:
-            self.compile_batch("mac", n_bits, k)
-        else:
-            self.compile("mac", n_bits)
-        return qragged_matmul_exact(quantize(xs, n_bits),
-                                    quantize(we, n_bits), counts)
 
 
 # ------------------------------------------------------ shared default ----

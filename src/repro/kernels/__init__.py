@@ -7,10 +7,8 @@ word, bitwise gate evaluation); backends select them via ``pack=true``
 policy — see :mod:`repro.engine.backends`.
 """
 from .crossbar_step import crossbar_run_pallas_packed
-from .ops import (bitserial_matmul, bitserial_matmul_ref, crossbar_run,
-                  crossbar_run_ref)
+from .ops import crossbar_run, crossbar_run_ref
 from .ref import crossbar_run_ref_packed
 
 __all__ = ["crossbar_run", "crossbar_run_ref",
-           "crossbar_run_ref_packed", "crossbar_run_pallas_packed",
-           "bitserial_matmul", "bitserial_matmul_ref"]
+           "crossbar_run_ref_packed", "crossbar_run_pallas_packed"]

@@ -13,12 +13,10 @@ import jax.numpy as jnp
 
 from repro.core.executor import PackedProgram
 
-from .bitserial_matmul import bitserial_matmul_pallas
 from .crossbar_step import crossbar_run_pallas
-from .ref import bitserial_matmul_ref, crossbar_run_ref
+from .ref import crossbar_run_ref
 
-__all__ = ["crossbar_run", "crossbar_run_cached", "bitserial_matmul",
-           "crossbar_run_ref", "bitserial_matmul_ref"]
+__all__ = ["crossbar_run", "crossbar_run_cached", "crossbar_run_ref"]
 
 
 def crossbar_run(state_bits: jnp.ndarray, packed: PackedProgram, *,
@@ -49,13 +47,3 @@ def crossbar_run_cached(state_bits: jnp.ndarray, kind: str, n: int, *,
     return crossbar_run(state_bits, exe.packed, use_pallas=use_pallas,
                         interpret=interpret, row_block=row_block)
 
-
-def bitserial_matmul(x: jnp.ndarray, w: jnp.ndarray, n_bits: int = 8, *,
-                     use_pallas: bool = True,
-                     interpret: Optional[bool] = None,
-                     bm: int = 128, bn: int = 128, bk: int = 128
-                     ) -> jnp.ndarray:
-    if use_pallas:
-        return bitserial_matmul_pallas(x, w, n_bits, bm=bm, bn=bn, bk=bk,
-                                       interpret=interpret)
-    return bitserial_matmul_ref(x, w, n_bits)

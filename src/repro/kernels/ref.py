@@ -25,8 +25,7 @@ from repro.core.isa import Gate
 
 __all__ = ["crossbar_run_ref", "crossbar_run_ref_packed",
            "crossbar_run_ref_packed_faulty",
-           "packed_scan_body", "packed_device_tables",
-           "bitserial_matmul_ref"]
+           "packed_scan_body", "packed_device_tables"]
 
 
 def crossbar_run_ref(state_bits: jnp.ndarray, packed: PackedProgram
@@ -181,13 +180,3 @@ def crossbar_run_ref_packed_faulty(state_words: jnp.ndarray,
                       jnp.asarray(sa1))
     return st[:, :state_words.shape[1]]
 
-
-def bitserial_matmul_ref(x: jnp.ndarray, w: jnp.ndarray,
-                         n_bits: int = 8) -> jnp.ndarray:
-    """Bit-plane decomposition reference: sum_j 2^j (X_j @ W)."""
-    x = jnp.asarray(x, jnp.int32)
-    acc = jnp.zeros((x.shape[0], w.shape[1]), jnp.float32)
-    for j in range(n_bits):
-        plane = ((x >> j) & 1).astype(jnp.float32)
-        acc += (2.0 ** j) * plane @ w.astype(jnp.float32)
-    return acc

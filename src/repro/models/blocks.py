@@ -33,7 +33,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import obs
+from repro import obs, pim
 from repro.configs.base import ModelConfig
 
 from .attention import (POSITIONS_LAST, KVCache, LatentCache, attend,
@@ -47,22 +47,13 @@ __all__ = ["init_block", "apply_block", "init_state", "pim_proj",
 # ------------------------------------------------------ PIM offload ----
 def pim_proj(cfg: ModelConfig, x: jnp.ndarray, w: jnp.ndarray, *,
              scope: str) -> jnp.ndarray:
-    """One block linear, optionally offloaded to the PIM engine.
-
-    ``scope`` is ``"attn"`` (q/k/v/o projections) or ``"ffn"`` (both
-    FFN projections); whether it routes through the engine is governed
-    by ``cfg.pim_block_mode`` (:meth:`ModelConfig.pim_scopes`). The
-    engine path quantizes to ``cfg.pim_linear_bits``, runs the integer
-    matmul bit-identical to the in-memory MultPIM-MAC, and compiles the
-    co-scheduled MAC group into the process-shared program cache at
-    trace time — every projection of every layer reuses the one
-    verified schedule (weight-stationary: decode steps never recompile).
-    """
+    """One linear of ``scope`` (``"head"``, ``"attn"`` for the q/k/v/o
+    projections, ``"ffn"`` for the FFN's): a PIM linear at
+    ``cfg.pim_linear_bits`` (:func:`repro.pim.linear`) when the scope is
+    in ``cfg.pim_scopes()``, else ``x @ w``."""
     if scope not in cfg.pim_scopes():
         return x @ w
-    from repro.engine import get_engine   # lazy: models stay engine-free
-    mode = "pim" if cfg.pim_linear_mode == "off" else cfg.pim_linear_mode
-    return get_engine().linear(x, w, n_bits=cfg.pim_linear_bits, mode=mode)
+    return pim.linear(x, w, cfg.pim_linear_bits)
 
 
 _MLP = ("w1", "w2", "w3")
@@ -92,14 +83,11 @@ def pim_weights(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
 
 def _pim_ragged(cfg: ModelConfig, xs: jnp.ndarray, we: jnp.ndarray,
                 counts: jnp.ndarray) -> jnp.ndarray:
-    """MoE per-expert grouped GEMM, PIM-offloaded under the ``"ffn"``
-    scope (the expert FFNs are the block's FFN projections)."""
+    """MoE per-expert grouped GEMM: a PIM one (:func:`repro.pim.
+    ragged_linear`) under the ``"ffn"`` scope, else ``ragged_dot``."""
     if "ffn" not in cfg.pim_scopes():
         return jax.lax.ragged_dot(xs, we, counts)
-    from repro.engine import get_engine
-    mode = "pim" if cfg.pim_linear_mode == "off" else cfg.pim_linear_mode
-    return get_engine().ragged_linear(xs, we, counts,
-                                      n_bits=cfg.pim_linear_bits, mode=mode)
+    return pim.ragged_linear(xs, we, counts, cfg.pim_linear_bits)
 
 
 # ============================================================ attention ====

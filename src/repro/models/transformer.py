@@ -19,37 +19,14 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.configs.base import ModelConfig
+from repro.pim.quant import plan_weight
 
-from .blocks import (apply_block, init_block, init_state, pim_weights,
-                     writes_by_position)
+from .blocks import (apply_block, init_block, init_state, pim_proj,
+                     pim_weights, writes_by_position)
 from .layers import Initializer, rms_norm, softcap
 
 __all__ = ["stack_plan", "init_params", "forward", "decode_step",
-           "init_decode_state", "encode", "head_matmul", "plan_weights"]
-
-
-def head_matmul(cfg: ModelConfig, x: jnp.ndarray,
-                head: jnp.ndarray) -> jnp.ndarray:
-    """LM-head projection, optionally offloaded to the PIM engine.
-
-    With ``cfg.pim_linear_mode != "off"`` the projection runs as a
-    PIM-mode linear through the process-shared :mod:`repro.engine` — the
-    Section-VI MAC schedule for ``cfg.pim_linear_bits`` is compiled into
-    the engine's program cache at trace time (once per width) and the
-    matmul itself uses the bit-identical quantized integer path.
-
-    This is the ``"head"`` scope of the PIM offload; the *block* scopes
-    (attention q/k/v/o and FFN projections, incl. the MoE ragged path)
-    route through :func:`repro.models.blocks.pim_proj` under
-    ``cfg.pim_block_mode`` and share the same engine, so one verified
-    MAC schedule serves the whole model (see
-    :func:`repro.pim.planner.plan_block` for the crossbar grouping).
-    """
-    if cfg.pim_linear_mode == "off":
-        return x @ head
-    from repro.engine import get_engine   # lazy: models stay engine-free
-    return get_engine().linear(x, head, n_bits=cfg.pim_linear_bits,
-                               mode=cfg.pim_linear_mode)
+           "init_decode_state", "encode", "plan_weights"]
 
 
 # ------------------------------------------------------------ planning ----
@@ -129,9 +106,7 @@ def plan_weights(cfg: ModelConfig, params: Dict[str, Any]) -> Dict[str, Any]:
     The identity when no linear runs in ``pim`` mode. Traceable, so
     ``jax.eval_shape`` gives the plan's shapes.
     """
-    from repro.pim.quant import plan_weight   # lazy: as head_matmul's
-
-    scopes = () if cfg.pim_linear_mode == "fake" else cfg.pim_scopes()
+    scopes = cfg.pim_scopes()
     if not scopes:
         return params
     bits = cfg.pim_linear_bits
@@ -252,7 +227,7 @@ def forward(cfg: ModelConfig, params, tokens: jnp.ndarray, *,
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head", params["embed"].T)
-    logits = head_matmul(cfg, x, head)
+    logits = pim_proj(cfg, x, head, scope="head")
     logits = softcap(logits, cfg.softcap_final)
     return logits, (new_states if states is not None else None)
 
@@ -356,5 +331,6 @@ def decode_step(cfg: ModelConfig, params, token: jnp.ndarray,
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head", params["embed"].T)
-    logits = softcap(head_matmul(cfg, x, head), cfg.softcap_final)
+    logits = softcap(pim_proj(cfg, x, head, scope="head"),
+                     cfg.softcap_final)
     return logits, new_states

@@ -1,63 +1,40 @@
-"""PIMLinear: a linear layer executed with MultPIM fixed-point semantics.
+"""The PIM linear: a product under MultPIM fixed-point semantics.
 
-Three numerically-linked execution paths:
+:func:`linear` quantizes the activations per call and multiplies them
+by the weight's centred codes in one integer product
+(:func:`~repro.pim.quant.qmatmul_planned`), bit-identical to what the
+in-memory MultPIM-MAC computes on the quantized operands; the weight is
+a :class:`~repro.pim.quant.PlannedWeight` (quantized once,
+:func:`repro.models.transformer.plan_weights`) or a float weight planned
+in the call. :func:`ragged_linear` is the MoE grouped GEMM over
+per-expert segments (:func:`~repro.pim.quant.qragged_matmul_exact`).
 
-1. ``mode="float"`` — plain f32/bf16 matmul (training / baseline).
-2. ``mode="pim"`` — quantize activations+weights to N bits, integer
-   matmul via the CSAS bit-serial Pallas kernel (bit-identical to what
-   the in-memory MultPIM-MAC computes; tests close the loop against the
-   cycle-accurate simulator on small tiles), dequantize.
-3. ``mode="fake"`` — quantize-dequantize with a float matmul
-   (straight-through estimator for PIM-aware finetuning).
-
-Every PIMLinear also knows its Section-VI crossbar cost
-(:func:`repro.core.costmodel.gemm_cost`), which the planner aggregates
-into per-model PIM latency/area reports.
+Both are traceable and touch no crossbar: what the linears cost on the
+crossbar is the planner's (:func:`repro.pim.planner.plan_block`).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Optional
+import jax.numpy as jnp
 
-from repro.core.costmodel import CrossbarSpec, GemmCost, gemm_cost
+from . import quant
 
-__all__ = ["PIMLinearSpec", "pim_linear_apply"]
-
-
-@dataclass(frozen=True)
-class PIMLinearSpec:
-    in_dim: int
-    out_dim: int
-    n_bits: int = 8
-    mode: str = "float"           # float | pim | fake
-    use_pallas: bool = False      # route the int matmul through Pallas
-    # Which block-plan scope this linear belongs to ("head" | "ffn" |
-    # "attn") — the co-scheduled crossbar group it shares passes with
-    # under full-block serving (repro.pim.planner.plan_block).
-    scope: str = "head"
-
-    def cost(self, batch_rows: int,
-             spec: CrossbarSpec = CrossbarSpec()) -> GemmCost:
-        return gemm_cost(batch_rows, self.in_dim, self.out_dim,
-                         self.n_bits, spec=spec)
-
-    def as_block_linear(self) -> "BlockLinear":
-        """This spec as the planner's inventory record."""
-        from .planner import BlockLinear
-        return BlockLinear(name=f"{self.scope}.linear", scope=self.scope,
-                           in_dim=self.in_dim, out_dim=self.out_dim)
+__all__ = ["linear", "ragged_linear"]
 
 
-def pim_linear_apply(spec: PIMLinearSpec, x: jnp.ndarray, w: jnp.ndarray,
-                     b: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """x (..., in_dim) @ w (in_dim, out_dim) under the chosen mode.
+def linear(x: jnp.ndarray, w, n_bits: int) -> jnp.ndarray:
+    """``x`` (..., in) @ ``w`` (in, out) at ``n_bits``. A weight planned
+    at another width raises."""
+    lead = x.shape[:-1]
+    xq = quant.quantize(x.reshape(-1, x.shape[-1]), n_bits)
+    if not isinstance(w, quant.PlannedWeight):
+        w = quant.plan_weight(w, n_bits)
+    return quant.qmatmul_planned(xq, w).reshape(*lead, w.shape[-1])
 
-    Deprecation shim for :meth:`repro.engine.Engine.linear`: every
-    PIM-mode linear in the process (serve path included) runs through
-    the one shared Engine, so the Section-VI MAC schedule for
-    ``spec.n_bits`` compiles exactly once and the cost model rides the
-    same verified program.
-    """
-    from repro.engine import get_engine
-    return get_engine().linear(x, w, b, n_bits=spec.n_bits, mode=spec.mode,
-                               use_pallas=spec.use_pallas)
+
+def ragged_linear(xs: jnp.ndarray, we: jnp.ndarray, counts: jnp.ndarray,
+                  n_bits: int) -> jnp.ndarray:
+    """``xs`` (T, D) expert-sorted rows against ``we`` (E, D, F), row
+    segments of ``counts`` (E,) lengths, each expert's GEMM at
+    ``n_bits``; the stack is quantized in the call, with one scale."""
+    return quant.qragged_matmul_exact(quant.quantize(xs, n_bits),
+                                      quant.quantize(we, n_bits), counts)

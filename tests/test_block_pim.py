@@ -1,7 +1,7 @@
 """Full-block PIM serving: block linear inventory, co-scheduled group
 planning (chains by column budget, weight-stationary reuse), the model
-hooks that route attention/FFN/MoE projections through the engine, and
-the quantized ragged path's parity with the dense correction."""
+hooks that run attention/FFN/MoE projections as PIM linears, and the
+quantized ragged path's parity with the dense correction."""
 import dataclasses
 
 import jax
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.engine import Engine, get_engine
+from repro.engine import Engine
 from repro.pim import (QTensor, block_linears, plan_block, qmatmul_exact,
                        qragged_matmul_exact, quantize)
 
@@ -328,16 +328,15 @@ def test_qragged_temporaries_grow_with_rows_not_rows_times_experts():
 
 
 def test_engine_ragged_linear_modes():
-    eng = get_engine()
+    """The PIM grouped GEMM (``repro.pim.ragged_linear``) against the
+    float one (``ragged_dot``)."""
+    from repro import pim
     rng = np.random.default_rng(4)
     xs = jnp.asarray(rng.standard_normal((5, 6)), jnp.float32)
     we = jnp.asarray(rng.standard_normal((2, 6, 4)), jnp.float32)
     counts = jnp.asarray([3, 2], jnp.int32)
-    yf = eng.ragged_linear(xs, we, counts, mode="float")
-    yp = eng.ragged_linear(xs, we, counts, mode="pim")
-    yk = eng.ragged_linear(xs, we, counts, mode="fake")
-    assert yf.shape == yp.shape == yk.shape == (5, 4)
+    yf = jax.lax.ragged_dot(xs, we, counts)
+    yp = pim.ragged_linear(xs, we, counts, 8)
+    assert yf.shape == yp.shape == (5, 4)
     rel = float(jnp.linalg.norm(yp - yf) / jnp.linalg.norm(yf))
     assert rel < 0.05
-    with pytest.raises(ValueError):
-        eng.ragged_linear(xs, we, counts, mode="bogus")

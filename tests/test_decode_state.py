@@ -21,9 +21,9 @@ from repro.kernels.decode_attention import (kv_decode_attention,
 from repro.models import build_model
 from repro.models.attention import (KVCache, LatentCache, NEG_INF,
                                     decode_attend, latent_decode_attend)
-from repro.models.blocks import apply_block
+from repro.models.blocks import apply_block, pim_proj
 from repro.models.layers import rms_norm, softcap
-from repro.models.transformer import head_matmul, stack_plan
+from repro.models.transformer import stack_plan
 
 WINDOW = 8
 CACHE = 32
@@ -84,7 +84,8 @@ def _parent_decode_step(cfg, params, token, position, states):
         new["suffix"].append(ns)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head", params["embed"].T)
-    return softcap(head_matmul(cfg, x, head), cfg.softcap_final), new
+    return (softcap(pim_proj(cfg, x, head, scope="head"), cfg.softcap_final),
+            new)
 
 
 @pytest.mark.parametrize("kind", ["g", "l", "mla", "rglru", "rwkv"])

@@ -242,34 +242,18 @@ def test_engine_matvec_matches_pre_redesign_path():
 
 
 def test_engine_linear_matches_pre_redesign_pim_linear():
+    """The PIM linear (``repro.pim.linear``) gives the pre-redesign
+    result: quantize -> exact int matmul -> dequantize."""
     import jax.numpy as jnp
 
-    from repro.pim import PIMLinearSpec, pim_linear_apply
+    from repro import pim
     from repro.pim.quant import qmatmul_exact, quantize
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((5, 32)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((32, 12)), jnp.float32)
-    # pre-redesign reference: quantize -> exact int matmul -> dequantize
     want = qmatmul_exact(quantize(x, 8), quantize(w, 8, axis=0))
-    got = get_engine().linear(x, w, n_bits=8, mode="pim")
+    got = pim.linear(x, w, 8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    shim = pim_linear_apply(PIMLinearSpec(32, 12, mode="pim"), x, w)
-    np.testing.assert_array_equal(np.asarray(shim), np.asarray(got))
-    f = get_engine().linear(x, w, n_bits=8, mode="float")
-    np.testing.assert_allclose(np.asarray(f), np.asarray(x @ w), rtol=1e-6)
-
-
-def test_linear_pim_mode_registers_mac_in_shared_cache():
-    clear_cache()
-    eng = get_engine()
-    import jax.numpy as jnp
-    x = jnp.ones((2, 8), jnp.float32)
-    w = jnp.ones((8, 3), jnp.float32)
-    eng.linear(x, w, n_bits=4, mode="pim")
-    eng.linear(x, w, n_bits=4, mode="pim")
-    st = eng.stats()
-    assert st["misses"] == 1 and st["hits"] >= 1   # compile once, reuse
-    assert eng.compile("mac", 4).entry is eng.compile("multpim_mac", 4).entry
 
 
 def test_run_many_identity_stable_tables():

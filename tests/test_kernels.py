@@ -6,8 +6,7 @@ import pytest
 from repro.core.bits import to_bits
 from repro.core.executor import pack_program, run_numpy
 from repro.core.multpim import multpim_multiplier
-from repro.kernels.ops import (bitserial_matmul, bitserial_matmul_ref,
-                               crossbar_run, crossbar_run_ref)
+from repro.kernels.ops import crossbar_run, crossbar_run_ref
 
 pytestmark = pytest.mark.kernels
 
@@ -42,40 +41,3 @@ def test_crossbar_kernel_vs_ref_oracle():
     got = np.asarray(crossbar_run(jnp.asarray(state), packed))
     ref = np.asarray(crossbar_run_ref(jnp.asarray(state), packed))
     assert (got == ref).all()
-
-
-@pytest.mark.parametrize("m,k,n,bits", [
-    (32, 64, 16, 8), (100, 96, 60, 8), (17, 130, 33, 4), (64, 64, 64, 2)])
-def test_bitserial_matmul_sweep(m, k, n, bits):
-    rng = np.random.default_rng(m * k)
-    x = rng.integers(0, 1 << bits, (m, k)).astype(np.int32)
-    w = rng.standard_normal((k, n)).astype(np.float32)
-    got = np.asarray(bitserial_matmul(jnp.asarray(x), jnp.asarray(w), bits))
-    ref = np.asarray(bitserial_matmul_ref(jnp.asarray(x), jnp.asarray(w),
-                                          bits))
-    # kernel pads/tiles K, so accumulation order differs from the ref
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=5e-3)
-    exact = x.astype(np.float64) @ w.astype(np.float64)
-    np.testing.assert_allclose(got, exact, rtol=3e-4, atol=5e-3)
-
-
-def test_bitserial_matmul_int_exact():
-    """Integer weights: the kernel is bit-exact (the PIM semantics)."""
-    rng = np.random.default_rng(7)
-    x = rng.integers(0, 256, (50, 80)).astype(np.int32)
-    w = rng.integers(-64, 64, (80, 30)).astype(np.float32)
-    got = np.asarray(bitserial_matmul(jnp.asarray(x), jnp.asarray(w), 8))
-    assert (got == x.astype(np.int64) @ w.astype(np.int64)).all()
-
-
-@pytest.mark.parametrize("blocks", [(64, 64, 64), (128, 128, 128),
-                                    (128, 256, 128)])
-def test_bitserial_block_shapes(blocks):
-    bm, bn, bk = blocks
-    rng = np.random.default_rng(3)
-    x = rng.integers(0, 16, (130, 140)).astype(np.int32)
-    w = rng.standard_normal((140, 70)).astype(np.float32)
-    got = np.asarray(bitserial_matmul(jnp.asarray(x), jnp.asarray(w), 4,
-                                      bm=bm, bn=bn, bk=bk))
-    ref = np.asarray(bitserial_matmul_ref(jnp.asarray(x), jnp.asarray(w), 4))
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=5e-3)

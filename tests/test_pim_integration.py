@@ -1,22 +1,22 @@
-"""Closes the loop: PIMLinear int matmul == cycle-accurate simulator.
+"""Closes the loop: the PIM linear's int matmul == cycle-accurate simulator.
 
 The chain: float layer -> quantized ints -> (a) qmatmul_exact /
-(b) Pallas bit-serial kernel / (c) the in-memory MultPIM-MAC simulator —
-all three must agree bit-for-bit on the integer accumulation.
+(b) the in-memory MultPIM-MAC simulator — both must agree bit-for-bit
+on the integer accumulation.
 """
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.matvec import matvec as pim_matvec
-from repro.pim import (PIMLinearSpec, gemms_from_config,
-                       pim_linear_apply, plan_model)
+from repro import pim
+from repro.pim import gemms_from_config, plan_model
 
 pytestmark = pytest.mark.pim
 
 
 def test_pim_linear_matches_simulator():
-    """8-bit PIMLinear integer accumulation == the crossbar simulator's
+    """8-bit PIM linear integer accumulation == the crossbar simulator's
     full-precision fixed-point mat-vec, element for element."""
     n_bits = 8
     rng = np.random.default_rng(0)
@@ -39,20 +39,10 @@ def test_pim_linear_quant_error_small():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((64, 128)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((128, 96)), jnp.float32)
-    yf = pim_linear_apply(PIMLinearSpec(128, 96, mode="float"), x, w)
-    yp = pim_linear_apply(PIMLinearSpec(128, 96, mode="pim"), x, w)
+    yf = x @ w
+    yp = pim.linear(x, w, 8)
     rel = float(jnp.linalg.norm(yp - yf) / jnp.linalg.norm(yf))
     assert rel < 0.02
-
-
-def test_pim_linear_pallas_path_identical():
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.standard_normal((32, 64)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((64, 48)), jnp.float32)
-    a = pim_linear_apply(PIMLinearSpec(64, 48, mode="pim"), x, w)
-    b = pim_linear_apply(PIMLinearSpec(64, 48, mode="pim",
-                                       use_pallas=True), x, w)
-    assert float(jnp.max(jnp.abs(a - b))) == 0.0
 
 
 def test_planner_on_real_arch():

@@ -8,9 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.pim
 from repro import obs
 from repro.configs import ARCHS, get_config
-from repro.engine import Engine, get_engine
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model, plan_weights
 from repro.pim import PlannedWeight, plan_weight
@@ -21,7 +21,7 @@ pytestmark = pytest.mark.pim
 
 
 def _linear(x, w, n_bits):
-    return get_engine().linear(x, w, n_bits=n_bits, mode="pim")
+    return repro.pim.linear(x, w, n_bits)
 
 
 # As plan_weights runs it: compiled, so its scales round as in a step.
@@ -33,7 +33,7 @@ _plan = jax.jit(plan_weight, static_argnums=1)
                          ids=["ffn", "head"])
 def test_planned_linear_bit_identical(n_bits, shape):
     """A batch-8 decode row through an FFN- and a head-shaped weight: the
-    engine's linear on the float weight (planned in the call) and on its
+    PIM linear on the float weight (planned in the call) and on its
     plan both give :func:`qmatmul_exact`'s result, bit for bit."""
     rng = np.random.default_rng(n_bits)
     x = jnp.asarray(rng.standard_normal((8, 1, shape[0])), jnp.float32)
@@ -84,12 +84,11 @@ def test_planned_product_dtypes_in_lowered_hlo(n_bits, dtype):
 
 
 def test_planned_weight_rejects_other_widths_and_pallas():
+    """A weight planned at 8 bits refuses a product at 4."""
     w = plan_weight(jnp.ones((16, 8)), 8)
     x = jnp.ones((2, 16))
     with pytest.raises(ValueError, match="planned at 8"):
         _linear(x, w, 4)
-    with pytest.raises(ValueError, match="Pallas"):
-        get_engine().linear(x, w, n_bits=8, mode="pim", use_pallas=True)
 
 
 def _cfg(linear="pim", block="ffn", **over):
@@ -118,7 +117,6 @@ def _planned_paths(plan):
     ("off", "ffn", {"['scan'][0]['mlp']['w1']", "['scan'][0]['mlp']['w2']",
                     "['scan'][0]['mlp']['w3']"}),
     ("off", "none", set()),
-    ("fake", "full", set()),
 ])
 def test_plan_follows_pim_scopes(linear, block, planned):
     """The planned weights are those the PIM-mode linears quantize; the
@@ -202,14 +200,13 @@ def test_plan_covers_every_pim_linear_of_the_decode_step(arch, monkeypatch):
     params = jax.eval_shape(model.init, jax.random.key(0))
     plan = jax.eval_shape(lambda p: plan_weights(cfg, p), params)
     got = []
-    real = Engine.linear
+    real = repro.pim.linear
 
-    def spy(self, x, w, *args, **kw):
-        if kw.get("mode", "pim") == "pim":
-            got.append(isinstance(w, PlannedWeight))
-        return real(self, x, w, *args, **kw)
+    def spy(x, w, n_bits):
+        got.append(isinstance(w, PlannedWeight))
+        return real(x, w, n_bits)
 
-    monkeypatch.setattr(Engine, "linear", spy)
+    monkeypatch.setattr(repro.pim, "linear", spy)
     states = jax.eval_shape(lambda: model.init_decode_state(2, 8))
     tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
     jax.eval_shape(model.decode_step, plan, tok, tok, states)
