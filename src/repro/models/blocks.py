@@ -41,7 +41,7 @@ from .attention import (POSITIONS_LAST, KVCache, LatentCache, attend,
 from .layers import Initializer, rms_norm, rope, yarn_inv_freq, yarn_mscale
 
 __all__ = ["init_block", "apply_block", "init_state", "pim_proj",
-           "pim_weights", "writes_by_position"]
+           "pim_weights", "pim_stacks", "writes_by_position"]
 
 
 # ------------------------------------------------------ PIM offload ----
@@ -63,10 +63,12 @@ def pim_weights(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
     """The weights a block of ``kind`` passes to :func:`pim_proj`, by
     scope, as key paths into its params (a path may be absent, e.g.
     ``w3`` of a gelu MLP). :func:`repro.models.transformer.plan_weights`
-    quantizes these once; the MoE expert stacks (``_pim_ragged``) are
-    not here. Keep it beside the ``apply_*`` functions' ``pim_proj``
-    calls: ``tests/test_weight_plan.py`` traces every architecture's
-    decode step on the plan and fails on a float weight in ``pim`` mode."""
+    quantizes these once, per column, and the MoE expert stacks that the
+    block passes to ``_pim_ragged`` (:func:`pim_stacks`) once, with one
+    scale a stack. Keep both beside the ``apply_*`` functions' calls:
+    ``tests/test_weight_plan.py`` traces every architecture's decode
+    step on the plan and fails on a float weight or stack in ``pim``
+    mode."""
     def mlp(key):
         return tuple((key, n) for n in _MLP)
     attn = ("wq", "wk", "wv", "wo", "xq", "xk", "xv", "xo")
@@ -81,10 +83,19 @@ def pim_weights(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
     return {}
 
 
-def _pim_ragged(cfg: ModelConfig, xs: jnp.ndarray, we: jnp.ndarray,
+def pim_stacks(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
+    """The expert stacks a block of ``kind`` passes to ``_pim_ragged``,
+    by scope, as key paths into its params (:func:`pim_weights`)."""
+    if kind == "m":
+        return {"ffn": (("we1",), ("we3",), ("we2",))}
+    return {}
+
+
+def _pim_ragged(cfg: ModelConfig, xs: jnp.ndarray, we,
                 counts: jnp.ndarray) -> jnp.ndarray:
     """MoE per-expert grouped GEMM: a PIM one (:func:`repro.pim.
-    ragged_linear`) under the ``"ffn"`` scope, else ``ragged_dot``."""
+    ragged_linear`, on a planned or a float stack) under the ``"ffn"``
+    scope, else ``ragged_dot``."""
     if "ffn" not in cfg.pim_scopes():
         return jax.lax.ragged_dot(xs, we, counts)
     return pim.ragged_linear(xs, we, counts, cfg.pim_linear_bits)

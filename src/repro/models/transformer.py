@@ -19,10 +19,10 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.configs.base import ModelConfig
-from repro.pim.quant import plan_weight
+from repro.pim.quant import STACK_AXES, plan_weight
 
 from .blocks import (apply_block, init_block, init_state, pim_proj,
-                     pim_weights, writes_by_position)
+                     pim_stacks, pim_weights, writes_by_position)
 from .layers import Initializer, rms_norm, softcap
 
 __all__ = ["stack_plan", "init_params", "forward", "decode_step",
@@ -101,31 +101,36 @@ def plan_weights(cfg: ModelConfig, params: Dict[str, Any]) -> Dict[str, Any]:
 
     The weights are those each block kind passes to
     :func:`~repro.models.blocks.pim_proj` (:func:`~.blocks.pim_weights`)
-    under ``cfg.pim_scopes()``, and the LM head; a tied head is planned
-    as ``lm_head``, which :func:`decode_step` reads before ``embed.T``.
-    The identity when no linear runs in ``pim`` mode. Traceable, so
-    ``jax.eval_shape`` gives the plan's shapes.
+    under ``cfg.pim_scopes()``, planned per column, the MoE expert
+    stacks it passes to ``repro.pim.ragged_linear``
+    (:func:`~.blocks.pim_stacks`), planned with one scale a stack and
+    layer, and the LM head; a tied head is planned as ``lm_head``, which
+    :func:`decode_step` reads before ``embed.T``. The identity when no
+    linear runs in ``pim`` mode. Traceable, so ``jax.eval_shape`` gives
+    the plan's shapes.
     """
     scopes = cfg.pim_scopes()
     if not scopes:
         return params
     bits = cfg.pim_linear_bits
     # One program a weight: op by op, each temporary is a weight's size.
-    plan_one = jax.jit(plan_weight, static_argnums=1)
+    plan_one = jax.jit(plan_weight, static_argnums=(1, 2))
 
-    def planned(p, path):
+    def planned(p, path, axis):
         key, *rest = path
         if key not in p:                  # e.g. w3 of a gelu MLP
             return p
         p = dict(p)
-        p[key] = planned(p[key], rest) if rest else plan_one(p[key], bits)
+        p[key] = (planned(p[key], rest, axis) if rest
+                  else plan_one(p[key], bits, axis))
         return p
 
     def block(p, kind):
-        for scope, paths in pim_weights(cfg, kind).items():
-            if scope in scopes:
-                for path in paths:
-                    p = planned(p, path)
+        for weights, axis in ((pim_weights, -2), (pim_stacks, STACK_AXES)):
+            for scope, paths in weights(cfg, kind).items():
+                if scope in scopes:
+                    for path in paths:
+                        p = planned(p, path, axis)
         return p
 
     prefix, unit, _, suffix = stack_plan(cfg)
@@ -136,7 +141,7 @@ def plan_weights(cfg: ModelConfig, params: Dict[str, Any]) -> Dict[str, Any]:
     if "head" in scopes:
         head = (params["lm_head"] if "lm_head" in params
                 else params["embed"].T)
-        out["lm_head"] = plan_one(head, bits)
+        out["lm_head"] = plan_one(head, bits, -2)
     return out
 
 

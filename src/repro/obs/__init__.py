@@ -14,7 +14,10 @@ Four pieces, one import surface:
   not need a switch. :data:`WEIGHT_PLANS` and :data:`PLAN_REUSES` name
   the serve step's counters of weight plans made and reused;
   :data:`POSITION_WRITES` and :data:`WHOLE_WRITES` the decode step's
-  layer states written by position and whole, counted when it is traced.
+  layer states written by position and whole, and
+  :data:`STACK_PLAN_READS` and :data:`STACK_QUANTIZATIONS` its MoE
+  expert products on a planned and on a float stack, counted when it is
+  traced.
 * **Waterfall** (:mod:`.waterfall`) — modeled-cycle counter tracks
   (partition occupancy, gate activity, switching) derived from compiled
   programs, merged into the same trace file; plus the
@@ -79,6 +82,7 @@ __all__ = [
     "device_scopes", "watch_compiles",
     # counter names
     "WEIGHT_PLANS", "PLAN_REUSES", "POSITION_WRITES", "WHOLE_WRITES",
+    "STACK_PLAN_READS", "STACK_QUANTIZATIONS",
     # logging
     "setup_logging", "get_logger",
 ]
@@ -93,6 +97,11 @@ PLAN_REUSES = "pim.plan_reuses"      # steps served from a stored plan
 # (recurrent states).
 POSITION_WRITES = "kv_cache.position_writes"
 WHOLE_WRITES = "kv_cache.whole_writes"
+# Counters of the MoE expert products, counted as a step is traced
+# (repro.pim.ragged_linear): calls on an expert stack planned once, and
+# calls that quantize a float stack.
+STACK_PLAN_READS = "pim.stack_plan_reads"
+STACK_QUANTIZATIONS = "pim.stack_quantizations"
 
 
 # --------------------------------------------------------------- spans ----

@@ -8,10 +8,12 @@ and matmuls correct the offset analytically.
 
 Weights that stay put while serving are quantized once
 (:func:`plan_weight`) into a :class:`PlannedWeight`: centred codes
-``q - 2^(n-1)`` (``int8`` up to 8 bits) and per-column scales. Against
-it :func:`qmatmul_planned` forms :func:`qmatmul_exact`'s exact integer
-sum in one product, with no offset correction; :func:`qmatmul_exact`
-stays as the reference that the planned product is tested against.
+``q - 2^(n-1)`` (``int8`` up to 8 bits) and per-column scales, or one
+scale for a whole MoE expert stack (:data:`STACK_AXES`). Against it
+:func:`qmatmul_planned` and :func:`qragged_matmul_exact` form
+:func:`qmatmul_exact`'s exact integer sum in one product, with no
+offset correction; :func:`qmatmul_exact` stays as the reference that
+the planned product is tested against.
 """
 from __future__ import annotations
 
@@ -25,11 +27,15 @@ from repro import obs
 
 __all__ = ["QTensor", "quantize", "dequantize", "qmatmul_exact",
            "qragged_matmul_exact", "PlannedWeight", "plan_weight",
-           "qmatmul_planned"]
+           "qmatmul_planned", "STACK_AXES"]
 
 
 #: Rows a tile of :func:`qragged_matmul_exact` multiplies at a time.
 RAGGED_TILE = 512
+
+#: The axes that an (E, D, F) expert stack's one scale reduces over
+#: (:func:`plan_weight`), each layer of a layer-stacked one on its own.
+STACK_AXES = (-3, -2, -1)
 
 
 class QTensor(NamedTuple):
@@ -83,15 +89,18 @@ def qmatmul_exact(xq: QTensor, wq: QTensor) -> jnp.ndarray:
         return (prod - corr).astype(jnp.float32) * xq.scale * wq.scale
 
 
-def qragged_matmul_exact(xq: QTensor, wq: QTensor, counts: jnp.ndarray,
-                         *, tile: int = RAGGED_TILE) -> jnp.ndarray:
+def qragged_matmul_exact(xq: QTensor, w: PlannedWeight,
+                         counts: jnp.ndarray, *,
+                         tile: int = RAGGED_TILE) -> jnp.ndarray:
     """Ragged grouped-GEMM variant of :func:`qmatmul_exact` for the MoE
     dropless dispatch: ``xq.q`` is the (T, D) expert-sorted token block,
-    ``wq.q`` the (E, D, F) per-expert weight stack (per-tensor scale so
-    one offset covers every expert), ``counts`` the (E,) per-expert
-    segment lengths. Row ``t`` multiplies against its segment's expert
-    exactly as ``jax.lax.ragged_dot`` would on the float path; rows past
-    the segments read 0 (a TPU ``ragged_dot`` does not zero them).
+    ``w`` the (E, D, F) per-expert weight stack planned with one scale
+    (:func:`plan_weight` over :data:`STACK_AXES`, so one offset covers
+    every expert), ``counts`` the (E,) per-expert segment lengths. Row
+    ``t`` multiplies against its segment's expert exactly as
+    ``jax.lax.ragged_dot`` would on the float path; rows past the
+    segments read 0 (a TPU ``ragged_dot`` does not zero them). A stack
+    planned at another width than ``xq`` raises.
 
     The corrected integer sum ``sum (x - zx)(w - zw)`` of
     :func:`qmatmul_exact` is formed from the centred codes (``int8 x int8
@@ -106,13 +115,15 @@ def qragged_matmul_exact(xq: QTensor, wq: QTensor, counts: jnp.ndarray,
     the product and its temporaries grow with the rows, not with rows
     times experts.
     """
-    dtype = jnp.int8 if max(xq.n_bits, wq.n_bits) <= 8 else jnp.int32
+    if xq.n_bits != w.n_bits:
+        raise ValueError(f"activations at {xq.n_bits} bits, stack "
+                         f"planned at {w.n_bits}")
     t, d = xq.q.shape
-    e, _, f = wq.q.shape
+    e, _, f = w.shape
+    wc, w_scale = w.q, w.scale.reshape(())
     batched = (((2,), (1,)), ((0,), (0,)))
     with obs.scope(obs.PIM_QUANTIZE):
-        xc = (xq.q - xq.zero).astype(dtype)
-        wc = (wq.q - wq.zero).astype(dtype)
+        xc = (xq.q - xq.zero).astype(wc.dtype)
     with obs.scope(obs.PIM_MATMUL):
         first_row = jnp.cumsum(counts) - counts
         i = jnp.arange(t)
@@ -123,7 +134,7 @@ def qragged_matmul_exact(xq: QTensor, wq: QTensor, counts: jnp.ndarray,
                 preferred_element_type=jnp.int32)
             mine = g[None, :, None] == jnp.arange(e)[:, None, None]
             prod = jnp.sum(jnp.where(mine, prods, 0), axis=0)
-            return prod.astype(jnp.float32) * xq.scale * wq.scale
+            return prod.astype(jnp.float32) * xq.scale * w_scale
         n = -(-t // tile) + e
         tiles = -(-counts // tile)                         # (E,)
         first_tile = jnp.cumsum(tiles) - tiles
@@ -146,18 +157,19 @@ def qragged_matmul_exact(xq: QTensor, wq: QTensor, counts: jnp.ndarray,
                          n * tile)
         prod = jnp.take(prods.reshape(n * tile, f), slot, axis=0,
                         mode="fill", fill_value=0)
-        return prod.astype(jnp.float32) * xq.scale * wq.scale
+        return prod.astype(jnp.float32) * xq.scale * w_scale
 
 
 @dataclasses.dataclass(frozen=True)
 class PlannedWeight:
-    """A weight quantized once, for :func:`qmatmul_planned`.
+    """A weight quantized once, for :func:`qmatmul_planned` or, an
+    expert stack, :func:`qragged_matmul_exact`.
 
     ``q`` holds the centred codes ``q - 2^(n-1)`` of :func:`quantize`
-    along the input axis (``int8`` for ``n_bits <= 8``, else ``int32``),
-    shaped like the float weight (layer-stacked ones too); ``scale`` the
-    ``float32`` per-column scales, ``(..., 1, out)``. A pytree whose
-    leaves are ``q`` and ``scale``; ``n_bits`` is static.
+    (``int8`` for ``n_bits <= 8``, else ``int32``), shaped like the float
+    weight (layer-stacked ones too); ``scale`` the ``float32`` scales,
+    per column ``(..., 1, out)`` or one a stack ``(..., 1, 1, 1)``. A
+    pytree whose leaves are ``q`` and ``scale``; ``n_bits`` is static.
     """
     q: jnp.ndarray
     scale: jnp.ndarray
@@ -172,15 +184,18 @@ jax.tree_util.register_dataclass(PlannedWeight, data_fields=["q", "scale"],
                                  meta_fields=["n_bits"])
 
 
-def plan_weight(w: jnp.ndarray, n_bits: int) -> PlannedWeight:
-    """``w`` (..., in, out) float -> its :class:`PlannedWeight`: codes and
-    scales per column, each layer of a stack on its own, exactly what
-    :func:`quantize` with ``axis=0`` gives one (in, out) weight in the
-    same setting (traced, or op by op), less its offset."""
+def plan_weight(w: jnp.ndarray, n_bits: int, axis=-2) -> PlannedWeight:
+    """``w`` float -> its :class:`PlannedWeight`, with scales over
+    ``axis``: per column of a (..., in, out) weight by default, or one
+    for each (E, D, F) expert stack over :data:`STACK_AXES`; each layer
+    of a stack on its own. The codes and scales are exactly what
+    :func:`quantize` gives one weight in the same setting (traced, or op
+    by op), with ``axis=0`` per column and ``axis=None`` for a stack,
+    less its offset."""
     zero = 2 ** (n_bits - 1)
     dtype = jnp.int8 if n_bits <= 8 else jnp.int32
     with obs.scope(obs.PIM_QUANTIZE):
-        scale = _scale(w, n_bits, -2)
+        scale = _scale(w, n_bits, axis)
         # Clipped in centred form: op by op, no int32 copy of the weight.
         q = jnp.clip(jnp.round(w / scale), -zero, zero - 1).astype(dtype)
         return PlannedWeight(q, scale.astype(jnp.float32), n_bits)

@@ -9,12 +9,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config
 from repro.engine import Engine
-from repro.pim import (QTensor, block_linears, plan_block, qmatmul_exact,
-                       qragged_matmul_exact, quantize)
+from repro.pim import (PlannedWeight, QTensor, block_linears, plan_block,
+                       plan_weight, qmatmul_exact, qragged_matmul_exact,
+                       quantize)
+from repro.pim.quant import RAGGED_TILE, STACK_AXES
 
 pytestmark = pytest.mark.pim
+
+
+def _plan_stack(we, n_bits):
+    """An (E, D, F) stack planned with one scale, as the ragged path
+    takes it."""
+    return plan_weight(we, n_bits, STACK_AXES)
 
 
 def _pim_cfg(arch="gemma2-9b", block_mode="full"):
@@ -217,7 +226,7 @@ def test_qragged_matmul_matches_dense_per_segment():
     we = jnp.asarray(rng.standard_normal((e, d, f)), jnp.float32)
     xq = quantize(xs, 8)
     wq = quantize(we, 8)
-    got = qragged_matmul_exact(xq, wq, counts)
+    got = qragged_matmul_exact(xq, _plan_stack(we, 8), counts)
     lo = 0
     for ei, c in enumerate([4, 0, 2]):
         if c == 0:
@@ -252,7 +261,8 @@ def test_quantized_matmuls_exact_at_model_widths():
     we = jnp.asarray(rng.standard_normal((2, d, 3)), jnp.float32)
     counts = jnp.asarray([3, 1], jnp.int32)
     wqe = quantize(we, 8)
-    got_r = np.asarray(qragged_matmul_exact(xq, wqe, counts), np.float64)
+    got_r = np.asarray(qragged_matmul_exact(xq, _plan_stack(we, 8), counts),
+                       np.float64)
     wie = np.asarray(wqe.q, np.int64) - wqe.zero
     want_r = np.concatenate([xi[:3] @ wie[0], xi[3:] @ wie[1]]).astype(
         np.float64) * np.asarray(xq.scale, np.float64) * float(wqe.scale)
@@ -273,7 +283,7 @@ def test_ragged_product_operands_in_lowered_hlo(n_bits, dtype, tile,
     we = jax.ShapeDtypeStruct((3, 64, 16), jnp.float32)
     counts = jax.ShapeDtypeStruct((3,), jnp.int32)
     text = jax.jit(lambda x, w, c: qragged_matmul_exact(
-        quantize(x, n_bits), quantize(w, n_bits), c, tile=tile)).lower(
+        quantize(x, n_bits), _plan_stack(w, n_bits), c, tile=tile)).lower(
             xs, we, counts).as_text()
     dots = [ln for ln in text.splitlines() if "dot_general" in ln]
     assert len(dots) == 1
@@ -292,10 +302,11 @@ def test_qragged_tiles_span_experts_exactly(tile):
     counts = np.array([7, 0, 13, 1, 0, 9, 0])
     t, d, f = 33, 32, 6                       # 3 rows past the segments
     xq = quantize(jnp.asarray(rng.standard_normal((t, d)), jnp.float32), 8)
-    wq = quantize(jnp.asarray(rng.standard_normal((7, d, f)), jnp.float32),
-                  8)
+    we = jnp.asarray(rng.standard_normal((7, d, f)), jnp.float32)
+    wq = quantize(we, 8)
     got = np.asarray(qragged_matmul_exact(
-        xq, wq, jnp.asarray(counts, jnp.int32), tile=tile), np.float64)
+        xq, _plan_stack(we, 8), jnp.asarray(counts, jnp.int32), tile=tile),
+        np.float64)
     xi = np.asarray(xq.q, np.int64) - xq.zero
     wi = np.asarray(wq.q, np.int64) - wq.zero
     want = np.zeros((t, f))
@@ -315,13 +326,13 @@ def test_qragged_temporaries_grow_with_rows_not_rows_times_experts():
     e, t, d, f = 64, 16384, 256, 512
 
     def product(x, w, c):
-        return qragged_matmul_exact(QTensor(x, jnp.float32(0.01), 8, 128),
-                                    QTensor(w, jnp.float32(0.01), 8, 128),
-                                    c)
+        return qragged_matmul_exact(
+            QTensor(x, jnp.float32(0.01), 8, 128),
+            PlannedWeight(w, jnp.full((1, 1, 1), 0.01, jnp.float32), 8), c)
 
     compiled = jax.jit(product).lower(
         jax.ShapeDtypeStruct((t, d), jnp.int32),
-        jax.ShapeDtypeStruct((e, d, f), jnp.int32),
+        jax.ShapeDtypeStruct((e, d, f), jnp.int8),
         jax.ShapeDtypeStruct((e,), jnp.int32)).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < e * t * f * 4 / 8
@@ -340,3 +351,58 @@ def test_engine_ragged_linear_modes():
     assert yf.shape == yp.shape == (5, 4)
     rel = float(jnp.linalg.norm(yp - yf) / jnp.linalg.norm(yf))
     assert rel < 0.05
+
+
+@pytest.mark.parametrize("rows", [12, RAGGED_TILE + 88],
+                         ids=["batched", "tiled"])
+@pytest.mark.parametrize("n_bits", [4, 8, 12])
+def test_planned_stack_matches_the_float_stack(n_bits, rows):
+    """An expert stack planned once, as ``plan_weights`` plans it (one
+    program, one scale a stack), has ``quantize``'s codes less its offset
+    and its scale; ``ragged_linear`` on it gives what it gives on the
+    float stack, and what the centred integer sum of ``quantize``'s codes
+    gives, bit for bit, up to a tile of rows and past it. Tracing counts
+    a plan read on the planned stack and a quantization on the float
+    one; a stack planned at another width raises."""
+    from repro import pim
+    rng = np.random.default_rng(n_bits + rows)
+    e, d, f = 3, 32, 16
+    xs = jnp.asarray(rng.standard_normal((rows, d)), jnp.float32)
+    we = jnp.asarray(rng.standard_normal((e, d, f)) * 0.05, jnp.float32)
+    n = [rows // 2, 0, rows - rows // 2 - 3]       # 3 rows past them
+    counts = jnp.asarray(n, jnp.int32)
+    planned = jax.jit(plan_weight, static_argnums=(1, 2))(
+        we, n_bits, STACK_AXES)
+    assert planned.scale.shape == (1, 1, 1)
+    assert planned.q.dtype == (jnp.int8 if n_bits <= 8 else jnp.int32)
+    ref = jax.jit(lambda w: quantize(w, n_bits))(we)
+    np.testing.assert_array_equal(np.asarray(planned.q, np.int32),
+                                  np.asarray(ref.q) - ref.zero)
+    np.testing.assert_array_equal(planned.scale.reshape(()), ref.scale)
+
+    reads = obs.counter(obs.STACK_PLAN_READS)
+    quantizations = obs.counter(obs.STACK_QUANTIZATIONS)
+    r0, q0 = reads.value, quantizations.value
+    run = jax.jit(lambda x, w, c: pim.ragged_linear(x, w, c, n_bits))
+    got_plan = np.asarray(run(xs, planned, counts))
+    assert (reads.value - r0, quantizations.value - q0) == (1, 0)
+    got_float = np.asarray(run(xs, we, counts))
+    assert (reads.value - r0, quantizations.value - q0) == (1, 1)
+    np.testing.assert_array_equal(got_plan, got_float)
+
+    xq = jax.jit(lambda x: quantize(x, n_bits))(xs)
+    xi = np.asarray(xq.q, np.int64) - xq.zero
+    wi = np.asarray(ref.q, np.int64) - ref.zero
+    want = np.zeros((rows, f), np.int64)
+    lo = 0
+    for j, c in enumerate(n):
+        want[lo:lo + c] = xi[lo:lo + c] @ wi[j]
+        lo += c
+    want = (want.astype(np.float32) * np.asarray(xq.scale, np.float32)
+            * np.asarray(ref.scale, np.float32))
+    np.testing.assert_array_equal(got_plan, want)
+    assert not got_plan[lo:].any()
+
+    other = 8 if n_bits != 8 else 4
+    with pytest.raises(ValueError, match=f"planned at {n_bits}"):
+        pim.ragged_linear(xs, planned, counts, other)

@@ -190,3 +190,41 @@ def test_decode_step_copies_no_layer_of_a_cache(one_chip, arch):
     assert not moved, moved
     layer = min(a.size // a.shape[0] * 4 for a in caches)
     assert compiled.memory_analysis().temp_size_in_bytes < layer
+
+
+def test_planned_moe_decode_step_reduces_no_float_expert_stack(one_chip):
+    """deepseek-v2-lite's decode step on the weight plan, at the
+    decode-pim-4k cell's widths and shapes (8 held experts of 2048 x
+    1408, batch 32, a 4096-position latent cache) cut to 2 layers, for
+    one v5e: the held expert stacks reach it as int8 codes, and no
+    reduction in its optimized HLO reads a float32 (E, D, F) stack, as
+    a per-call quantization's scale reduction would."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import build_model, plan_weights
+    cfg = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(
+        cfg.scaled(n_layers=2, layer_pattern="dm",
+                   moe=dataclasses.replace(cfg.moe, experts_held=8)),
+        pim_linear_mode="pim", pim_linear_bits=8, pim_block_mode="ffn")
+    m = build_model(cfg)
+    on = lambda t: jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    plan = on(jax.eval_shape(lambda p: plan_weights(cfg, p), params))
+    states = on(jax.eval_shape(lambda: m.init_decode_state(32, 4096)))
+    tok = _spec(one_chip, (32, 1), jnp.int32)
+    moe = plan["suffix"][0] if plan["suffix"] else plan["prefix"][1]
+    assert all(moe[k].q.dtype == jnp.int8 for k in ("we1", "we2", "we3"))
+    text = jax.jit(m.decode_step, donate_argnums=(3,)).lower(
+        plan, tok, tok, states).compile().as_text()
+    assert "s8[8,2048,1408]" in text and "s8[8,1408,2048]" in text
+    # Each instruction's shape by name, and what each reduce reads (its
+    # first operand; a fused reduce reads an instruction of its fusion).
+    shape = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    read = [shape.get(a) for a in
+            re.findall(r"= [^=]*? reduce\(%([\w.\-]+)", text)]
+    assert read, "no reduce found: the HLO text's form changed"
+    stacks = {"f32[8,2048,1408]", "f32[8,1408,2048]"}
+    assert not stacks & set(read), read

@@ -137,16 +137,37 @@ def test_plan_follows_pim_scopes(linear, block, planned):
 
 def test_plan_of_a_tied_head_and_a_moe_block():
     """A tied head is planned as ``lm_head`` from ``embed.T``; the MoE
-    block plans its shared FFN and q/k/v, not ``wo`` (a plain matmul)
-    nor the expert stacks (the ragged path)."""
+    block plans its shared FFN and q/k/v, not ``wo`` (a plain matmul),
+    and its expert stacks ``we1``, ``we3``, ``we2`` with one scale for
+    each layer of a stack: ``quantize``'s over the layer's whole (E, D,
+    F) stack, codes less the offset."""
     cfg = dataclasses.replace(get_config("deepseek-moe-16b", smoke=True),
                               pim_linear_mode="pim", pim_block_mode="full")
-    params = jax.eval_shape(build_model(cfg).init, jax.random.key(0))
-    plan = jax.eval_shape(lambda p: plan_weights(cfg, p), params)
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.key(0))
+    plan = plan_weights(cfg, params)
     paths = _planned_paths(plan)
     moe = [p for p in paths if "shared" in p]
     assert moe, paths
-    assert not any(k in p for p in paths for k in ("we1", "we2", "we3"))
+    stacks = {k: [p for p in paths if f"['{k}']" in p]
+              for k in ("we1", "we2", "we3")}
+    assert all(stacks.values()), paths
+    leaves = jax.tree_util.tree_flatten_with_path(
+        plan, is_leaf=lambda a: isinstance(a, PlannedWeight))[0]
+    was = dict(jax.tree_util.tree_flatten_with_path(params)[0])
+    for path, w in leaves:
+        if not any(f"['{k}']" in jax.tree_util.keystr(path)
+                   for k in stacks):
+            continue
+        f = was[path]
+        assert w.q.shape == f.shape and w.q.dtype == jnp.int8
+        assert w.scale.shape == f.shape[:-3] + (1, 1, 1)
+        for i in np.ndindex(f.shape[:-3]):     # each layer of the stack
+            ref = quant.quantize(f[i], 8)
+            np.testing.assert_array_equal(np.asarray(w.q[i], np.int32),
+                                          np.asarray(ref.q) - ref.zero)
+            np.testing.assert_array_equal(w.scale[i].reshape(()),
+                                          ref.scale)
     tied = dataclasses.replace(_cfg(), tie_embeddings=True)
     tparams = jax.jit(build_model(tied).init)(jax.random.key(0))
     assert "lm_head" not in tparams
@@ -155,14 +176,18 @@ def test_plan_of_a_tied_head_and_a_moe_block():
     np.testing.assert_array_equal(head.q, ref.q)
 
 
+def _moe_cfg(block):
+    return dataclasses.replace(get_config("deepseek-v2-lite", smoke=True),
+                               pim_linear_mode="pim", pim_linear_bits=8,
+                               pim_block_mode=block)
+
+
 @pytest.mark.parametrize("block", ["ffn", "full"])
 def test_planned_step_quantizes_no_weight(block, monkeypatch):
     """Tracing the serve step on the plan quantizes activations only:
-    no dense PIM linear quantizes a weight per step."""
-    cfg = _cfg("pim", block)
-    model = build_model(cfg)
-    params = jax.jit(model.init)(jax.random.key(0))
-    plan = plan_weights(cfg, params)
+    no dense PIM linear quantizes a weight per step, and no MoE block an
+    expert stack (``pim.stack_quantizations`` stays, the stacks are read
+    planned); on the float params both are planned per call."""
     axes, planned = [], []
     real, real_plan = quant.quantize, quant.plan_weight
 
@@ -170,28 +195,43 @@ def test_planned_step_quantizes_no_weight(block, monkeypatch):
         axes.append(axis)
         return real(x, n_bits, axis)
 
-    def plan_spy(w, n_bits):
+    def plan_spy(w, n_bits, axis=-2):
         planned.append(w.shape)
-        return real_plan(w, n_bits)
+        return real_plan(w, n_bits, axis)
 
     monkeypatch.setattr(quant, "quantize", spy)
     monkeypatch.setattr(quant, "plan_weight", plan_spy)
-    states = model.init_decode_state(2, 8)
-    tok = jnp.zeros((2, 1), jnp.int32)
-    serve_step, _ = make_serve_step(model, make_host_mesh(1))
-    jax.eval_shape(serve_step, plan, states, tok, tok)
-    assert axes and all(a is None for a in axes) and not planned
-    axes.clear()
-    jax.eval_shape(serve_step, params, states, tok, tok)
-    assert planned                      # float weights are planned per call
+    reads = obs.counter(obs.STACK_PLAN_READS)
+    quantizations = obs.counter(obs.STACK_QUANTIZATIONS)
+    for cfg in (_cfg("pim", block), _moe_cfg(block)):
+        model = build_model(cfg)
+        params = jax.jit(model.init)(jax.random.key(0))
+        plan = plan_weights(cfg, params)
+        moe = "m" in cfg.layer_kinds()
+        axes.clear()
+        planned.clear()
+        states = model.init_decode_state(2, 8)
+        tok = jnp.zeros((2, 1), jnp.int32)
+        serve_step, _ = make_serve_step(model, make_host_mesh(1))
+        r0, q0 = reads.value, quantizations.value
+        jax.eval_shape(serve_step, plan, states, tok, tok)
+        assert axes and all(a is None for a in axes) and not planned
+        assert quantizations.value == q0
+        assert (reads.value > r0) == moe
+        axes.clear()
+        jax.eval_shape(serve_step, params, states, tok, tok)
+        assert planned                  # float weights are planned per call
+        assert (quantizations.value > q0) == moe
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_plan_covers_every_pim_linear_of_the_decode_step(arch, monkeypatch):
     """Every block kind (dense, local, MoE and its dense layer, RG-LRU,
     RWKV, cross-attention): on the plan, each ``pim``-mode linear of the
-    decode step gets a planned weight, so ``blocks.pim_weights`` lists
-    what the ``apply_*`` functions pass to ``pim_proj``; a planned weight
+    decode step gets a planned weight and each MoE expert product a
+    planned stack, so ``blocks.pim_weights`` and ``blocks.pim_stacks``
+    list what the ``apply_*`` functions pass to ``pim_proj`` and
+    ``_pim_ragged`` (``pim.stack_quantizations`` stays); a planned weight
     that reached a plain matmul would fail the trace."""
     cfg = dataclasses.replace(get_config(arch, smoke=True),
                               pim_linear_mode="pim", pim_linear_bits=8,
@@ -199,18 +239,57 @@ def test_plan_covers_every_pim_linear_of_the_decode_step(arch, monkeypatch):
     model = build_model(cfg)
     params = jax.eval_shape(model.init, jax.random.key(0))
     plan = jax.eval_shape(lambda p: plan_weights(cfg, p), params)
-    got = []
-    real = repro.pim.linear
+    got, stacks = [], []
+    real, real_ragged = repro.pim.linear, repro.pim.ragged_linear
 
     def spy(x, w, n_bits):
         got.append(isinstance(w, PlannedWeight))
         return real(x, w, n_bits)
 
+    def ragged_spy(xs, we, counts, n_bits):
+        stacks.append(isinstance(we, PlannedWeight))
+        return real_ragged(xs, we, counts, n_bits)
+
     monkeypatch.setattr(repro.pim, "linear", spy)
+    monkeypatch.setattr(repro.pim, "ragged_linear", ragged_spy)
+    quantizations = obs.counter(obs.STACK_QUANTIZATIONS)
+    q0 = quantizations.value
     states = jax.eval_shape(lambda: model.init_decode_state(2, 8))
     tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
     jax.eval_shape(model.decode_step, plan, tok, tok, states)
     assert got and all(got), got
+    assert all(stacks) and bool(stacks) == ("m" in cfg.layer_kinds())
+    assert quantizations.value == q0
+
+
+_MOE_ARCHS = sorted(a for a in ARCHS
+                    if "m" in get_config(a, smoke=True).layer_kinds())
+
+
+@pytest.mark.parametrize("arch", _MOE_ARCHS)
+def test_moe_decode_step_reads_planned_stacks_and_prefill_quantizes(arch):
+    """Every MoE architecture in PIM ``ffn`` mode: tracing the decode
+    step on the plan counts no ``pim.stack_quantizations`` and three
+    ``pim.stack_plan_reads`` an MoE block; its prefill on the float
+    params quantizes the stacks in the call."""
+    cfg = dataclasses.replace(get_config(arch, smoke=True),
+                              pim_linear_mode="pim", pim_linear_bits=8,
+                              pim_block_mode="ffn")
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    plan = jax.eval_shape(lambda p: plan_weights(cfg, p), params)
+    reads = obs.counter(obs.STACK_PLAN_READS)
+    quantizations = obs.counter(obs.STACK_QUANTIZATIONS)
+    r0, q0 = reads.value, quantizations.value
+    states = jax.eval_shape(lambda: model.init_decode_state(2, 8))
+    tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    jax.eval_shape(model.decode_step, plan, tok, tok, states)
+    assert quantizations.value == q0
+    assert reads.value - r0 >= 3 and (reads.value - r0) % 3 == 0
+    r0 = reads.value
+    prompts = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    jax.eval_shape(model.forward, params, prompts)
+    assert reads.value == r0 and quantizations.value - q0 >= 3
 
 
 def _tiny_serve(seed=0):
